@@ -24,10 +24,10 @@ import jax
 import jax.numpy as jnp
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-from repro import compat
 from benchmarks.schedule_sim import (balanced_schedule, coverage_ok,
                                      expected_speedup, idle_fraction,
                                      ring_schedule)
+from repro.launch.mesh import make_mesh
 
 ROWS = []
 
@@ -80,18 +80,18 @@ def bench_table5_checkpointing():
     from repro.train.step import make_train_step
 
     cfg = smoke_config(get_config("llama-7b")).replace(n_layers=4)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeSpec("b5", 512, 2, "train")
     results = {}
     for remat in ("none", "hf", "remat_aware"):
         par = make_parallel_config(mesh, shape, remat=remat)
-        model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))
+        model = build_model(cfg, Runtime(mesh=mesh, par=par))
         params = model.init(jax.random.PRNGKey(0))
         opt = adamw.init(params)
         batch = SyntheticTokens(cfg, shape, par, mesh).batch(0)
         step = jax.jit(make_train_step(model, TrainConfig()))
-        flops = compat.cost_analysis(
-            step.lower(params, opt, batch).compile()).get("flops", 0)
+        flops = step.lower(params, opt, batch).compile().cost_analysis(
+        ).get("flops", 0)
 
         def run(step=step, params=params, opt=opt, batch=batch):
             jax.block_until_ready(step(params, opt, batch))
@@ -112,9 +112,10 @@ def bench_table3_rsa():
     and wall time, 8 host devices, seq-parallel attention only."""
     code = """
 import time, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import DistAttnSpec, dist_attn_fwd
-mesh = jax.make_mesh((1,8), ("data","model"))
+mesh = make_mesh((1,8), ("data","model"))
 B,N,H,D = 1,4096,8,64
 ks = jax.random.split(jax.random.PRNGKey(0),3)
 q,k,v = (jax.random.normal(kk,(B,N,H,D),jnp.float32) for kk in ks)
@@ -151,10 +152,11 @@ def bench_table4_ulysses():
     layer from compiled HLO (8 host devices) + head-divisibility failures."""
     code = """
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import DistAttnSpec, dist_attn_fwd
 from repro.analysis.roofline import collective_stats
-mesh = jax.make_mesh((1,8), ("data","model"))
+mesh = make_mesh((1,8), ("data","model"))
 B,N,D = 1,4096,64
 for name, H, Hkv, sched in [("balanced_mha",8,8,"balanced"),
                             ("ulysses_mha",8,8,"ulysses"),
@@ -232,9 +234,10 @@ def bench_schedules_wall():
     so the perf trajectory covers the schedules, not just the kernels."""
     code = """
 import time, statistics, numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import DistAttnSpec, dist_attn_fwd, zigzag_perm
-mesh = jax.make_mesh((1,8), ("data","model"))
+mesh = make_mesh((1,8), ("data","model"))
 B,N,H,D = 1,2048,8,64
 ks = jax.random.split(jax.random.PRNGKey(0),3)
 q,k,v = (jax.random.normal(kk,(B,N,H,D),jnp.float32) for kk in ks)
@@ -328,6 +331,7 @@ def bench_schedules_plans2d():
             continue
         code = f"""
 import time, statistics, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import DistAttnSpec, Mesh2DSpec, dist_flash_attn
 B,N,Hq,Hkv,D = {B},{N},{Hq},{Hkv},{D}
@@ -346,10 +350,10 @@ for label, sched, r, u in (("chosen",{name!r},{r},{u}),
                            ("pure_ring","ring",8,1),
                            ("head_parallel","ring",1,8)):
     if u == 1:
-        mesh = jax.make_mesh((1,8), ("data","model"))
+        mesh = make_mesh((1,8), ("data","model"))
         spec = DistAttnSpec(axis="model", axis_size=8, schedule=sched, mask=m)
     else:
-        mesh = jax.make_mesh((1,r,u), ("data","seq","head"))
+        mesh = make_mesh((1,r,u), ("data","seq","head"))
         spec = DistAttnSpec(axis="seq", axis_size=8, schedule=sched,
                             mask=m, mesh2d=Mesh2DSpec(r=r,u=u))
     def loss(a,b,c,mesh=mesh,spec=spec):

@@ -50,6 +50,8 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.mesh import make_mesh
+
 DEFAULT_OUT = os.path.join(os.path.dirname(__file__), "..",
                            "BENCH_serving.json")
 
@@ -86,11 +88,11 @@ def run_trace(*, arch="smollm-360m", n_requests=8, max_batch=4,
     from repro.serve.engine import Engine
 
     cfg = smoke_config(get_config(arch))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeSpec("bench", max(prompt_lens), max(4, n_requests),
                       "prefill")
     par = make_parallel_config(mesh, shape)
-    model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))
+    model = build_model(cfg, Runtime(mesh=mesh, par=par))
     params = model.init(jax.random.PRNGKey(0))
     prompts = np.asarray(
         SyntheticTokens(cfg, shape, par, mesh).batch(0)["tokens"])
@@ -185,10 +187,10 @@ def run_shared_prefix(*, arch="smollm-360m", n_requests=6, prefix_len=48,
     from repro.serve.engine import Engine
 
     cfg = smoke_config(get_config(arch))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeSpec("bench", prefix_len + tail_len, 4, "prefill")
     par = make_parallel_config(mesh, shape)
-    model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))
+    model = build_model(cfg, Runtime(mesh=mesh, par=par))
     params = model.init(jax.random.PRNGKey(0))
     rows = np.asarray(
         SyntheticTokens(cfg, shape, par, mesh).batch(0)["tokens"])
@@ -281,11 +283,11 @@ def run_chaos(*, arch="smollm-360m", n_requests=8, max_batch=4,
     from repro.serve.faults import FaultInjector
 
     cfg = smoke_config(get_config(arch))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeSpec("bench", max(prompt_lens), max(4, n_requests),
                       "prefill")
     par = make_parallel_config(mesh, shape)
-    model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))
+    model = build_model(cfg, Runtime(mesh=mesh, par=par))
     params = model.init(jax.random.PRNGKey(0))
     prompts = np.asarray(
         SyntheticTokens(cfg, shape, par, mesh).batch(0)["tokens"])
@@ -379,11 +381,11 @@ def run_speculative(*, arch="smollm-360m", n_requests=6, max_batch=4,
     from repro.serve.speculative import ModelDraft, SpecConfig
 
     cfg = smoke_config(get_config(arch))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeSpec("bench", max(prompt_lens), max(4, n_requests),
                       "prefill")
     par = make_parallel_config(mesh, shape)
-    model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))
+    model = build_model(cfg, Runtime(mesh=mesh, par=par))
     params = model.init(jax.random.PRNGKey(0))
     prompts = np.asarray(
         SyntheticTokens(cfg, shape, par, mesh).batch(0)["tokens"])

@@ -35,6 +35,7 @@ import numpy as np  # noqa: E402
 from repro.core.config import ShapeSpec, get_config, smoke_config  # noqa
 import dataclasses  # noqa: E402
 from repro.data.pipeline import SyntheticTokens  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models.transformer import Runtime, build_model  # noqa: E402
 from repro.parallel.sharding import make_parallel_config  # noqa: E402
 from repro.serve.engine import Engine, FixedSlotEngine  # noqa: E402
@@ -46,10 +47,10 @@ def run(window: int, *, chunk_tokens: int = 256, prefix_cache: bool = True,
     cfg = smoke_config(get_config("qwen3-8b"))
     if window:
         cfg = cfg.replace(attn=dataclasses.replace(cfg.attn, window=window))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))   # 4-way seq parallel
+    mesh = make_mesh((2, 4), ("data", "model"))   # 4-way seq parallel
     shape = ShapeSpec("lc", 1024, 4, "prefill")       # 1K-token prompts
     par = make_parallel_config(mesh, shape)
-    model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))
+    model = build_model(cfg, Runtime(mesh=mesh, par=par))
     params = model.init(jax.random.PRNGKey(0))
     batch = SyntheticTokens(cfg, shape, par, mesh).batch(0)
     prompts = np.asarray(batch["tokens"])
@@ -65,8 +66,7 @@ def run(window: int, *, chunk_tokens: int = 256, prefix_cache: bool = True,
             spec = SpecConfig(depth=spec_depth, mode="ngram")
         else:
             d_cfg = smoke_config(get_config(draft_config))
-            d_model = build_model(d_cfg, Runtime(mesh=mesh, par=par,
-                                                 impl="ref"))
+            d_model = build_model(d_cfg, Runtime(mesh=mesh, par=par))
             d_params = d_model.init(jax.random.PRNGKey(7))
             spec = SpecConfig(depth=spec_depth, mode="model",
                               draft_arch=d_cfg.name)

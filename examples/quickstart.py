@@ -27,7 +27,7 @@ def main():
     # balanced schedule + rematerialization-aware checkpointing — the
     # paper's configuration — are the defaults
     par = make_parallel_config(mesh, shape)
-    model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))
+    model = build_model(cfg, Runtime(mesh=mesh, par=par))
 
     params = model.init(jax.random.PRNGKey(0))
     opt = adamw.init(params)

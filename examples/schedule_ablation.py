@@ -19,10 +19,11 @@ from repro.analysis.roofline import collective_stats  # noqa: E402
 from repro.core.dist_attention import DistAttnSpec, dist_attn_fwd  # noqa
 from repro.kernels.ref import full_attn_ref  # noqa: E402
 from repro.core import mask as mask_lib  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def main():
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_mesh((1, 8), ("data", "model"))
     B, N, H, D = 1, 2048, 8, 64
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q, k, v = (jax.random.normal(kk, (B, N, H, D)) for kk in ks)

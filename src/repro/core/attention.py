@@ -22,7 +22,8 @@ process); new call sites should pass ``mask=``.
 matches the MaskSpec's kinds against each backend's capability set and
 platform support, falling back down the registry's chain (with a logged
 downgrade) instead of crashing — e.g. ``pallas`` on a CPU host runs
-``pallas-interpret``/``chunked-lax``.
+``chunked-lax``. ``impl=None`` follows the platform: ``pallas`` on TPU,
+``ref`` elsewhere.
 """
 from __future__ import annotations
 
@@ -32,11 +33,6 @@ from repro.core import mask as mk
 from repro.core.mask import MaskSpec
 from repro.kernels import registry
 from repro.kernels.ref import NEG_INF, merge_ref
-
-
-def set_default_impl(impl: str) -> None:
-    """Set the process-wide default backend (configs override per call)."""
-    registry.set_default(impl)
 
 
 def _resolve_mask(mask, causal, rel_offset, window) -> MaskSpec:

@@ -83,7 +83,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import mask as mk
 from repro.core import schedule as sp
 from repro.core.attention import chunk_attn, chunk_attn_bwd
@@ -159,7 +158,8 @@ class DistAttnSpec:
     window: dataclasses.InitVar[Optional[int]] = None
     scale: Optional[float] = None
     # attention backend name resolved via repro.kernels.registry (None =
-    # process default); capability/platform fallback happens at resolve time
+    # the platform's default); capability/platform fallback happens at
+    # resolve time
     impl: Optional[str] = None
     # per-call-site kernel tile hints, forwarded to tunable backends only
     # (Pallas block shapes / chunked-lax scan chunk). None = backend default.
@@ -426,9 +426,9 @@ def dist_attn_fwd(q, k, v, *, mesh, spec: DistAttnSpec,
     if segments is not None:
         in_specs.append(seg_s)
         args.append(segments)
-    fn = compat.shard_map(partial(_fwd_local, spec), mesh=mesh,
-                          in_specs=tuple(in_specs),
-                          out_specs=(qkv_s, lse_s), check_vma=False)
+    fn = jax.shard_map(partial(_fwd_local, spec), mesh=mesh,
+                       in_specs=tuple(in_specs),
+                       out_specs=(qkv_s, lse_s), check_vma=False)
     return fn(*args)
 
 
@@ -441,9 +441,9 @@ def dist_attn_bwd(q, k, v, o, lse, do, *, mesh, spec: DistAttnSpec,
     if segments is not None:
         in_specs.append(seg_s)
         args.append(segments)
-    fn = compat.shard_map(partial(_bwd_local, spec), mesh=mesh,
-                          in_specs=tuple(in_specs),
-                          out_specs=(qkv_s, qkv_s, qkv_s), check_vma=False)
+    fn = jax.shard_map(partial(_bwd_local, spec), mesh=mesh,
+                       in_specs=tuple(in_specs),
+                       out_specs=(qkv_s, qkv_s, qkv_s), check_vma=False)
     return fn(*args)
 
 
@@ -520,10 +520,10 @@ def _decode_local(seq_axes, shard_len, window, scale, has_pos, q, kc, vc,
     # linearized shard index over (possibly multiple) sequence axes
     idx = jnp.int32(0)
     for ax in seq_axes:
-        idx = idx * compat.axis_size(ax) + lax.axis_index(ax)
+        idx = idx * lax.axis_size(ax) + lax.axis_index(ax)
     n_shards = 1
     for ax in seq_axes:
-        n_shards *= compat.axis_size(ax)
+        n_shards *= lax.axis_size(ax)
     S_total = n_shards * shard_len
     offset = idx * shard_len
     B, _, Hq, Dq = q.shape
@@ -645,7 +645,7 @@ def dist_decode_attn(q, k_cache, v_cache, k_new, v_new, *, mesh,
             pos = jnp.broadcast_to(pos, (q.shape[0],))
         in_specs.append(P(b))
         args.append(pos)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         partial(_decode_local, tuple(seq_axes), shard_len, w, scale,
                 pos is not None),
         mesh=mesh,
@@ -699,8 +699,8 @@ def dist_attn_fwd_latent(q, k, v, payload, w_up, expand, *, mesh, spec,
     qkv_s = P(b, spec.axis, None, None)
     pl_s = P(b, spec.axis, None)
     lse_s = P(b, spec.axis, None)
-    w_s = compat.tree_map(lambda a: P(*(None,) * a.ndim), w_up)
-    fn = compat.shard_map(
+    w_s = jax.tree.map(lambda a: P(*(None,) * a.ndim), w_up)
+    fn = jax.shard_map(
         partial(_fwd_latent_local, spec, expand), mesh=mesh,
         in_specs=(qkv_s, qkv_s, qkv_s, pl_s, w_s),
         out_specs=(qkv_s, lse_s), check_vma=False)

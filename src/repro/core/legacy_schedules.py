@@ -8,10 +8,10 @@ extend these — new schedule capabilities go into the plan builders.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.core import mask as mk
 from repro.core.attention import chunk_attn, chunk_attn_bwd, mask_partial, merge
 
@@ -30,7 +30,7 @@ def _seg_kw(mask, q_seg, kv_seg):
 def _shift(x, axis, shift, size):
     """ppermute by a fixed shift: device p receives from (p − shift) mod P."""
     perm = [(i, (i + shift) % size) for i in range(size)]
-    return compat.tree_map(lambda a: lax.ppermute(a, axis, perm), x)
+    return jax.tree.map(lambda a: lax.ppermute(a, axis, perm), x)
 
 def _ring_steps(spec: DistAttnSpec, chunk_len: int) -> int:
     """Number of ring steps; truncated by the sliding window (Appendix F)."""
@@ -133,7 +133,7 @@ def _bwd_ring(spec, q, k, v, o, s, do, seg=None):
     # containers: (k, v) data + (dk, dv) accumulators travel together
     kv = _shift((k, v), spec.axis, 1, P_)
     seg_r = _shift(seg, spec.axis, 1, P_) if seg is not None else None
-    dkv = compat.tree_map(lambda a: jnp.zeros(a.shape, f32), kv)
+    dkv = jax.tree.map(lambda a: jnp.zeros(a.shape, f32), kv)
     for t in range(1, n + 1):
         if t < n:                                     # prefetch data (overlap)
             kv_nxt = _shift(kv, spec.axis, 1, P_)

@@ -36,7 +36,6 @@ from functools import partial
 from typing import Callable
 
 import jax
-from repro import compat
 import jax.numpy as jnp
 
 
@@ -47,7 +46,7 @@ def _tree_add(a, b):
         if getattr(x, "dtype", None) == jax.dtypes.float0:
             return x
         return jnp.add(x, y)
-    return compat.tree_map(add, a, b)
+    return jax.tree.map(add, a, b)
 
 
 def remat_aware(pre_attn: Callable, attn_fwd: Callable, attn_bwd: Callable,

@@ -65,7 +65,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro import compat
 from repro.core import mask as mk
 from repro.core.attention import (chunk_attn, chunk_attn_bwd, empty_partial,
                                   mask_partial, merge)
@@ -408,7 +407,7 @@ def _shift(x, axis, shift, size):
     P.  Multi-hop shifts (skipped steps folded together) are one
     collective."""
     perm = [(i, (i + shift) % size) for i in range(size)]
-    return compat.tree_map(lambda a: lax.ppermute(a, axis, perm), x)
+    return jax.tree.map(lambda a: lax.ppermute(a, axis, perm), x)
 
 
 def _gchunk(layout, P, owner, i):
@@ -501,7 +500,7 @@ class _Ctx:
 def _sel(pv, a, b):
     """Predicate-select two pytrees of arrays/scalars (None passes
     through)."""
-    return compat.tree_map(lambda x, y: jnp.where(pv, x, y), a, b)
+    return jax.tree.map(lambda x, y: jnp.where(pv, x, y), a, b)
 
 
 def _q_side(ctx: _Ctx, ref: Ref, extras):

@@ -13,8 +13,8 @@ distance, so no scalar prefetch is required). Document (packed-sequence)
 masking is supported two ways:
 
   * dynamic ``q_segments``/``kv_segments`` (B, T) int32 arrays enter the
-    kernels as narrow ``(1, block)`` blocks next to their q/kv tiles and
-    are compared elementwise inside the mask;
+    kernels as (B, 1, T) with narrow ``(1, 1, block)`` blocks next to their
+    q/kv tiles and are compared elementwise inside the mask;
   * a static ``mask.boundaries`` layout needs no arrays at all — segment
     IDs become trace-time iota comparisons AND the grid pruning below drops
     cross-document blocks entirely.
@@ -37,13 +37,12 @@ transpose for the dkv kernel — is computed at trace time by
 The backward follows FA2: ``delta = rowsum(do ⊙ o)`` precomputed, then a
 dq-kernel (grid over q blocks, sequential kv) and a dkv-kernel (grid over kv
 blocks, sequential q) recompute ``p = exp(s − lse)`` blockwise from the saved
-logsumexp. ``lse``/``delta`` enter the kernels as narrow ``(1, 1, block_q)``
-blocks of the (B, H, T) arrays — not lane-replicated (B, H, T, 128) float32
-broadcasts materialized in HBM. Hardware note: the narrow stat blocks put T
-on the lane dimension, so the default ``block_q=128`` stays lane-aligned;
-CI validates interpret mode only, and ``test_pruned_flash_compiles_on_tpu``
-(TPU-gated) covers the compiled Mosaic lowering of the narrow blocks, the
-in-kernel ``lax.cond`` fast path, and the remapped index maps.
+logsumexp. ``lse``/``delta`` enter the kernels as (B, H, 1, T) arrays in
+narrow ``(1, 1, 1, block_q)`` blocks — not lane-replicated (B, H, T, 128)
+float32 broadcasts materialized in HBM. Mosaic requires a block's last two
+dims to be (8k or full, 128k or full): the unit axis is full, and T sits on
+the lanes, so ``block_q`` must be a multiple of 128 or all of T.
+``tests/test_tpu_compile.py`` compiles the kernels for a described TPU v5e.
 """
 from __future__ import annotations
 
@@ -51,7 +50,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from repro import compat
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -141,14 +139,27 @@ def _check_segs(mask: MaskSpec, q_segments, kv_segments) -> bool:
 
 
 def _seg_specs(br, bc, kv_block, *, dkv=False, q_block=None):
-    """BlockSpecs of the (B, T) segment-ID arrays for each grid
-    orientation: narrow (1, block) tiles riding next to their q/kv tiles."""
+    """BlockSpecs of the (B, 1, T) segment-ID arrays for each grid
+    orientation: narrow (1, 1, block) tiles riding next to their q/kv
+    tiles."""
     if not dkv:
-        return [pl.BlockSpec((1, br), lambda b, h, i, j: (b, i)),
-                pl.BlockSpec((1, bc),
-                             lambda b, h, i, j: (b, kv_block(i, j)))]
-    return [pl.BlockSpec((1, br), lambda b, h, j, i: (b, q_block(j, i))),
-            pl.BlockSpec((1, bc), lambda b, h, j, i: (b, j))]
+        return [pl.BlockSpec((1, 1, br), lambda b, h, i, j: (b, 0, i)),
+                pl.BlockSpec((1, 1, bc),
+                             lambda b, h, i, j: (b, 0, kv_block(i, j)))]
+    return [pl.BlockSpec((1, 1, br),
+                         lambda b, h, j, i: (b, 0, q_block(j, i))),
+            pl.BlockSpec((1, 1, bc), lambda b, h, j, i: (b, 0, j))]
+
+
+def _seg_operands(q_segments, kv_segments):
+    """(B, T) segment IDs as the (B, 1, T) int32 kernel operands."""
+    return [jnp.asarray(s, jnp.int32)[:, None, :]
+            for s in (q_segments, kv_segments)]
+
+
+def _stat_operand(x):
+    """(B, H, T) row statistic as the (B, H, 1, T) float32 operand."""
+    return x.astype(jnp.float32)[:, :, None, :]
 
 
 # ---------------------------------------------------------------- forward
@@ -180,8 +191,8 @@ def _fwd_kernel(*refs, scale, mask, nk, prune, has_segs):
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         if mask.needs_mask:
-            q_seg = None if qs_ref is None else qs_ref[0]
-            kv_seg = None if ks_ref is None else ks_ref[0]
+            q_seg = None if qs_ref is None else qs_ref[0, 0]
+            kv_seg = None if ks_ref is None else ks_ref[0, 0]
             s = _apply_mask(s, i, j, mask, prune, q_seg, kv_seg)
 
         m_prev = m_ref[:, 0]                             # (br,)
@@ -202,8 +213,8 @@ def _fwd_kernel(*refs, scale, mask, nk, prune, has_segs):
         l = l_ref[:, 0]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.where(l == 0.0, NEG_INF, m_ref[:, 0] +
-                                  jnp.log(l_safe))
+        lse_ref[0, 0, 0] = jnp.where(l == 0.0, NEG_INF, m_ref[:, 0] +
+                                     jnp.log(l_safe))
 
 
 def flash_fwd_bhtd(q, k, v, *, scale, mask: MaskSpec, block_q=128,
@@ -247,31 +258,30 @@ def flash_fwd_bhtd(q, k, v, *, scale, mask: MaskSpec, block_q=128,
     operands = [q, k, v]
     if has_segs:
         in_specs += _seg_specs(br, bc, kv_block)
-        operands += [jnp.asarray(q_segments, jnp.int32),
-                     jnp.asarray(kv_segments, jnp.int32)]
+        operands += _seg_operands(q_segments, kv_segments)
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, br, Dv), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, br), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, 1, br), lambda b, h, i, j: (b, h, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hq, Tq, Dv), q.dtype),
-            jax.ShapeDtypeStruct((B, Hq, Tq), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, 1, Tq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((br, Dv), jnp.float32),
             pltpu.VMEM((br, LANES), jnp.float32),
             pltpu.VMEM((br, LANES), jnp.float32),
         ],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
     )(*operands)
-    return o, lse
+    return o, lse.reshape(B, Hq, Tq)
 
 
 # ---------------------------------------------------------------- backward
@@ -300,13 +310,13 @@ def _dq_kernel(*refs, scale, mask, nk, prune, has_segs):
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]                              # (br,)
-        delta = delta_ref[0, 0]
+        lse = lse_ref[0, 0, 0]                           # (br,)
+        delta = delta_ref[0, 0, 0]
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         if mask.needs_mask:
-            q_seg = None if qs_ref is None else qs_ref[0]
-            kv_seg = None if ks_ref is None else ks_ref[0]
+            q_seg = None if qs_ref is None else qs_ref[0, 0]
+            kv_seg = None if ks_ref is None else ks_ref[0, 0]
             s = _apply_mask(s, i, j, mask, prune, q_seg, kv_seg)
         p = jnp.where(lse[:, None] <= NEG_INF / 2, 0.0,
                       jnp.exp(s - lse[:, None]))
@@ -343,13 +353,13 @@ def _dkv_kernel(*refs, scale, mask, nq, prune, has_segs):
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
+        lse = lse_ref[0, 0, 0]
+        delta = delta_ref[0, 0, 0]
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         if mask.needs_mask:
-            q_seg = None if qs_ref is None else qs_ref[0]
-            kv_seg = None if ks_ref is None else ks_ref[0]
+            q_seg = None if qs_ref is None else qs_ref[0, 0]
+            kv_seg = None if ks_ref is None else ks_ref[0, 0]
             s = _apply_mask(s, i, j, mask, prune, q_seg, kv_seg)
         p = jnp.where(lse[:, None] <= NEG_INF / 2, 0.0,
                       jnp.exp(s - lse[:, None]))
@@ -382,8 +392,7 @@ def flash_bwd_bhtd(q, k, v, o, lse, do, *, scale, mask: MaskSpec,
     if delta is None:
         delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
                         axis=-1)
-    delta = delta.astype(jnp.float32)
-    lse = lse.astype(jnp.float32)
+    stats = [_stat_operand(lse), _stat_operand(delta)]
 
     pruned = prune and mask.prunable
     seq_kv, seq_q = nk, nq
@@ -395,10 +404,7 @@ def flash_bwd_bhtd(q, k, v, o, lse, do, *, scale, mask: MaskSpec,
                 jnp.zeros((B, Hkv, Tk, D), k.dtype),
                 jnp.zeros((B, Hkv, Tk, Dv), v.dtype))
 
-    seg_ops = []
-    if has_segs:
-        seg_ops = [jnp.asarray(q_segments, jnp.int32),
-                   jnp.asarray(kv_segments, jnp.int32)]
+    seg_ops = _seg_operands(q_segments, kv_segments) if has_segs else []
 
     def kv_block(i, j):
         return _kv_index(i, j, br, bc, nk, mask, prune)
@@ -409,7 +415,7 @@ def flash_bwd_bhtd(q, k, v, o, lse, do, *, scale, mask: MaskSpec,
     v_spec = pl.BlockSpec(
         (1, 1, bc, Dv), lambda b, h, i, j: (b, h // g, kv_block(i, j), 0))
     do_spec = pl.BlockSpec((1, 1, br, Dv), lambda b, h, i, j: (b, h, i, 0))
-    stat_spec = pl.BlockSpec((1, 1, br), lambda b, h, i, j: (b, h, i))
+    stat_spec = pl.BlockSpec((1, 1, 1, br), lambda b, h, i, j: (b, h, 0, i))
 
     in_specs = [q_spec, kv_spec, v_spec, do_spec, stat_spec, stat_spec]
     if has_segs:
@@ -422,11 +428,11 @@ def flash_bwd_bhtd(q, k, v, o, lse, do, *, scale, mask: MaskSpec,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((br, D), jnp.float32)],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v, do, lse, delta, *seg_ops)
+    )(q, k, v, do, *stats, *seg_ops)
 
     # dkv: grid over kv blocks, sequential over the valid q blocks. Output
     # per *query* head, then group-summed below (GQA).
@@ -441,8 +447,8 @@ def flash_bwd_bhtd(q, k, v, o, lse, do, *, scale, mask: MaskSpec,
                             lambda b, h, j, i: (b, h, q_block(j, i), 0))
     k_out2 = pl.BlockSpec((1, 1, bc, D), lambda b, h, j, i: (b, h, j, 0))
     v_out2 = pl.BlockSpec((1, 1, bc, Dv), lambda b, h, j, i: (b, h, j, 0))
-    stat_spec2 = pl.BlockSpec((1, 1, br),
-                              lambda b, h, j, i: (b, h, q_block(j, i)))
+    stat_spec2 = pl.BlockSpec((1, 1, 1, br),
+                              lambda b, h, j, i: (b, h, 0, q_block(j, i)))
     in_specs2 = [q_spec2, kv_spec2, v_spec2, do_spec2, stat_spec2,
                  stat_spec2]
     if has_segs:
@@ -457,11 +463,11 @@ def flash_bwd_bhtd(q, k, v, o, lse, do, *, scale, mask: MaskSpec,
                    jax.ShapeDtypeStruct((B, Hq, Tk, Dv), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bc, D), jnp.float32),
                         pltpu.VMEM((bc, Dv), jnp.float32)],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v, do, lse, delta, *seg_ops)
+    )(q, k, v, do, *stats, *seg_ops)
     if g > 1:
         dk_h = dk_h.reshape(B, Hkv, g, Tk, D).sum(axis=2)
         dv_h = dv_h.reshape(B, Hkv, g, Tk, Dv).sum(axis=2)

@@ -53,7 +53,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.core import mask as mk
 from repro.core.mask import MaskSpec
 from repro.kernels.ref import NEG_INF, merge_ref
@@ -267,7 +266,7 @@ def paged_attn_pallas(q, k_pool, v_pool, block_table, lengths, *, mask=None,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g * Tq, Dv), q.dtype),
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(block_table, jnp.int32), jnp.asarray(lengths, jnp.int32),

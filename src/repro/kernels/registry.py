@@ -24,8 +24,14 @@ Capabilities are **mask-kind sets**: each backend declares which
 ``resolve(impl, platform, mask=spec)`` matches the spec's required kinds
 against them, walking each backend's fallback chain when the requested
 backend can't run (wrong platform, unsupported mask kind, wrong dtype) and
-logging the downgrade — requesting ``pallas`` on CPU runs
-``pallas-interpret`` (or ``chunked-lax``) instead of crashing.
+logging the downgrade — requesting ``pallas`` on CPU runs ``chunked-lax``
+instead of crashing.
+
+The backend follows the platform: ``impl=None`` resolves to the compiled
+``pallas`` kernels on TPU and to ``ref`` elsewhere. The interpreter runs
+only when a caller names ``pallas-interpret``. On TPU a fallback may land
+on ``chunked-lax`` (exact and compiled) but never on ``ref`` or the
+interpreter: such a request raises instead of running the O(T²) oracle.
 
 Backend names are normalized (``pallas_interpret`` == ``pallas-interpret``)
 so the pre-registry spelling keeps working.
@@ -133,8 +139,9 @@ class BackendSpec:
 
 
 _REGISTRY: Dict[str, BackendSpec] = {}
-_DEFAULT = ["ref"]
 _WARNED = set()   # (requested, resolved, platform) — log each downgrade once
+# backends a TPU request may never fall back to (uncompiled or O(T²))
+_NO_TPU_FALLBACK = frozenset({"ref", "pallas-interpret"})
 
 
 def _norm(name: str) -> str:
@@ -161,16 +168,13 @@ def get(name: str) -> BackendSpec:
     return _REGISTRY[key]
 
 
-def set_default(name: str) -> None:
-    _DEFAULT[0] = get(name).name
-
-
-def default_name() -> str:
-    return _DEFAULT[0]
-
-
 def current_platform() -> str:
     return jax.default_backend()
+
+
+def default_name(platform: Optional[str] = None) -> str:
+    """The backend ``impl=None`` resolves to on ``platform``."""
+    return "pallas" if (platform or current_platform()) == "tpu" else "ref"
 
 
 def resolve(impl: Optional[str] = None, platform: Optional[str] = None, *,
@@ -179,14 +183,16 @@ def resolve(impl: Optional[str] = None, platform: Optional[str] = None, *,
             paged: bool = False) -> BackendSpec:
     """Return a runnable backend for the request, walking fallbacks.
 
-    ``impl=None`` uses the process default; ``mask`` is the MaskSpec the
-    call site will pass; ``dynamic_offsets`` marks a call that carries
-    traced position-offset operands; ``paged=True`` requires the backend's
-    block-table decode path. A downgrade (requested backend can't
-    serve the request) is logged once per (requested, resolved, platform)
-    triple; an empty/cyclic fallback chain raises."""
+    ``impl=None`` uses the platform's default (:func:`default_name`);
+    ``mask`` is the MaskSpec the call site will pass; ``dynamic_offsets``
+    marks a call that carries traced position-offset operands;
+    ``paged=True`` requires the backend's block-table decode path. A
+    downgrade (requested backend can't serve the request) is logged once
+    per (requested, resolved, platform) triple; an exhausted fallback
+    chain raises, and so does one that would leave TPU for ``ref`` or the
+    interpreter."""
     platform = platform or current_platform()
-    want = get(impl if impl is not None else default_name())
+    want = get(impl if impl is not None else default_name(platform))
     caps = dict(platform=platform, mask=mask, dtype=dtype,
                 dynamic_offsets=dynamic_offsets, paged=paged)
     reason = want.unsupported_reason(**caps)
@@ -201,6 +207,8 @@ def resolve(impl: Optional[str] = None, platform: Optional[str] = None, *,
         if _norm(cand.name) in seen:
             continue
         seen.add(_norm(cand.name))
+        if platform == "tpu" and _norm(cand.name) in _NO_TPU_FALLBACK:
+            continue
         tried.append(cand.name)
         if cand.unsupported_reason(**caps) is None:
             key = (want.name, cand.name, platform)
@@ -360,7 +368,7 @@ register(BackendSpec(
     name="pallas", fwd=_pallas_fwd(False), bwd=_pallas_bwd(False),
     platforms=("tpu",), dtypes=("float32", "bfloat16"),
     tunable_blocks=True, paged_fwd=_paged_pallas(False),
-    fallback=("pallas-interpret", "chunked-lax", "ref"),
+    fallback=("chunked-lax", "ref"),
     description="compiled Pallas TPU FlashAttention-2 kernel"))
 
 register(BackendSpec(

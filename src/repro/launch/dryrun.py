@@ -1,9 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import: jax locks the device count on first
-# backend initialization. 512 placeholder host devices stand in for the
-# production 2×16×16 multi-pod mesh (dry-run only).
-
 """Multi-pod dry-run: lower + compile every (architecture × input shape)
 on the production meshes and extract memory/cost/collective analyses.
 
@@ -14,10 +8,13 @@ on the production meshes and extract memory/cost/collective analyses.
 Success of ``.lower().compile()`` for a pair proves the sharding config is
 coherent (no mismatched collectives, divisibility holes, or unsupported
 layouts); the printed analyses feed EXPERIMENTS.md §Dry-run and §Roofline.
+The CLI runs on 512 placeholder host devices, which stand in for the
+production 2×16×16 multi-pod mesh; importing this module changes nothing.
 """
 import argparse
 import dataclasses
 import json
+import os
 import subprocess
 import sys
 import time
@@ -27,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.analysis import roofline as R
 from repro.core.config import (ARCH_IDS, SHAPES, TrainConfig, get_config,
                                get_shape)
@@ -42,7 +38,7 @@ LONG_CTX_WINDOW = 8192   # paper Appendix-F sliding window for long_500k
 
 
 def prepare(arch: str, shape_name: str, mesh, *, schedule="balanced",
-            remat="remat_aware", impl="ref", latent_ring=False):
+            remat="remat_aware", impl=None, latent_ring=False):
     """Build (step_fn, arg_structs, in_shardings) for one pair."""
     cfg = get_config(arch)
     shape = get_shape(shape_name)
@@ -58,14 +54,14 @@ def prepare(arch: str, shape_name: str, mesh, *, schedule="balanced",
     p_struct = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
     p_sh = param_shardings(p_struct, mesh, par)
     batch_struct, batch_spec = input_specs(cfg, shape, par, mesh)
-    batch_sh = compat.tree_map(lambda s: NamedSharding(mesh, s), batch_spec,
+    batch_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), batch_spec,
                             is_leaf=lambda x: isinstance(x, P))
 
     if shape.kind == "train":
         tc = TrainConfig()
         opt_struct = jax.eval_shape(adamw.init, p_struct)
         opt_sh = adamw.AdamWState(step=NamedSharding(mesh, P()), m=p_sh,
-                                  v=compat.tree_map(lambda s: s, p_sh))
+                                  v=jax.tree.map(lambda s: s, p_sh))
         step = make_train_step(model, tc)
         args = (p_struct, opt_struct, batch_struct)
         shardings = (p_sh, opt_sh, batch_sh)
@@ -131,7 +127,7 @@ def _knob_points(cfg):
     return dims, pts, build
 
 
-def _measure(cfg, shape, mesh, schedule, remat, impl="ref",
+def _measure(cfg, shape, mesh, schedule, remat, impl=None,
              latent_ring=False):
     """(flops, bytes, collective_bytes, hop_bytes) for one concrete cfg,
     compiled with UNROLLED layer scans so cost_analysis sees every layer.
@@ -154,13 +150,13 @@ def _measure_inner(cfg, shape, mesh, schedule, remat, impl,
     p_struct = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
     p_sh = param_shardings(p_struct, mesh, par)
     batch_struct, batch_spec = input_specs(cfg, shape, par, mesh)
-    batch_sh = compat.tree_map(lambda s: NamedSharding(mesh, s), batch_spec,
+    batch_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), batch_spec,
                             is_leaf=lambda x: isinstance(x, P))
     if shape.kind == "train":
         step = make_train_step(model, TrainConfig())
         opt_struct = jax.eval_shape(adamw.init, p_struct)
         opt_sh = adamw.AdamWState(step=NamedSharding(mesh, P()), m=p_sh,
-                                  v=compat.tree_map(lambda s: s, p_sh))
+                                  v=jax.tree.map(lambda s: s, p_sh))
         args, shd = (p_struct, opt_struct, batch_struct), \
             (p_sh, opt_sh, batch_sh)
     elif shape.kind == "prefill":
@@ -173,14 +169,14 @@ def _measure_inner(cfg, shape, mesh, schedule, remat, impl,
         args, shd = (p_struct, cache_struct, batch_struct), \
             (p_sh, cache_sh, batch_sh)
     compiled = jax.jit(step, in_shardings=shd).lower(*args).compile()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = R.collective_stats(compiled.as_text())
     return (float(cost.get("flops", 0.0)),
             float(cost.get("bytes accessed", 0.0)),
             coll.total_bytes, coll.hop_weighted_bytes, coll)
 
 
-def extrapolate_costs(cfg, shape, mesh, schedule, remat, impl="ref",
+def extrapolate_costs(cfg, shape, mesh, schedule, remat, impl=None,
                       latent_ring=False):
     """Affine fit of (flops, bytes, coll, hop) over the scan knobs."""
     import numpy as np
@@ -219,7 +215,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
     t2 = time.time()
 
     mem = compiled.memory_analysis()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = R.collective_stats(compiled.as_text())
     if multi_pod:
         # the multi-pod pass proves the 512-chip sharding lowers+compiles
@@ -243,7 +239,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
         }
     # scan-aware extrapolated costs (see _knob_points). NOTE: cfg here
     # already carries the long_500k window override from prepare().
-    ext = extrapolate_costs(cfg, shape, mesh, schedule, remat, impl="ref",
+    ext = extrapolate_costs(cfg, shape, mesh, schedule, remat,
                             latent_ring=latent_ring)
     flops = ext["flops"]
     bytes_acc = ext["bytes"]
@@ -371,4 +367,6 @@ def run_all(args):
 
 
 if __name__ == "__main__":
+    # before the first backend initialization, which locks the device count
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     sys.exit(main())

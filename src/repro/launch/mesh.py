@@ -1,19 +1,33 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-A FUNCTION (not a module-level constant) so importing this module never
+Functions (not module-level constants) so importing this module never
 touches jax device state. Single pod: (data=16, model=16) = 256 chips;
 multi-pod: (pod=2, data=16, model=16) = 512 chips. The ``model`` axis is
 the DISTFLASHATTN sequence-parallel axis.
+
+Every mesh of the program is built by :func:`make_mesh`, with ``Auto``
+axis types: the model code places arrays with ``NamedSharding`` and
+``shard_map`` and relies on the compiler to propagate shardings, which
+``jax.make_mesh``'s default ``Explicit`` axes would turn into type errors
+(a gather such as ``embed[tokens]`` then needs an ``out_sharding=``).
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` over ``devices`` (default: all) with Auto axes."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(seq: int = 1, data: int | None = None):
@@ -21,7 +35,7 @@ def make_local_mesh(seq: int = 1, data: int | None = None):
     n = len(jax.devices())
     if data is None:
         data = n // seq
-    return jax.make_mesh((data, seq), ("data", "model"))
+    return make_mesh((data, seq), ("data", "model"))
 
 
 def make_seq2d_mesh(r: int, u: int, data: int = 1):
@@ -31,4 +45,4 @@ def make_seq2d_mesh(r: int, u: int, data: int = 1):
     intra-group (intra-node on real hardware — BurstAttention's split).
     Activations shard the sequence over the ("seq", "head") axis *pair*;
     ``parallel.sharding.make_parallel_config`` picks the axes up by name."""
-    return jax.make_mesh((data, r, u), ("data", "seq", "head"))
+    return make_mesh((data, r, u), ("data", "seq", "head"))

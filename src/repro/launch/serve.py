@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.core.config import ShapeSpec, get_config, smoke_config
 import dataclasses
 from repro.data.pipeline import SyntheticTokens
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.models.transformer import Runtime, build_model
 from repro.parallel.sharding import make_parallel_config
@@ -51,6 +52,7 @@ def main(argv=None):
                          "(default: configs/spec_pairs.py pairing)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)
@@ -61,7 +63,7 @@ def main(argv=None):
         else make_production_mesh(multi_pod="multipod" in args.mesh)
     shape = ShapeSpec("serve", args.prompt_len, args.batch, "prefill")
     par = make_parallel_config(mesh, shape)
-    model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))
+    model = build_model(cfg, Runtime(mesh=mesh, par=par))
     params = model.init(jax.random.PRNGKey(0))
     batch = SyntheticTokens(cfg, shape, par, mesh).batch(0)
 
@@ -93,8 +95,7 @@ def main(argv=None):
                 d_cfg = get_config(d_arch)
                 if args.smoke:
                     d_cfg = smoke_config(d_cfg)
-                d_model = build_model(d_cfg, Runtime(mesh=mesh, par=par,
-                                                     impl="ref"))
+                d_model = build_model(d_cfg, Runtime(mesh=mesh, par=par))
                 d_params = d_model.init(jax.random.PRNGKey(7))
                 spec = SpecConfig(depth=args.spec_depth, mode="model",
                                   draft_arch=d_cfg.name)

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.config import ModelConfig
 from repro.models.layers import dense_init, rms_norm
 
@@ -60,7 +59,7 @@ def _moe_local(cfg: ModelConfig, seq_axis, all_axes, p, x):
     m = cfg.moe
     b, t, d = x.shape
     n = b * t
-    S = compat.axis_size(seq_axis)
+    S = lax.axis_size(seq_axis)
     e_loc = m.n_routed // S
     h = rms_norm(x, p["ln"], cfg.norm_eps).reshape(n, d)
 
@@ -125,7 +124,7 @@ def moe_apply(p, x, cfg: ModelConfig, *, mesh, seq_axis="model",
     e_spec = P(seq_axis, None, None)
     pspec = {k: (e_spec if k in ("wg", "wu", "wd")
                  else P(*(None,) * p[k].ndim)) for k in p}
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         partial(_moe_local, cfg, seq_axis, all_axes),
         mesh=mesh, in_specs=(pspec, x_s), out_specs=(x_s, P()),
         check_vma=False)
@@ -143,7 +142,7 @@ def _moe_decode_local(cfg: ModelConfig, seq_axis, p, x):
     m = cfg.moe
     b, t, d = x.shape
     n = b * t
-    S = compat.axis_size(seq_axis)
+    S = lax.axis_size(seq_axis)
     e_loc = m.n_routed // S
     sh = lax.axis_index(seq_axis)
     h = rms_norm(x, p["ln"], cfg.norm_eps).reshape(n, d)
@@ -172,7 +171,7 @@ def moe_decode_apply(p, x, cfg: ModelConfig, *, mesh, seq_axis="model",
     e_spec = P(seq_axis, None, None)
     pspec = {k: (e_spec if k in ("wg", "wu", "wd")
                  else P(*(None,) * p[k].ndim)) for k in p}
-    fn = compat.shard_map(partial(_moe_decode_local, cfg, seq_axis),
+    fn = jax.shard_map(partial(_moe_decode_local, cfg, seq_axis),
                        mesh=mesh, in_specs=(pspec, x_s), out_specs=x_s,
                        check_vma=False)
     return fn(p, x)

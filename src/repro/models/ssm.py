@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.config import ModelConfig
 from repro.models.layers import dense_init, rms_norm
 
@@ -111,7 +110,7 @@ def _device_prefix(axis, decay, state):
     """Hillis–Steele exclusive prefix of (decay, state) over the sequence
     axis. decay: (b,nh); state: (b,nh,N,hd). Monoid: apply segment2 after
     segment1 → (d1·d2, s1·d2 + s2)."""
-    P_ = compat.axis_size(axis)
+    P_ = lax.axis_size(axis)
     p = lax.axis_index(axis)
     d_acc, s_acc = decay, state                           # inclusive running
     shift = 1
@@ -147,7 +146,7 @@ def _ssm_local(cfg: ModelConfig, seq_axis, p, x):
     # causal depthwise conv with cross-shard halo
     xbc = jnp.concatenate([xin, B, C], axis=-1)
     k = s.d_conv
-    P_ = compat.axis_size(seq_axis)
+    P_ = lax.axis_size(seq_axis)
     if P_ > 1:
         perm = [(i, (i + 1) % P_) for i in range(P_)]
         tail = lax.ppermute(xbc[:, -(k - 1):], seq_axis, perm)
@@ -183,7 +182,7 @@ def ssm_apply(p, x, cfg: ModelConfig, *, mesh, seq_axis="model",
     bspec = tuple(batch_axes) if batch_axes else None
     x_s = P(bspec, seq_axis, None)
     pspec = {k: P(*(None,) * p[k].ndim) for k in p}
-    fn = compat.shard_map(partial(_ssm_local, cfg, seq_axis), mesh=mesh,
+    fn = jax.shard_map(partial(_ssm_local, cfg, seq_axis), mesh=mesh,
                        in_specs=(pspec, x_s), out_specs=x_s, check_vma=False)
     return fn(p, x)
 

@@ -26,7 +26,6 @@ from jax import lax
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import mask as mk
 from repro.core.config import ModelConfig, ParallelConfig
 from repro.core.dist_attention import (DistAttnSpec, Mesh2DSpec,
@@ -230,7 +229,7 @@ def dense_layer_params(key, cfg, dtype, *, is_mla=False, use_moe=False,
 
 
 def _stack(key, n, make):
-    return compat.tree_map(lambda *xs: jnp.stack(xs),
+    return jax.tree.map(lambda *xs: jnp.stack(xs),
                         *[make(k) for k in jax.random.split(key, max(n, 1))])
 
 
@@ -385,7 +384,7 @@ class DecoderLM:
         cfg, rt = self.cfg, self.rt
         period = cfg.hybrid_period
         G = cfg.n_layers // period
-        stacked = compat.tree_map(
+        stacked = jax.tree.map(
             lambda a: a.reshape(G, period, *a.shape[1:]), p["layers"])
         ssm_layer = self._ssm_layer()
         emb0 = h
@@ -1001,7 +1000,7 @@ class DecoderLM:
         cfg, rt = self.cfg, self.rt
         period = cfg.hybrid_period
         G = cfg.n_layers // period
-        stacked = compat.tree_map(
+        stacked = jax.tree.map(
             lambda a: a.reshape(G, period, *a.shape[1:]), p["layers"])
         emb0 = h
         scfg = self._shared_cfg()
@@ -1110,7 +1109,7 @@ def _cache_write(cache, new, pos, rt: Runtime):
     def upd(c, x, pv):
         idx = jnp.int32(0)
         for ax in seq_axes:
-            idx = idx * compat.axis_size(ax) + lax.axis_index(ax)
+            idx = idx * lax.axis_size(ax) + lax.axis_index(ax)
         slot = pv % (n * S_loc)                       # (B,)
         owner = slot // S_loc
         local = slot % S_loc
@@ -1119,8 +1118,8 @@ def _cache_write(cache, new, pos, rt: Runtime):
         hit = hit.reshape(hit.shape + (1,) * (c.ndim - 2))
         return jnp.where(hit, x.astype(c.dtype), c)   # x (B,1,...) bcasts
 
-    fn = compat.shard_map(upd, mesh=rt.mesh, in_specs=(cspec, rspec,
-                                                       P(bspec)),
+    fn = jax.shard_map(upd, mesh=rt.mesh, in_specs=(cspec, rspec,
+                                                    P(bspec)),
                        out_specs=cspec, check_vma=False)
     return fn(cache, new, pos)
 

@@ -10,7 +10,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core.config import TrainConfig
 
 
@@ -21,9 +20,9 @@ class AdamWState(NamedTuple):
 
 
 def init(params) -> AdamWState:
-    zeros = compat.tree_map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+    zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
     return AdamWState(step=jnp.zeros((), jnp.int32), m=zeros,
-                      v=compat.tree_map(jnp.copy, zeros))
+                      v=jax.tree.map(jnp.copy, zeros))
 
 
 def schedule(step, tc: TrainConfig):
@@ -38,7 +37,7 @@ def clip_by_global_norm(grads, max_norm):
     leaves = jax.tree.leaves(grads)
     gn = jnp.sqrt(sum(jnp.sum(g.astype(jnp.float32) ** 2) for g in leaves))
     scale = jnp.minimum(1.0, max_norm / jnp.maximum(gn, 1e-6))
-    return compat.tree_map(lambda g: g * scale.astype(g.dtype), grads), gn
+    return jax.tree.map(lambda g: g * scale.astype(g.dtype), grads), gn
 
 
 def update(grads, state: AdamWState, params, tc: TrainConfig):
@@ -58,11 +57,11 @@ def update(grads, state: AdamWState, params, tc: TrainConfig):
         upd = upd + tc.weight_decay * p.astype(jnp.float32)
         return (p.astype(jnp.float32) - lr * upd).astype(p.dtype), m2, v2
 
-    out = compat.tree_map(upd, params, grads, state.m, state.v)
-    new_p = compat.tree_map(lambda t: t[0], out, is_leaf=lambda t:
+    out = jax.tree.map(upd, params, grads, state.m, state.v)
+    new_p = jax.tree.map(lambda t: t[0], out, is_leaf=lambda t:
                          isinstance(t, tuple) and len(t) == 3)
-    new_m = compat.tree_map(lambda t: t[1], out, is_leaf=lambda t:
+    new_m = jax.tree.map(lambda t: t[1], out, is_leaf=lambda t:
                          isinstance(t, tuple) and len(t) == 3)
-    new_v = compat.tree_map(lambda t: t[2], out, is_leaf=lambda t:
+    new_v = jax.tree.map(lambda t: t[2], out, is_leaf=lambda t:
                          isinstance(t, tuple) and len(t) == 3)
     return new_p, AdamWState(step, new_m, new_v), {"lr": lr, "gnorm": gnorm}

@@ -21,7 +21,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.config import ParallelConfig, ShapeSpec
 
 MOE_EXPERT_KEYS = ("wg", "wu", "wd")
@@ -111,7 +110,7 @@ def param_shardings(params, mesh: Mesh, par: ParallelConfig):
         else:
             specs.append(param_spec(pstr, leaf.shape, par, fsdp_size))
     specs = jax.tree_util.tree_unflatten(treedef, specs)
-    return compat.tree_map(lambda s: NamedSharding(mesh, s), specs,
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
                         is_leaf=lambda x: isinstance(x, P))
 
 

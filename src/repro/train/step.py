@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.config import ModelConfig, ShapeSpec, TrainConfig
 from repro.data.pipeline import input_specs
 from repro.models.transformer import Runtime, build_model
@@ -47,11 +46,11 @@ def make_train_step(model, tc: TrainConfig):
         # sync); ``finite`` selects between new and old leaves
         params2, opt2, om = adamw.update(grads, opt_state, params, tc)
         keep = partial(jnp.where, finite)
-        params2 = compat.tree_map(keep, params2, params)
+        params2 = jax.tree.map(keep, params2, params)
         opt2 = adamw.AdamWState(
             step=keep(opt2.step, opt_state.step),
-            m=compat.tree_map(keep, opt2.m, opt_state.m),
-            v=compat.tree_map(keep, opt2.v, opt_state.v))
+            m=jax.tree.map(keep, opt2.m, opt_state.m),
+            v=jax.tree.map(keep, opt2.v, opt_state.v))
         om = {k: keep(v, jnp.zeros_like(v)) for k, v in om.items()}
         return params2, opt2, {"loss": loss, **metrics, **om,
                                "skipped_nonfinite":
@@ -63,7 +62,7 @@ def jit_train_step(model, tc: TrainConfig, params_sh, batch_sh):
     """jit with explicit shardings + donated params/opt."""
     opt_sh = adamw.AdamWState(
         step=NamedSharding(model.rt.mesh, P()),
-        m=params_sh, v=compat.tree_map(lambda s: s, params_sh))
+        m=params_sh, v=jax.tree.map(lambda s: s, params_sh))
     step = make_train_step(model, tc)
     return jax.jit(step,
                    in_shardings=(params_sh, opt_sh, batch_sh),
@@ -96,5 +95,5 @@ def init_sharded(model, tc: TrainConfig, rng):
     opt = jax.jit(adamw.init,
                   out_shardings=adamw.AdamWState(
                       step=NamedSharding(rt.mesh, P()), m=p_sh,
-                      v=compat.tree_map(lambda s: s, p_sh)))(params)
+                      v=jax.tree.map(lambda s: s, p_sh)))(params)
     return params, opt, p_sh

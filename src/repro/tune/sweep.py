@@ -154,13 +154,14 @@ def sweep_kernels(data: dict, *, smoke: bool = False,
 
 _SCHED_CODE = """
 import time, statistics, numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import DistAttnSpec, dist_attn_fwd, zigzag_perm
 SEQS = {seqs!r}
 SCHEDS = {scheds!r}
 REGIMES = {regimes!r}
 ITERS = {iters}
-mesh = jax.make_mesh((1, 8), ("data", "model"))
+mesh = make_mesh((1, 8), ("data", "model"))
 B, H, D = 1, 8, 64
 def timeit(f, *a):
     jax.block_until_ready(f(*a))

@@ -7,7 +7,7 @@ One JSON document holds everything the sweeps measured on one host:
     {
       "schema_version": 1,
       "generated_by": "tools/autotune.py",
-      "host": {"platform": "cpu", "jax": "0.4.37", ...},
+      "host": {"platform": "cpu", "jax": "0.9.0", ...},
       "kernel":   [ {backend, platform, mask_kind, head_dim, seq, op,
                      block_q, block_kv, wall_us, sweep: {"64x64": us, ...}} ],
       "schedule": [ {mask_kind, P, seq, Hq, Hkv, Dqk, best,

@@ -126,10 +126,11 @@ def test_auto_resolution_executes_on_devices(subproc):
     the clean trace-time ValueError, never a mid-execution raise."""
     out = subproc("""
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import DistAttnSpec, dist_flash_attn
 B,N,D = 1,128,16
-mesh = jax.make_mesh((1,8), ("data","model"))
+mesh = make_mesh((1,8), ("data","model"))
 def run(Hq, Hkv, m, seg=None, grad=False):
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (B,N,Hq,D), jnp.float32)
@@ -140,9 +141,10 @@ def run(Hq, Hkv, m, seg=None, grad=False):
         def loss(q,k,v):
             o,_ = dist_flash_attn(q,k,v,mesh,spec,segments=seg,batch_axes=None)
             return jnp.sum(o**2)
-        jax.grad(loss, argnums=(0,1,2))(q,k,v)
+        jax.jit(jax.grad(loss, argnums=(0,1,2)))(q,k,v)
     else:
-        dist_flash_attn(q,k,v,mesh,spec,segments=seg,batch_axes=None)
+        jax.jit(lambda q,k,v: dist_flash_attn(
+            q,k,v,mesh,spec,segments=seg,batch_axes=None))(q,k,v)
 seg = jnp.concatenate([jnp.zeros((B,N//2),jnp.int32),
                        jnp.ones((B,N-N//2),jnp.int32)], axis=1)
 for (Hq,Hkv) in ((16,16),(32,8),(15,5)):

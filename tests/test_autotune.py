@@ -30,6 +30,7 @@ from repro.kernels.registry import block_tuning_kw
 from repro.tune import table as tt
 from repro.tune.calibrate import (fit_nonneg, mask_for_kind,
                                   schedule_features, spearman)
+from repro.launch.mesh import make_mesh
 
 # --------------------------------------------------------------- fixtures
 
@@ -398,7 +399,7 @@ def served():
     from repro.models.transformer import Runtime, build_model
     from repro.parallel.sharding import make_parallel_config
     cfg = smoke_config(get_config("smollm-360m"))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeSpec("tune", 32, 4, "prefill")
     par = make_parallel_config(mesh, shape)
     model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))

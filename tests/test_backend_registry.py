@@ -166,15 +166,56 @@ def test_resolve_name_normalization():
 
 
 def test_resolve_default_roundtrip():
+    """impl=None resolves to the platform's default backend."""
     assert registry.resolve(None).name == registry.default_name()
-    old = registry.default_name()
+    for platform in ("cpu", "gpu", "tpu"):
+        assert (registry.resolve(None, platform=platform).name
+                == registry.default_name(platform))
+
+
+def test_default_backend_follows_platform():
+    assert registry.resolve(None, platform="tpu").name == "pallas"
+    assert registry.resolve(None, platform="cpu").name == "ref"
+    assert registry.default_name("cpu") == "ref"
+    assert registry.resolve(None, platform="tpu", mask=mk.document(),
+                            paged=False).name == "pallas"
+    assert registry.resolve(None, platform="tpu", mask=mk.causal(),
+                            paged=True).name == "pallas"
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_pallas_never_falls_back_to_interpreter(platform):
+    """pallas-interpret runs only when named; no chain reaches it."""
+    for name in registry.names():
+        assert "pallas-interpret" not in registry.get(name).fallback, name
+    for kw in ({}, {"paged": True, "mask": mk.causal()},
+               {"dynamic_offsets": True}):
+        assert registry.resolve("pallas", platform=platform,
+                                **kw).name != "pallas-interpret"
+    assert registry.resolve("pallas-interpret",
+                            platform=platform).name == "pallas-interpret"
+
+
+def test_tpu_fallback_to_exact_compiled_backend_only():
+    # traced offsets: pallas cannot serve them, chunked-lax (compiled) can
+    assert registry.resolve("pallas", platform="tpu",
+                            dynamic_offsets=True).name == "chunked-lax"
+    only_ref = registry.BackendSpec(
+        name="tpu-only-ref-fallback-test", fwd=lambda *a, **k: None,
+        bwd=lambda *a, **k: None, platforms=("tpu",),
+        mask_kinds=frozenset({"causal"}), fallback=("ref",))
+    registry.register(only_ref, overwrite=True)
     try:
-        registry.set_default("chunked-lax")
-        assert registry.resolve(None).name == "chunked-lax"
-        with pytest.raises(ValueError):
-            registry.set_default("bogus")
+        with pytest.raises(ValueError, match="no runnable attention backend"):
+            registry.resolve("tpu-only-ref-fallback-test", platform="tpu",
+                             mask=mk.document())
+        # off the TPU the same chain may end on the oracle
+        assert registry.resolve("tpu-only-ref-fallback-test", platform="cpu",
+                                mask=mk.causal()).name == "ref"
     finally:
-        registry.set_default(old)
+        registry._REGISTRY.pop("tpu-only-ref-fallback-test", None)
+    # naming ref on TPU is a caller's choice, not a fallback
+    assert registry.resolve("ref", platform="tpu").name == "ref"
 
 
 def test_null_backend_is_marked_inexact_and_never_a_fallback():

@@ -41,13 +41,14 @@ from repro.serve.engine import Engine
 from repro.serve.faults import (FAULT_OWNER, KINDS, AuditFailure, FaultEvent,
                                 FaultInjector)
 from repro.serve.scheduler import TERMINAL_STATES
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture(scope="module")
 def served():
     """One smoke model for the whole module (build+init dominates)."""
     cfg = smoke_config(get_config("smollm-360m"))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeSpec("chaos", 24, 4, "prefill")
     par = make_parallel_config(mesh, shape)
     model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))

@@ -15,13 +15,14 @@ from repro.data.pipeline import SyntheticTokens
 from repro.models.transformer import Runtime, build_model
 from repro.parallel.sharding import make_parallel_config
 from repro.serve.engine import Engine, FixedSlotEngine
+from repro.launch.mesh import make_mesh
 
 
 def _setup(arch, window=0, prompt_len=24, batch=3):
     cfg = smoke_config(get_config(arch))
     if window:
         cfg = cfg.replace(attn=dataclasses.replace(cfg.attn, window=window))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeSpec("srv", prompt_len, batch, "prefill")
     par = make_parallel_config(mesh, shape)
     model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))

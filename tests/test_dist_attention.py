@@ -2,15 +2,17 @@
 oracle, on 8 forced host devices (subprocess so the main pytest process
 keeps its single real device)."""
 import pytest
+from repro.launch.mesh import make_mesh
 
 
 def test_schedules_match_oracle(subproc):
     out = subproc("""
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import DistAttnSpec, dist_attn_fwd, dist_flash_attn
 from repro.kernels.ref import full_attn_ref
-mesh = jax.make_mesh((2,4), ("data","model"))
+mesh = make_mesh((2,4), ("data","model"))
 B,N,H,Hkv,D = 4,256,4,2,32
 ks = jax.random.split(jax.random.PRNGKey(0),3)
 q = jax.random.normal(ks[0],(B,N,H,D)); k = jax.random.normal(ks[1],(B,N,Hkv,D)); v = jax.random.normal(ks[2],(B,N,Hkv,D))
@@ -39,10 +41,11 @@ print("OK grads")
 def test_window_and_bidirectional(subproc):
     out = subproc("""
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import DistAttnSpec, dist_attn_fwd
 from repro.kernels.ref import full_attn_ref
-mesh = jax.make_mesh((1,8), ("data","model"))
+mesh = make_mesh((1,8), ("data","model"))
 B,N,H,D = 2,128,2,16
 ks = jax.random.split(jax.random.PRNGKey(1),3)
 q,k,v = (jax.random.normal(kk,(B,N,H,D)) for kk in ks)
@@ -65,10 +68,11 @@ def test_odd_p_schedule(subproc):
     """Odd worker counts (paper: zero idle when P odd) stay exact."""
     out = subproc("""
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import DistAttnSpec, dist_attn_fwd
 from repro.kernels.ref import full_attn_ref
-mesh = jax.make_mesh((1,7), ("data","model"))
+mesh = make_mesh((1,7), ("data","model"))
 B,N,H,D = 2,7*16,2,16
 ks = jax.random.split(jax.random.PRNGKey(2),3)
 q,k,v = (jax.random.normal(kk,(B,N,H,D)) for kk in ks)
@@ -87,10 +91,11 @@ def test_block_tuning_hints_through_schedules(subproc):
     backward, with and without a sliding window."""
     out = subproc("""
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import DistAttnSpec, dist_flash_attn
 from repro.kernels.ref import full_attn_ref
-mesh = jax.make_mesh((1,4), ("data","model"))
+mesh = make_mesh((1,4), ("data","model"))
 B,N,H,D = 1,256,2,16
 ks = jax.random.split(jax.random.PRNGKey(5),3)
 q,k,v = (jax.random.normal(kk,(B,N,H,D)) for kk in ks)
@@ -116,10 +121,11 @@ for sched, window in [("balanced",0), ("ring",40)]:
 def test_decode_attention(subproc):
     out = subproc("""
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import dist_decode_attn
 from repro.kernels.ref import chunk_attn_ref
-mesh = jax.make_mesh((2,4), ("data","model"))
+mesh = make_mesh((2,4), ("data","model"))
 B,N,H,Hkv,D = 4,256,4,2,32
 ks = jax.random.split(jax.random.PRNGKey(0),6)
 k = jax.random.normal(ks[1],(B,N,Hkv,D)); v = jax.random.normal(ks[2],(B,N,Hkv,D))
@@ -144,6 +150,7 @@ def test_models_distributed_match_single(subproc):
     smoke matrix checked visually during bring-up, now locked in)."""
     out = subproc("""
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core.config import ARCH_IDS, get_config, smoke_config, ShapeSpec
 from repro.models.transformer import Runtime, build_model
 from repro.parallel.sharding import make_parallel_config
@@ -153,7 +160,7 @@ for arch in ["smollm-360m", "deepseek-v2-lite-16b", "zamba2-2.7b", "whisper-tiny
     cfg = smoke_config(get_config(arch))
     losses = {}
     for (d, s) in [(1, 1), (2, 4)]:
-        mesh = jax.make_mesh((d, s), ("data", "model"))
+        mesh = make_mesh((d, s), ("data", "model"))
         par = make_parallel_config(mesh, shape)
         model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))
         params = model.init(jax.random.PRNGKey(0))
@@ -171,11 +178,12 @@ def test_zigzag_and_ulysses(subproc):
     """Beyond-paper zigzag placement and the Ulysses baseline are exact."""
     out = subproc("""
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import (DistAttnSpec, dist_attn_fwd,
                                        dist_flash_attn, zigzag_perm)
 from repro.kernels.ref import full_attn_ref
-mesh = jax.make_mesh((1,8), ("data","model"))
+mesh = make_mesh((1,8), ("data","model"))
 B,N,H,Hkv,D = 2,512,4,2,32
 ks = jax.random.split(jax.random.PRNGKey(0),3)
 q = jax.random.normal(ks[0],(B,N,H,D)); k = jax.random.normal(ks[1],(B,N,Hkv,D)); v = jax.random.normal(ks[2],(B,N,Hkv,D))
@@ -219,11 +227,12 @@ def test_cross_schedule_golden(subproc, P):
     distributed schedules."""
     out = subproc(f"""
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import DistAttnSpec, dist_attn_fwd
 from repro.kernels.ref import full_attn_ref
 P = {P}
-mesh = jax.make_mesh((1, P), ("data", "model"))
+mesh = make_mesh((1, P), ("data", "model"))
 B, H, Hkv, D = 2, 4, 2, 16
 N = P * 32
 ks = jax.random.split(jax.random.PRNGKey(3), 3)
@@ -257,12 +266,13 @@ def test_mla_latent_ring_prefill(subproc):
     """Latent-ring MLA prefill == materialized-KV prefill (model level)."""
     out = subproc("""
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core.config import get_config, smoke_config, ShapeSpec
 from repro.models.transformer import Runtime, build_model
 from repro.parallel.sharding import make_parallel_config
 from repro.data.pipeline import SyntheticTokens
 cfg = smoke_config(get_config("deepseek-v2-lite-16b"))
-mesh = jax.make_mesh((2,4), ("data","model"))
+mesh = make_mesh((2,4), ("data","model"))
 shape = ShapeSpec("z", 64, 4, "prefill")
 outs = {}
 for name, sched, lat in [("base","balanced",False), ("latent","zigzag",True)]:
@@ -345,7 +355,7 @@ def test_spec_validation_and_removed_kwargs():
     # the decode entry point's window= kwarg is removed too
     import jax
     import jax.numpy as jnp
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     z4 = jnp.zeros((1, 1, 2, 8))
     zc = jnp.zeros((1, 4, 2, 8))
     with pt.raises(TypeError, match=r"dist_decode_attn\(window=\) was "
@@ -384,11 +394,12 @@ def test_document_mask_all_schedules(subproc):
     ring alongside KV. Boundaries intentionally do not align with shards."""
     out = subproc("""
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import (DistAttnSpec, dist_attn_fwd,
                                        dist_flash_attn, zigzag_perm)
 from repro.kernels.ref import full_attn_ref
-mesh = jax.make_mesh((1,8), ("data","model"))
+mesh = make_mesh((1,8), ("data","model"))
 B,N,H,Hkv,D = 2,512,4,2,32
 ks = jax.random.split(jax.random.PRNGKey(0),3)
 q = jax.random.normal(ks[0],(B,N,H,D)); k = jax.random.normal(ks[1],(B,N,Hkv,D)); v = jax.random.normal(ks[2],(B,N,Hkv,D))
@@ -441,10 +452,11 @@ def test_windowed_decode_vs_bruteforce(subproc):
     sharding."""
     out = subproc("""
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import dist_decode_attn
 from repro.kernels.ref import chunk_attn_ref
-mesh = jax.make_mesh((2,4), ("data","model"))
+mesh = make_mesh((2,4), ("data","model"))
 B,N,H,Hkv,D = 2,256,4,2,32
 ks = jax.random.split(jax.random.PRNGKey(3),6)
 k = jax.random.normal(ks[1],(B,N,Hkv,D)); v = jax.random.normal(ks[2],(B,N,Hkv,D))
@@ -471,9 +483,10 @@ def test_ulysses_head_divisibility_error_paths(subproc):
     both divide P."""
     out = subproc("""
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import DistAttnSpec, dist_attn_fwd
-mesh = jax.make_mesh((1,4), ("data","model"))
+mesh = make_mesh((1,4), ("data","model"))
 B,N,D = 1,128,16
 spec = DistAttnSpec(axis="model", axis_size=4, schedule="ulysses", mask=mk.causal())
 def run(Hq, Hkv):

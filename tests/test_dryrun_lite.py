@@ -7,7 +7,6 @@ import pytest
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.analysis import roofline as R
 from repro.core.config import (ShapeSpec, TrainConfig, get_config,
                                smoke_config)
@@ -16,6 +15,7 @@ from repro.models.transformer import Runtime, build_model
 from repro.optim import adamw
 from repro.parallel.sharding import make_parallel_config, param_shardings
 from repro.train.step import make_train_step
+from repro.launch.mesh import make_mesh
 
 
 @pytest.mark.parametrize("arch,shape_kind", [
@@ -25,14 +25,14 @@ from repro.train.step import make_train_step
 ])
 def test_lower_compile_and_analyses(arch, shape_kind):
     cfg = smoke_config(get_config(arch))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeSpec("lite", 64, 2, shape_kind)
     par = make_parallel_config(mesh, shape)
     model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))
     p_struct = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
     p_sh = param_shardings(p_struct, mesh, par)
     batch_struct, batch_spec = input_specs(cfg, shape, par, mesh)
-    batch_sh = compat.tree_map(lambda s: NamedSharding(mesh, s), batch_spec,
+    batch_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), batch_spec,
                             is_leaf=lambda x: isinstance(x, P))
     if shape_kind == "train":
         step = make_train_step(model, TrainConfig())
@@ -48,7 +48,7 @@ def test_lower_compile_and_analyses(arch, shape_kind):
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 0
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     assert cost.get("flops", 0) > 0
 
 

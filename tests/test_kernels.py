@@ -172,28 +172,6 @@ def test_pruned_chunked_lax_matches_ref(case):
         assert jnp.allclose(c_, d_, atol=1e-6)
 
 
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="compiled Mosaic lowering needs TPU hardware")
-@pytest.mark.parametrize("case", PRUNE_CASES[:3], ids=_prune_ids)
-def test_pruned_flash_compiles_on_tpu(case):
-    """CI validates the pruned kernels under interpret=True only; on real
-    TPU this exercises the compiled lowering of the in-kernel lax.cond,
-    the narrow (1,1,br) lse/delta blocks, and the index-map remapping."""
-    B, Tq, Tk, Hq, Hkv, D, causal, rel, window, bq, bk = case
-    q, k, v, do = _mk(B, Tq, Tk, Hq, Hkv, D, jnp.float32)
-    kw = dict(causal=causal, rel_offset=rel, window=window, block_q=bq,
-              block_kv=bk)
-    o_r, lse_r = chunk_attn_ref(q, k, v, causal=causal, q_offset=rel,
-                                window=window)
-    o_p, lse_p = ops.flash_fwd(q, k, v, **kw)
-    assert jnp.allclose(o_r, o_p, atol=1e-5, rtol=1e-5)
-    ref = chunk_attn_bwd_ref(q, k, v, o_r, lse_r, do, causal=causal,
-                             q_offset=rel, window=window)
-    pal = ops.flash_bwd(q, k, v, o_p, lse_p, do, **kw)
-    for r, p_ in zip(ref, pal):
-        assert jnp.allclose(r, p_, atol=2e-4, rtol=2e-4)
-
-
 def test_pruned_grid_is_smaller_where_mask_allows():
     """The windowed regimes actually shrink the sequential grid dimension
     (not just skip compute): seq_grid < nk."""

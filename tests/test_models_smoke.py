@@ -5,7 +5,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import compat
 from repro.core.config import (ARCH_IDS, TrainConfig, get_config,
                                smoke_config, ShapeSpec)
 from repro.data.pipeline import SyntheticTokens, cache_specs
@@ -13,6 +12,7 @@ from repro.models.transformer import Runtime, build_model
 from repro.optim import adamw
 from repro.parallel.sharding import make_parallel_config
 from repro.train.step import make_train_step
+from repro.launch.mesh import make_mesh
 
 SHAPE = ShapeSpec("smoke", 64, 2, "train")
 
@@ -26,7 +26,7 @@ ARCH_PARAMS = [pytest.param(a, marks=pytest.mark.slow) if a in SLOW_ARCHS
 
 def _setup(arch):
     cfg = smoke_config(get_config(arch))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     par = make_parallel_config(mesh, SHAPE)
     model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))
     params = model.init(jax.random.PRNGKey(0))
@@ -75,7 +75,7 @@ def test_prefill_and_decode_shapes(arch):
     dshape = ShapeSpec("smoke_dec", 64, B, "decode")
     dpar = make_parallel_config(mesh, dshape)
     cstruct, _ = cache_specs(cfg, dshape, dpar)
-    cache = compat.tree_map(lambda s: jnp.zeros(s.shape, s.dtype), cstruct)
+    cache = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), cstruct)
     dmodel = build_model(cfg, Runtime(mesh=mesh, par=dpar, impl="ref"))
     lg, cache2 = jax.jit(dmodel.decode)(
         params, cache, {"token": jnp.zeros((B, 1), jnp.int32),

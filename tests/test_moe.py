@@ -9,6 +9,7 @@ from hypothesis import given, settings, strategies as st
 from repro.core.config import ModelConfig, MoEConfig
 from repro.models.layers import rms_norm
 from repro.models.moe import moe_params, moe_apply, moe_decode_apply
+from repro.launch.mesh import make_mesh
 
 
 def _cfg(n_routed=8, n_shared=2, top_k=2, cap=8.0):
@@ -37,7 +38,7 @@ def _dense_ref(p, x, cfg):
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_moe_matches_dense_reference(mesh):

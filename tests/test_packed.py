@@ -1,7 +1,7 @@
 """Packed-sequence (document-masked) training, end to end: the data
 pipeline's packed batches, the model's document masking, and the train step
 — differentially against unpacked/per-document oracles. These are the
-tier-1 "packed differential" tests CI runs under both JAX versions."""
+tier-1 "packed differential" tests CI runs as a named step."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,10 +13,11 @@ from repro.core.config import (ShapeSpec, TrainConfig, get_config,
 from repro.data.pipeline import SyntheticTokens, input_specs
 from repro.models.transformer import Runtime, build_model
 from repro.parallel.sharding import make_parallel_config
+from repro.launch.mesh import make_mesh
 
 
 def _mesh1():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_doc_boundaries_layout():
@@ -165,6 +166,7 @@ def test_packed_distributed_matches_single(subproc):
     batches are exact under every sequence-parallel schedule."""
     out = subproc("""
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core.config import get_config, smoke_config, ShapeSpec
 from repro.data.pipeline import SyntheticTokens
 from repro.models.transformer import Runtime, build_model
@@ -173,7 +175,7 @@ cfg = smoke_config(get_config("smollm-360m"))
 shape = ShapeSpec("packed", 128, 4, "train", docs=4)
 vals = {}
 for (d, s, sched) in [(1,1,"balanced"), (2,4,"balanced"), (1,8,"ring"), (1,8,"zigzag")]:
-    mesh = jax.make_mesh((d, s), ("data", "model"))
+    mesh = make_mesh((d, s), ("data", "model"))
     par = make_parallel_config(mesh, shape, schedule=sched)
     model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))
     params = model.init(jax.random.PRNGKey(0))

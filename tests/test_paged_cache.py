@@ -26,6 +26,7 @@ from repro.core import mask as mk
 from repro.core.attention import paged_decode_attn
 from repro.kernels import registry
 from repro.serve.cache import BlockAllocator, PagedKVCache, PoolExhausted
+from repro.launch.mesh import make_mesh
 
 TOL = 2e-5
 
@@ -220,7 +221,7 @@ def test_dense_decode_per_request_positions():
     from repro.core.dist_attention import dist_decode_attn
     rng = np.random.default_rng(3)
     B, S, Hq, D = 3, 24, 4, 16
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     pos = np.array([5, 17, 24], np.int64)
     q = jnp.asarray(rng.standard_normal((B, 1, Hq, D)), jnp.float32)
     kc = jnp.asarray(rng.standard_normal((B, S, Hq, D)), jnp.float32)
@@ -255,7 +256,7 @@ def test_scalar_pos_shim_warns_once():
     from repro.core.dist_attention import dist_decode_attn
     site = "dist_decode_attn(pos=<scalar>)"
     mkm._DEPRECATION_WARNED.discard(site)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     z = jnp.zeros((2, 1, 2, 4))
     zc = jnp.zeros((2, 8, 2, 4))
     with warnings.catch_warnings(record=True) as w:
@@ -297,6 +298,7 @@ def test_paged_decode_8dev_sharded_pool(subproc):
     replicated single-mesh math to fp32 tolerance."""
     out = subproc("""
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import mask as mk
 from repro.core.attention import paged_decode_attn
@@ -310,7 +312,7 @@ bt = jnp.asarray(rng.permutation(np.arange(1, N))[:B*nb].reshape(B,nb),
 lens = jnp.asarray([3, 9, 17, 31], jnp.int32)
 mask = mk.sliding_window(13)
 o_local = paged_decode_attn(q, kp, vp, bt, lens, mask=mask, impl="ref")
-mesh = jax.make_mesh((1, 8), ("data", "model"))
+mesh = make_mesh((1, 8), ("data", "model"))
 sh = NamedSharding(mesh, P("model", None, None, None))
 kp_s, vp_s = jax.device_put(kp, sh), jax.device_put(vp, sh)
 f = jax.jit(lambda *a: paged_decode_attn(*a, mask=mask, impl="ref"))

@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.core import mask as mk
 from repro.core.attention import chunk_attn, chunk_attn_bwd
 from repro.core.remat import apply_policy, remat_aware
@@ -85,7 +84,7 @@ def test_remat_aware_saves_fa_forward_flops():
 
     def gflops(f):
         g = jax.jit(jax.grad(lambda p, x: jnp.sum(f(p, x) ** 2)))
-        return compat.cost_analysis(g.lower(params, x).compile())["flops"]
+        return g.lower(params, x).compile().cost_analysis()["flops"]
 
     f_none = gflops(plain)
     f_hf = gflops(apply_policy(plain, "hf"))

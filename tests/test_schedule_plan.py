@@ -184,12 +184,12 @@ def test_plans_match_seed_implementations(subproc):
     backward — for ring/balanced/zigzag × causal/windowed/document."""
     out = subproc("""
 import numpy as np, jax, jax.numpy as jnp
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core import legacy_schedules as LS
 from repro.core.dist_attention import (DistAttnSpec, dist_attn_fwd,
                                        dist_attn_bwd, zigzag_perm)
-mesh = jax.make_mesh((1,8), ("data","model"))
+mesh = make_mesh((1,8), ("data","model"))
 PS = jax.sharding.PartitionSpec
 B,N,H,Hkv,D = 2,512,4,2,32
 ks = jax.random.split(jax.random.PRNGKey(0),4)
@@ -200,8 +200,8 @@ seg = jnp.asarray(np.tile(mk.segments_from_boundaries(N, bnd), (B,1)))
 perm = zigzag_perm(N, 8)
 qs = PS(None,"model",None,None); ls = PS(None,"model",None); gs = PS(None,"model")
 def smap(f, ins, outs):
-    return compat.shard_map(f, mesh=mesh, in_specs=ins, out_specs=outs,
-                            check_vma=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=ins, out_specs=outs,
+                         check_vma=False)
 cases = [
     ("ring", LS._fwd_ring, LS._bwd_ring, mk.causal(), False, False),
     ("ring", LS._fwd_ring, LS._bwd_ring, mk.sliding_window(100), False, False),
@@ -254,12 +254,13 @@ def test_windowed_balanced_zigzag_vs_oracle(subproc):
     ring steps than their causal plans."""
     out = subproc("""
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core import schedule as sp
 from repro.core.dist_attention import (DistAttnSpec, dist_flash_attn,
                                        zigzag_perm)
 from repro.kernels.ref import full_attn_ref
-mesh = jax.make_mesh((1,8), ("data","model"))
+mesh = make_mesh((1,8), ("data","model"))
 B,N,H,Hkv,D = 2,512,4,2,32
 ks = jax.random.split(jax.random.PRNGKey(1),3)
 q = jax.random.normal(ks[0],(B,N,H,D)); k = jax.random.normal(ks[1],(B,N,Hkv,D))
@@ -302,11 +303,12 @@ def test_boundary_documents_on_ring_family(subproc):
     the static layout — matching the segment-array oracle, fwd + grads."""
     out = subproc("""
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import (DistAttnSpec, dist_flash_attn,
                                        zigzag_perm)
 from repro.kernels.ref import full_attn_ref
-mesh = jax.make_mesh((1,8), ("data","model"))
+mesh = make_mesh((1,8), ("data","model"))
 B,N,H,Hkv,D = 2,512,4,2,32
 ks = jax.random.split(jax.random.PRNGKey(2),3)
 q = jax.random.normal(ks[0],(B,N,H,D)); k = jax.random.normal(ks[1],(B,N,Hkv,D))
@@ -343,11 +345,12 @@ def test_auto_schedule_resolution(subproc):
     backward exists) and raises nowhere the explicit names succeed."""
     out = subproc("""
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import (DistAttnSpec, dist_attn_fwd,
                                        dist_flash_attn)
 from repro.kernels.ref import full_attn_ref
-mesh = jax.make_mesh((1,8), ("data","model"))
+mesh = make_mesh((1,8), ("data","model"))
 B,N,H,D = 2,512,8,32
 ks = jax.random.split(jax.random.PRNGKey(3),3)
 q,k,v = (jax.random.normal(kk,(B,N,H,D)) for kk in ks)
@@ -396,10 +399,11 @@ def test_single_device_mesh_plan_paths(subproc):
     collapses to the local kernel with identical results."""
     out = subproc("""
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import DistAttnSpec, dist_attn_fwd
 from repro.kernels.ref import full_attn_ref
-mesh = jax.make_mesh((1,1), ("data","model"))
+mesh = make_mesh((1,1), ("data","model"))
 B,N,H,D = 2,128,4,16
 ks = jax.random.split(jax.random.PRNGKey(4),3)
 q,k,v = (jax.random.normal(kk,(B,N,H,D)) for kk in ks)
@@ -561,11 +565,12 @@ def test_plans2d_match_1d_and_oracle(subproc):
     (4, 2) factorizations of the 8-device host mesh."""
     out = subproc("""
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import DistAttnSpec, Mesh2DSpec, dist_flash_attn
 from repro.core.attention import chunk_attn
 B,N,D = 2,512,32
-mesh1 = jax.make_mesh((1,8), ("data","model"))
+mesh1 = make_mesh((1,8), ("data","model"))
 key = jax.random.PRNGKey(0)
 for (Hq,Hkv) in ((4,4),(4,2)):
     ks = jax.random.split(key, 4)
@@ -584,18 +589,20 @@ for (Hq,Hkv) in ((4,4),(4,2)):
         def loss1(q,k,v):
             o,_ = dist_flash_attn(q,k,v,mesh1,spec1,batch_axes=None)
             return jnp.sum(o*do)
-        o1,_ = dist_flash_attn(q,k,v,mesh1,spec1,batch_axes=None)
-        g1 = jax.grad(loss1, argnums=(0,1,2))(q,k,v)
+        o1,_ = jax.jit(lambda q,k,v: dist_flash_attn(
+            q,k,v,mesh1,spec1,batch_axes=None))(q,k,v)
+        g1 = jax.jit(jax.grad(loss1, argnums=(0,1,2)))(q,k,v)
         for (r,u) in ((2,4),(4,2)):
-            mesh2 = jax.make_mesh((1,r,u), ("data","seq","head"))
+            mesh2 = make_mesh((1,r,u), ("data","seq","head"))
             sched = "balanced" if m.causal else "ring"
             spec2 = DistAttnSpec(axis="seq", axis_size=8, schedule=sched,
                                  mask=m, mesh2d=Mesh2DSpec(r=r,u=u))
             def loss2(q,k,v):
                 o,_ = dist_flash_attn(q,k,v,mesh2,spec2,batch_axes=None)
                 return jnp.sum(o*do)
-            o2,_ = dist_flash_attn(q,k,v,mesh2,spec2,batch_axes=None)
-            g2 = jax.grad(loss2, argnums=(0,1,2))(q,k,v)
+            o2,_ = jax.jit(lambda q,k,v: dist_flash_attn(
+                q,k,v,mesh2,spec2,batch_axes=None))(q,k,v)
+            g2 = jax.jit(jax.grad(loss2, argnums=(0,1,2)))(q,k,v)
             eo = max(float(jnp.max(jnp.abs(o2-o_ref))),
                      float(jnp.max(jnp.abs(o2-o1))))
             eg = max(max(float(jnp.max(jnp.abs(a-b))) for a,b in zip(g2,g_ref)),
@@ -614,6 +621,7 @@ def test_plan2d_r1_zigzag_and_auto(subproc):
     on a 2D spec resolves an inner schedule that runs."""
     out = subproc("""
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import mask as mk
 from repro.core.dist_attention import (DistAttnSpec, Mesh2DSpec,
                                        dist_flash_attn, zigzag_perm)
@@ -630,14 +638,15 @@ def check(label, m, r, u, sched):
         return jnp.sum(o*do)
     o_ref,_ = chunk_attn(q,k,v,mask=m,impl="ref")
     g_ref = jax.grad(loss_ref, argnums=(0,1,2))(q,k,v)
-    mesh = jax.make_mesh((1,r,u), ("data","seq","head"))
+    mesh = make_mesh((1,r,u), ("data","seq","head"))
     spec = DistAttnSpec(axis="seq", axis_size=8, schedule=sched,
                         mask=m, mesh2d=Mesh2DSpec(r=r,u=u))
     def loss(q,k,v):
         o,_ = dist_flash_attn(q,k,v,mesh,spec,batch_axes=None)
         return jnp.sum(o*do)
-    o,_ = dist_flash_attn(q,k,v,mesh,spec,batch_axes=None)
-    g = jax.grad(loss, argnums=(0,1,2))(q,k,v)
+    o,_ = jax.jit(lambda q,k,v: dist_flash_attn(
+        q,k,v,mesh,spec,batch_axes=None))(q,k,v)
+    g = jax.jit(jax.grad(loss, argnums=(0,1,2)))(q,k,v)
     eo = float(jnp.max(jnp.abs(o-o_ref)))
     eg = max(float(jnp.max(jnp.abs(a-b))) for a,b in zip(g,g_ref))
     assert eo < 5e-5 and eg < 5e-5, (label, eo, eg)
@@ -650,11 +659,12 @@ check("auto r2u4 windowed", mk.sliding_window(60), 2, 4, "auto")
 r, u = 4, 2
 perm = zigzag_perm(N, r); inv = np.argsort(perm)
 m = mk.causal()
-mesh = jax.make_mesh((1,r,u), ("data","seq","head"))
+mesh = make_mesh((1,r,u), ("data","seq","head"))
 spec = DistAttnSpec(axis="seq", axis_size=8, schedule="zigzag",
                     mask=m, mesh2d=Mesh2DSpec(r=r,u=u))
 o_ref,_ = chunk_attn(q,k,v,mask=m,impl="ref")
-o2p,_ = dist_flash_attn(q[:,perm],k[:,perm],v[:,perm],mesh,spec,batch_axes=None)
+o2p,_ = jax.jit(lambda q,k,v: dist_flash_attn(
+    q,k,v,mesh,spec,batch_axes=None))(q[:,perm],k[:,perm],v[:,perm])
 assert float(jnp.max(jnp.abs(o2p[:,inv]-o_ref))) < 5e-5
 print("OK zigzag2d r4u2")
 """)

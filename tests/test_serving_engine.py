@@ -18,6 +18,7 @@ from repro.data.pipeline import SyntheticTokens
 from repro.models.transformer import Runtime, build_model
 from repro.parallel.sharding import make_parallel_config
 from repro.serve.engine import Engine, FixedSlotEngine
+from repro.launch.mesh import make_mesh
 
 
 def _setup(arch, window=0, prompt_len=24, batch=3):
@@ -25,7 +26,7 @@ def _setup(arch, window=0, prompt_len=24, batch=3):
     cfg = smoke_config(get_config(arch))
     if window:
         cfg = cfg.replace(attn=dataclasses.replace(cfg.attn, window=window))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeSpec("srv", prompt_len, batch, "prefill")
     par = make_parallel_config(mesh, shape)
     model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))
@@ -412,13 +413,14 @@ def test_engine_8dev_batch_invariance(subproc):
     runs on the same mesh."""
     out = subproc("""
 import numpy as np, jax
+from repro.launch.mesh import make_mesh
 from repro.core.config import ShapeSpec, get_config, smoke_config
 from repro.data.pipeline import SyntheticTokens
 from repro.models.transformer import Runtime, build_model
 from repro.parallel.sharding import make_parallel_config
 from repro.serve.engine import Engine
 cfg = smoke_config(get_config("qwen3-8b"))
-mesh = jax.make_mesh((1, 8), ("data", "model"))
+mesh = make_mesh((1, 8), ("data", "model"))
 shape = ShapeSpec("srv", 32, 2, "prefill")
 par = make_parallel_config(mesh, shape)
 model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))

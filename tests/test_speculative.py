@@ -30,13 +30,14 @@ from repro.parallel.sharding import make_parallel_config
 from repro.serve.engine import Engine
 from repro.serve.speculative import (ModelDraft, NGramDraft, NullDraft,
                                      SpecConfig, make_draft)
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture(scope="module")
 def served():
     """One smoke model for the whole module (build+init dominates)."""
     cfg = smoke_config(get_config("smollm-360m"))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeSpec("spec", 32, 4, "prefill")
     par = make_parallel_config(mesh, shape)
     model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))

@@ -9,6 +9,7 @@ from hypothesis import given, settings, strategies as st
 from repro.core.config import ModelConfig, SSMConfig
 from repro.models.ssm import (ssm_apply, ssm_decode_step, ssm_params,
                               ssm_sequential_ref, _ssd_chunked)
+from repro.launch.mesh import make_mesh
 
 
 def _cfg(d_state=16, head_dim=8, chunk=8, d_model=32):
@@ -20,14 +21,14 @@ def _cfg(d_state=16, head_dim=8, chunk=8, d_model=32):
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @settings(max_examples=6, deadline=None)
 @given(seed=st.integers(0, 100), chunk=st.sampled_from([4, 8, 16, 64]))
 def test_chunked_matches_sequential(seed, chunk):
     cfg = _cfg(chunk=chunk)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     p = ssm_params(jax.random.PRNGKey(seed), cfg, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(seed + 1), (2, 64, 32)) * 0.5
     y_ref = ssm_sequential_ref(p, x, cfg)

@@ -13,6 +13,7 @@ from repro.data.pipeline import SyntheticTokens
 from repro.io import checkpoint as ckpt
 from repro.optim import adamw
 from repro.parallel.sharding import make_parallel_config, param_shardings
+from repro.launch.mesh import make_mesh
 
 
 def test_adamw_converges_on_quadratic():
@@ -39,7 +40,7 @@ def test_synthetic_data_deterministic_and_learnable():
     cfg = get_config("smollm-360m")
     from repro.core.config import smoke_config, ShapeSpec
     cfg = smoke_config(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeSpec("s", 32, 2, "train")
     par = make_parallel_config(mesh, shape)
     ds = SyntheticTokens(cfg, shape, par, mesh, seed=7)
@@ -70,7 +71,7 @@ def test_checkpoint_roundtrip(tmp_path):
 
 
 def test_parallel_config_resolution():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     class FakeMesh:
         axis_names = ("pod", "data", "model")
@@ -91,7 +92,7 @@ def test_param_shardings_cover_all_leaves():
     from repro.core.config import smoke_config, ShapeSpec
     from repro.models.transformer import Runtime, build_model
     cfg = smoke_config(get_config("deepseek-v2-lite-16b"))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     par = make_parallel_config(mesh, ShapeSpec("s", 32, 2, "train"))
     model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))
     ps = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))

@@ -13,11 +13,12 @@ from repro.models.transformer import Runtime, build_model
 from repro.optim import adamw
 from repro.parallel.sharding import make_parallel_config
 from repro.train.step import make_train_step
+from repro.launch.mesh import make_mesh
 
 
 def _train(arch, steps=30, remat="remat_aware", schedule="balanced"):
     cfg = smoke_config(get_config(arch)).replace(vocab=128)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeSpec("ti", 64, 4, "train")
     par = make_parallel_config(mesh, shape, schedule=schedule, remat=remat)
     model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))
@@ -68,7 +69,7 @@ def test_nonfinite_step_is_skipped_params_bit_identical():
     optimizer state bit-identical; a healthy step afterwards updates
     normally."""
     cfg = smoke_config(get_config("smollm-360m")).replace(vocab=128)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeSpec("ti", 64, 4, "train")
     par = make_parallel_config(mesh, shape)
     model = build_model(cfg, Runtime(mesh=mesh, par=par, impl="ref"))
