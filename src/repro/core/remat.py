@@ -78,8 +78,9 @@ def remat_aware(pre_attn: Callable, attn_fwd: Callable, attn_bwd: Callable,
     def layer_bwd(res, dy):
         params, x, o, lse = res
         # recompute the cheap stages under vjp; attention fwd is NOT rerun
-        qkv, pre_vjp = jax.vjp(pre_attn, params, x)
-        _y, post_vjp = jax.vjp(post_attn, params, x, o)
+        with jax.named_scope("recompute"):
+            qkv, pre_vjp = jax.vjp(pre_attn, params, x)
+            _y, post_vjp = jax.vjp(post_attn, params, x, o)
         dparams2, dx2, do = post_vjp(dy)
         dqkv = attn_bwd(qkv, o, lse, do)
         dparams1, dx1 = pre_vjp(dqkv)

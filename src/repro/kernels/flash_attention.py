@@ -162,6 +162,21 @@ def _stat_operand(x):
     return x.astype(jnp.float32)[:, :, None, :]
 
 
+def _grid_work(prof, pruned, n):
+    """``metadata=`` of a flash call over ``n`` (batch, head) pairs: the
+    grid steps it launches and the steps whose compute body runs. Pruned,
+    the grid is the profile's shrunk one and the body runs where the
+    static mask reaches the tile; unpruned, the body runs on every step
+    of the full grid. Tiles that only dynamic segment IDs mask still run
+    (their scores are masked), so they count as executed. The compiled
+    call carries the counts as ``kernel_metadata``, so a trace event can
+    be matched to its own call's counts."""
+    if pruned:
+        return {"launched": n * prof.launched_steps,
+                "executed": n * prof.executed_steps}
+    return {"launched": n * prof.full_steps, "executed": n * prof.full_steps}
+
+
 # ---------------------------------------------------------------- forward
 
 
@@ -234,8 +249,9 @@ def flash_fwd_bhtd(q, k, v, *, scale, mask: MaskSpec, block_q=128,
     has_segs = _check_segs(mask, q_segments, kv_segments)
 
     seq = nk
-    if prune and mask.prunable:
-        prof = kv_profile(nq=nq, nk=nk, br=br, bc=bc, mask=mask)
+    pruned = prune and mask.prunable
+    prof = kv_profile(nq=nq, nk=nk, br=br, bc=bc, mask=mask)
+    if pruned:
         seq = prof.seq_grid
         if seq == 0:                      # statically fully masked chunk
             return (jnp.zeros((B, Hq, Tq, Dv), q.dtype),
@@ -280,6 +296,8 @@ def flash_fwd_bhtd(q, k, v, *, scale, mask: MaskSpec, block_q=128,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
+        metadata=_grid_work(prof, pruned, B * Hq),
     )(*operands)
     return o, lse.reshape(B, Hq, Tq)
 
@@ -395,10 +413,11 @@ def flash_bwd_bhtd(q, k, v, o, lse, do, *, scale, mask: MaskSpec,
     stats = [_stat_operand(lse), _stat_operand(delta)]
 
     pruned = prune and mask.prunable
+    kv_prof = kv_profile(nq=nq, nk=nk, br=br, bc=bc, mask=mask)
+    q_prof = q_profile(nq=nq, nk=nk, br=br, bc=bc, mask=mask)
     seq_kv, seq_q = nk, nq
     if pruned:
-        seq_kv = kv_profile(nq=nq, nk=nk, br=br, bc=bc, mask=mask).seq_grid
-        seq_q = q_profile(nq=nq, nk=nk, br=br, bc=bc, mask=mask).seq_grid
+        seq_kv, seq_q = kv_prof.seq_grid, q_prof.seq_grid
     if pruned and (seq_kv == 0 or seq_q == 0):   # statically fully masked
         return (jnp.zeros(q.shape, q.dtype),
                 jnp.zeros((B, Hkv, Tk, D), k.dtype),
@@ -432,6 +451,8 @@ def flash_bwd_bhtd(q, k, v, o, lse, do, *, scale, mask: MaskSpec,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_bwd.dq",
+        metadata=_grid_work(kv_prof, pruned, B * Hq),
     )(q, k, v, do, *stats, *seg_ops)
 
     # dkv: grid over kv blocks, sequential over the valid q blocks. Output
@@ -467,6 +488,8 @@ def flash_bwd_bhtd(q, k, v, o, lse, do, *, scale, mask: MaskSpec,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_bwd.dkv",
+        metadata=_grid_work(q_prof, pruned, B * Hq),
     )(q, k, v, do, *stats, *seg_ops)
     if g > 1:
         dk_h = dk_h.reshape(B, Hkv, g, Tk, D).sum(axis=2)

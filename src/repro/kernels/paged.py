@@ -269,6 +269,7 @@ def paged_attn_pallas(q, k_pool, v_pool, block_table, lengths, *, mask=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attn",
     )(jnp.asarray(block_table, jnp.int32), jnp.asarray(lengths, jnp.int32),
       q_r, k_r, v_r)
     return (o.reshape(B, Hkv, g, Tq, Dv).transpose(0, 3, 1, 2, 4)

@@ -105,7 +105,6 @@ def train(args: argparse.Namespace) -> List[dict]:
           f"schedule={args.schedule} remat={args.remat}")
 
     records = []
-    t0 = time.time()
     n_skipped = 0
     for i in range(args.steps):
         batch = tr.data.batch(i)
@@ -126,10 +125,12 @@ def train(args: argparse.Namespace) -> List[dict]:
             n_skipped += 1
         if i % args.log_every == 0 or i == args.steps - 1:
             r = records[-1]
-            dt = time.time() - t0
-            tok_s = (i + 1) * args.batch * args.seq / max(dt, 1e-9)
+            # steps 1 onward only: step 0's seconds hold the compile
+            warm = sum(rec["seconds"] for rec in records[1:])
+            tok_s = (f"{i * args.batch * args.seq / warm:.0f}" if warm > 0
+                     else "-")
             print(f"step {i:5d} loss {r['loss']:.4f} lr {r['lr']:.2e} "
-                  f"gnorm {r['gnorm']:.2f} tok/s {tok_s:.0f}"
+                  f"gnorm {r['gnorm']:.2f} tok/s {tok_s}"
                   + (f" skipped {n_skipped}" if n_skipped else ""),
                   flush=True)
         if args.ckpt_dir and args.ckpt_every and \

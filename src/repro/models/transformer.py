@@ -164,11 +164,15 @@ def _dense_stages(cfg, rt, is_mla, document=False):
             return L.mla_qkv(p["attn"], h, cfg, cos, sin) + (seg,)
         return L.attn_qkv(p["attn"], h, cfg, cos, sin) + (seg,)
 
+    # "attn" names the attention's device work in the trace, whether it
+    # runs locally or through a schedule's executor
+    @jax.named_scope("attn")
     def attn_fwd(qkv):
         q, k, v, seg = qkv
         return dist_attn_fwd(q, k, v, mesh=rt.mesh, spec=spec,
                              batch_axes=batch_axes, segments=seg)
 
+    @jax.named_scope("attn")
     def attn_bwd(qkv, o, lse, do):
         q, k, v, seg = qkv
         dq, dk, dv = dist_attn_bwd(q, k, v, o, lse, do, mesh=rt.mesh,
@@ -178,6 +182,7 @@ def _dense_stages(cfg, rt, is_mla, document=False):
                                                  jax.dtypes.float0)
         return dq, dk, dv, dseg
 
+    @jax.named_scope("attn")
     def attn_diff(qkv):
         q, k, v, seg = qkv
         return dist_flash_attn(q, k, v, rt.mesh, spec, batch_axes, seg)
@@ -438,8 +443,9 @@ class DecoderLM:
                 seg = seg[:, perm]
             h = constrain(h, rt.mesh, act_spec(rt.par))
         h, aux = self._backbone(p, h, cos, sin, seg)
-        logits = self._head(p, h)
-        ce = L.cross_entropy(logits, labels)
+        with jax.named_scope("head"):
+            logits = self._head(p, h)
+            ce = L.cross_entropy(logits, labels)
         total = ce + aux
         metrics = {"ce": ce, "aux": aux}
         if cfg.mtp_depth and "mtp" in p:

@@ -44,14 +44,15 @@ def make_train_step(model, tc: TrainConfig):
             finite &= jnp.all(jnp.isfinite(g))
         # the update itself runs unconditionally (one trace, no host
         # sync); ``finite`` selects between new and old leaves
-        params2, opt2, om = adamw.update(grads, opt_state, params, tc)
-        keep = partial(jnp.where, finite)
-        params2 = jax.tree.map(keep, params2, params)
-        opt2 = adamw.AdamWState(
-            step=keep(opt2.step, opt_state.step),
-            m=jax.tree.map(keep, opt2.m, opt_state.m),
-            v=jax.tree.map(keep, opt2.v, opt_state.v))
-        om = {k: keep(v, jnp.zeros_like(v)) for k, v in om.items()}
+        with jax.named_scope("optimizer"):
+            params2, opt2, om = adamw.update(grads, opt_state, params, tc)
+            keep = partial(jnp.where, finite)
+            params2 = jax.tree.map(keep, params2, params)
+            opt2 = adamw.AdamWState(
+                step=keep(opt2.step, opt_state.step),
+                m=jax.tree.map(keep, opt2.m, opt_state.m),
+                v=jax.tree.map(keep, opt2.v, opt_state.v))
+            om = {k: keep(v, jnp.zeros_like(v)) for k, v in om.items()}
         return params2, opt2, {"loss": loss, **metrics, **om,
                                "skipped_nonfinite":
                                    (1 - finite).astype(jnp.int32)}

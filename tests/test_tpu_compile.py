@@ -11,6 +11,8 @@ every pytest-xdist worker collects the same tests and only the worker that
 runs this file loads the TPU library.
 """
 import os
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +21,11 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import mask as mk
 from repro.kernels import ops
+from repro.kernels.block_sparse import kv_profile, q_profile
 from repro.kernels.paged import paged_attn_pallas
+
+sys.path.append(str(Path(__file__).resolve().parents[1] / "bench"))
+import scopes  # noqa: E402  (the benchmark's reader of kernel names)
 
 # (n_heads, n_kv_heads, head_dim): smollm-360m and qwen3-8b attention widths
 WIDTHS = {"hd64": (15, 5, 64), "hd128": (32, 8, 128)}
@@ -59,6 +65,7 @@ def _shape(sharding, shape, dtype=jnp.bfloat16):
 def _assert_kernel(lowered):
     hlo = lowered.compile().as_text()
     assert "tpu_custom_call" in hlo
+    return hlo
 
 
 def _qkv(sharding, width):
@@ -104,3 +111,50 @@ def test_paged_decode_compiles(one_chip, width, tq):
     lengths = _shape(one_chip, (B,), jnp.int32)
     fn = jax.jit(lambda *a: paged_attn_pallas(*a, mask=mk.causal()))
     _assert_kernel(fn.lower(q, pool, pool, table, lengths))
+
+
+@pytest.mark.parametrize("prune", [True, False])
+@pytest.mark.parametrize("mask_name", sorted(MASKS))
+def test_flash_calls_carry_their_names_and_grid_work(one_chip, mask_name,
+                                                     prune):
+    """Each flash call is named for the trace and carries its static grid
+    work, B·H times its ``GridProfile`` counts, as ``kernel_metadata``:
+    the steps it launches and those whose compute body runs."""
+    mask, dynamic = MASKS[mask_name]
+    q, k, v = _qkv(one_chip, "hd64")
+    segs = _segments(one_chip, dynamic)
+
+    def fwd_bwd(q, k, v, do, **kw):
+        o, lse = ops.flash_fwd(q, k, v, mask=mask, prune=prune, **kw)
+        return ops.flash_bwd(q, k, v, o, lse, do, mask=mask, prune=prune,
+                             **kw)
+
+    hlo = _assert_kernel(jax.jit(fwd_bwd).lower(q, k, v, q, **segs))
+    got = {scopes.kernel(n): meta
+           for n, (_, meta) in scopes.instructions(hlo).items()
+           if scopes.kernel(n)}
+    n = (T + 127) // 128
+    prof = {"flash_fwd": kv_profile(nq=n, nk=n, br=128, bc=128, mask=mask)}
+    prof["flash_bwd.dq"] = prof["flash_fwd"]
+    prof["flash_bwd.dkv"] = q_profile(nq=n, nk=n, br=128, bc=128, mask=mask)
+    bh = B * q.shape[2]
+    assert set(got) == set(prof)
+    for name, p in prof.items():
+        if prune and mask.prunable:   # the body runs where the mask reaches
+            want = {"launched": p.launched_steps,
+                    "executed": p.executed_steps}
+        else:                         # the body runs on every grid step
+            want = {"launched": p.full_steps, "executed": p.full_steps}
+        assert got[name] == {k: bh * v for k, v in want.items()}, name
+
+
+def test_paged_kernel_carries_its_name(one_chip):
+    hq, hkv, d = WIDTHS["hd64"]
+    q = _shape(one_chip, (B, 1, hq, d))
+    pool = _shape(one_chip, (64, 16, hkv, d))
+    table = _shape(one_chip, (B, 32), jnp.int32)
+    lengths = _shape(one_chip, (B,), jnp.int32)
+    fn = jax.jit(lambda *a: paged_attn_pallas(*a, mask=mk.causal()))
+    hlo = _assert_kernel(fn.lower(q, pool, pool, table, lengths))
+    assert any(n.startswith("paged_attn.")
+               for n in scopes.instructions(hlo))
