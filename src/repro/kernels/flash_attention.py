@@ -43,6 +43,14 @@ float32 broadcasts materialized in HBM. Mosaic requires a block's last two
 dims to be (8k or full, 128k or full): the unit axis is full, and T sits on
 the lanes, so ``block_q`` must be a multiple of 128 or all of T.
 ``tests/test_tpu_compile.py`` compiles the kernels for a described TPU v5e.
+
+Tile shapes. A call without ``block_q``/``block_kv`` takes its tiles from
+:func:`flash_tiles`, per kernel, from the call's own shape: the target
+tile, narrowed where a causal diagonal crosses a short call, to a sliding
+window and to :data:`VMEM_BUDGET`, then fitted to a divisor of T. Each grid step carries a fixed cost (pipeline
+bookkeeping, DMA issue and wait, the index-map arithmetic) that a 128×128
+tile is too small to hide; the targets come from a sweep of tile shapes on
+a TPU v5e (``tools/flash_tile_sweep.py``, PERF.md).
 """
 from __future__ import annotations
 
@@ -60,6 +68,77 @@ from repro.kernels.block_sparse import (interior_kv_bounds, kv_block_bounds,
 
 NEG_INF = -1e30
 LANES = 128  # TPU lane width; stat scratch is lane-replicated
+# tile edge every kernel aims for before the mask, the VMEM budget and T
+# narrow it (the sweep on a TPU v5e that chose it: PERF.md)
+TILE_TARGET = 1024
+# scoped VMEM one grid step may take by ``_vmem_bytes``: Mosaic scopes
+# 16 MiB for a kernel on v5e by default; the rest is the model's margin
+VMEM_BUDGET = 14 * 2 ** 20
+
+
+def _vmem_bytes(br, bc, d, dv, op, itemsize=2):
+    """Scoped VMEM of a grid step of kernel ``op`` at tiles (br, bc): the
+    double-buffered operand and result tiles, the f32 scratch, f32 copies
+    of the tiles that change every step (kv for fwd and dq, q and dO for
+    dkv) and two live f32 (br, bc) temporaries. From 0.9 MiB under to
+    3.1 MiB over what the TPU compiler allocates at head dims 64–512 and
+    tiles of 512–2048 (PERF.md)."""
+    q_rows, kv_rows = br * (d + dv), bc * (d + dv)
+    if op == "fwd":
+        io, step = br * d + kv_rows + br * dv, kv_rows
+        scratch = br * (dv + 2 * LANES)              # acc, m, l
+    elif op == "dq":
+        io, scratch, step = q_rows + kv_rows + br * d, br * d, kv_rows
+    else:
+        io, scratch, step = q_rows + 2 * kv_rows, kv_rows, q_rows
+    return 2 * itemsize * io + 4 * (scratch + step + 2 * br * bc)
+
+
+def _fit(T, block):
+    """The tile edge for length T nearest under ``block``: all of T when
+    it fits, else the largest multiple of 128 dividing T, else (no such
+    divisor) :func:`pick_block`'s."""
+    if T <= block:
+        return T
+    for b in range(block - block % LANES, 0, -LANES):
+        if T % b == 0:
+            return b
+    return pick_block(T, block)
+
+
+def flash_tiles(Tq, Tk, D, Dv, mask: MaskSpec, op, itemsize=2):
+    """(block_q, block_kv) of flash kernel ``op`` (``"fwd"``, ``"dq"`` or
+    ``"dkv"``) for a call over Tq queries and Tk keys at head dims D, Dv
+    and operands of ``itemsize`` bytes: :data:`TILE_TARGET` on each edge,
+    cut to a quarter of the shorter length where the causal diagonal
+    crosses the call (a tile it cuts is computed whole, so four tiles a
+    side hold that to a quarter more than the triangle) and to the
+    sliding window where one is narrower (each rounded down to 128, at
+    least 128), halved (the larger edge first) until :func:`_vmem_bytes`
+    fits :data:`VMEM_BUDGET`, then fitted to T (:func:`_fit`)."""
+    bq = bk = TILE_TARGET
+    if mask.causal and mask.kv_offset + Tk - 1 > mask.q_offset:
+        bq = bk = min(bq, max(LANES, min(Tq, Tk) // 4 // LANES * LANES))
+    if mask.window and mask.window > 0:
+        bq = bk = min(bq, max(LANES, mask.window // LANES * LANES))
+    while (_vmem_bytes(bq, bk, D, Dv, op, itemsize) > VMEM_BUDGET
+           and max(bq, bk) > LANES):
+        if bk >= bq:
+            bk //= 2
+        else:
+            bq //= 2
+    return _fit(Tq, bq), _fit(Tk, bk)
+
+
+def _tiles(q, k, v, mask, op, block_q, block_kv):
+    """Tiles of one kernel call on (B, H, T, D) operands: an explicit hint
+    wins on its axis (a non-dividing hint shrinks to a divisor of T);
+    :func:`flash_tiles` gives the rest."""
+    Tq, Tk = q.shape[2], k.shape[2]
+    bq, bk = flash_tiles(Tq, Tk, q.shape[3], v.shape[3], mask, op,
+                         q.dtype.itemsize)
+    return (bq if block_q is None else pick_block(Tq, block_q),
+            bk if block_kv is None else pick_block(Tk, block_kv))
 
 
 def _pos_mask(i, j, br, bc, mask: MaskSpec, q_seg=None, kv_seg=None):
@@ -162,19 +241,20 @@ def _stat_operand(x):
     return x.astype(jnp.float32)[:, :, None, :]
 
 
-def _grid_work(prof, pruned, n):
-    """``metadata=`` of a flash call over ``n`` (batch, head) pairs: the
-    grid steps it launches and the steps whose compute body runs. Pruned,
-    the grid is the profile's shrunk one and the body runs where the
-    static mask reaches the tile; unpruned, the body runs on every step
-    of the full grid. Tiles that only dynamic segment IDs mask still run
-    (their scores are masked), so they count as executed. The compiled
-    call carries the counts as ``kernel_metadata``, so a trace event can
-    be matched to its own call's counts."""
-    if pruned:
-        return {"launched": n * prof.launched_steps,
-                "executed": n * prof.executed_steps}
-    return {"launched": n * prof.full_steps, "executed": n * prof.full_steps}
+def _grid_work(prof, pruned, n, br, bc):
+    """``metadata=`` of a flash call over ``n`` (batch, head) pairs at
+    tiles (br, bc): the grid steps it launches, the steps whose compute
+    body runs, and the tiles (``block_q``, ``block_kv``). Pruned, the grid
+    is the profile's shrunk one and the body runs where the static mask
+    reaches the tile; unpruned, the body runs on every step of the full
+    grid. Tiles that only dynamic segment IDs mask still run (their scores
+    are masked), so they count as executed. The compiled call carries
+    these as ``kernel_metadata``, so a trace event can be matched to its
+    own call's counts and tiling."""
+    steps = ((prof.launched_steps, prof.executed_steps) if pruned
+             else (prof.full_steps, prof.full_steps))
+    return {"launched": n * steps[0], "executed": n * steps[1],
+            "block_q": br, "block_kv": bc}
 
 
 # ---------------------------------------------------------------- forward
@@ -232,19 +312,19 @@ def _fwd_kernel(*refs, scale, mask, nk, prune, has_segs):
                                      jnp.log(l_safe))
 
 
-def flash_fwd_bhtd(q, k, v, *, scale, mask: MaskSpec, block_q=128,
-                   block_kv=128, interpret=False, prune=True,
+def flash_fwd_bhtd(q, k, v, *, scale, mask: MaskSpec, block_q=None,
+                   block_kv=None, interpret=False, prune=True,
                    q_segments=None, kv_segments=None):
     """q,k: (B,Hq/Hkv,T,Dk); v: (B,Hkv,Tk,Dv) -> o (B,Hq,Tq,Dv), lse.
     Dv may differ from Dk (MLA). ``q_segments``/``kv_segments`` are (B, T)
-    int32 document IDs (document kind). ``prune=False`` forces the dense
-    sweep (benchmark baseline / differential testing)."""
+    int32 document IDs (document kind). ``block_q``/``block_kv`` left None
+    come from :func:`flash_tiles`. ``prune=False`` forces the dense sweep
+    (benchmark baseline / differential testing)."""
     B, Hq, Tq, D = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
     Dv = v.shape[3]
     g = Hq // Hkv
-    br = pick_block(Tq, block_q)      # non-dividing hints shrink to a divisor
-    bc = pick_block(Tk, block_kv)
+    br, bc = _tiles(q, k, v, mask, "fwd", block_q, block_kv)
     nq, nk = Tq // br, Tk // bc
     has_segs = _check_segs(mask, q_segments, kv_segments)
 
@@ -297,7 +377,7 @@ def flash_fwd_bhtd(q, k, v, *, scale, mask: MaskSpec, block_q=128,
                                  "arbitrary")),
         interpret=interpret,
         name="flash_fwd",
-        metadata=_grid_work(prof, pruned, B * Hq),
+        metadata=_grid_work(prof, pruned, B * Hq, br, bc),
     )(*operands)
     return o, lse.reshape(B, Hq, Tq)
 
@@ -393,56 +473,62 @@ def _dkv_kernel(*refs, scale, mask, nq, prune, has_segs):
 
 
 def flash_bwd_bhtd(q, k, v, o, lse, do, *, scale, mask: MaskSpec,
-                   block_q=128, block_kv=128, interpret=False, delta=None,
+                   block_q=None, block_kv=None, interpret=False, delta=None,
                    prune=True, q_segments=None, kv_segments=None):
     """Backward from saved (o, lse). Layout (B,H,T,D). Returns dq, dk, dv
     (dk/dv summed over the GQA group). ``delta`` (B,H,Tq) may be passed
-    precomputed (distributed helper path)."""
-    B, Hq, Tq, D = q.shape
-    Hkv, Tk = k.shape[1], k.shape[2]
-    Dv = v.shape[3]
-    g = Hq // Hkv
-    br = pick_block(Tq, block_q)      # non-dividing hints shrink to a divisor
-    bc = pick_block(Tk, block_kv)
-    nq, nk = Tq // br, Tk // bc
+    precomputed (distributed helper path). The dq and dkv kernels take
+    their tiles each from :func:`flash_tiles` where ``block_q``/
+    ``block_kv`` are None; a hint applies to both."""
     has_segs = _check_segs(mask, q_segments, kv_segments)
-
     if delta is None:
         delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
                         axis=-1)
-    stats = [_stat_operand(lse), _stat_operand(delta)]
+    operands = [q, k, v, do, _stat_operand(lse), _stat_operand(delta)]
+    if has_segs:
+        operands += _seg_operands(q_segments, kv_segments)
+    kw = dict(scale=scale, mask=mask, interpret=interpret, prune=prune,
+              has_segs=has_segs)
+    dq = _dq_call(operands, *_tiles(q, k, v, mask, "dq", block_q, block_kv),
+                  **kw)
+    dk, dv = _dkv_call(operands,
+                       *_tiles(q, k, v, mask, "dkv", block_q, block_kv), **kw)
+    return dq, dk, dv
 
+
+def _dq_call(operands, br, bc, *, scale, mask, interpret, prune, has_segs):
+    """dq kernel at tiles (br, bc): grid over q blocks, sequential over the
+    valid kv blocks."""
+    q, k, v = operands[:3]
+    B, Hq, Tq, D = q.shape
+    Hkv, Tk, Dv = k.shape[1], k.shape[2], v.shape[3]
+    g = Hq // Hkv
+    nq, nk = Tq // br, Tk // bc
     pruned = prune and mask.prunable
-    kv_prof = kv_profile(nq=nq, nk=nk, br=br, bc=bc, mask=mask)
-    q_prof = q_profile(nq=nq, nk=nk, br=br, bc=bc, mask=mask)
-    seq_kv, seq_q = nk, nq
-    if pruned:
-        seq_kv, seq_q = kv_prof.seq_grid, q_prof.seq_grid
-    if pruned and (seq_kv == 0 or seq_q == 0):   # statically fully masked
-        return (jnp.zeros(q.shape, q.dtype),
-                jnp.zeros((B, Hkv, Tk, D), k.dtype),
-                jnp.zeros((B, Hkv, Tk, Dv), v.dtype))
-
-    seg_ops = _seg_operands(q_segments, kv_segments) if has_segs else []
+    prof = kv_profile(nq=nq, nk=nk, br=br, bc=bc, mask=mask)
+    seq = prof.seq_grid if pruned else nk
+    if seq == 0:                          # statically fully masked chunk
+        return jnp.zeros(q.shape, q.dtype)
 
     def kv_block(i, j):
         return _kv_index(i, j, br, bc, nk, mask, prune)
 
     q_spec = pl.BlockSpec((1, 1, br, D), lambda b, h, i, j: (b, h, i, 0))
-    kv_spec = pl.BlockSpec(
-        (1, 1, bc, D), lambda b, h, i, j: (b, h // g, kv_block(i, j), 0))
-    v_spec = pl.BlockSpec(
-        (1, 1, bc, Dv), lambda b, h, i, j: (b, h // g, kv_block(i, j), 0))
-    do_spec = pl.BlockSpec((1, 1, br, Dv), lambda b, h, i, j: (b, h, i, 0))
     stat_spec = pl.BlockSpec((1, 1, 1, br), lambda b, h, i, j: (b, h, 0, i))
-
-    in_specs = [q_spec, kv_spec, v_spec, do_spec, stat_spec, stat_spec]
+    in_specs = [
+        q_spec,
+        pl.BlockSpec((1, 1, bc, D),
+                     lambda b, h, i, j: (b, h // g, kv_block(i, j), 0)),
+        pl.BlockSpec((1, 1, bc, Dv),
+                     lambda b, h, i, j: (b, h // g, kv_block(i, j), 0)),
+        pl.BlockSpec((1, 1, br, Dv), lambda b, h, i, j: (b, h, i, 0)),
+        stat_spec, stat_spec]
     if has_segs:
         in_specs += _seg_specs(br, bc, kv_block)
-    dq = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, mask=mask, nk=nk,
                           prune=prune, has_segs=has_segs),
-        grid=(B, Hq, nq, seq_kv),
+        grid=(B, Hq, nq, seq),
         in_specs=in_specs,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -452,34 +538,49 @@ def flash_bwd_bhtd(q, k, v, o, lse, do, *, scale, mask: MaskSpec,
                                  "arbitrary")),
         interpret=interpret,
         name="flash_bwd.dq",
-        metadata=_grid_work(kv_prof, pruned, B * Hq),
-    )(q, k, v, do, *stats, *seg_ops)
+        metadata=_grid_work(prof, pruned, B * Hq, br, bc),
+    )(*operands)
 
-    # dkv: grid over kv blocks, sequential over the valid q blocks. Output
-    # per *query* head, then group-summed below (GQA).
+
+def _dkv_call(operands, br, bc, *, scale, mask, interpret, prune,
+              has_segs):
+    """dkv kernel at tiles (br, bc): grid over kv blocks, sequential over
+    the valid q blocks. Output per *query* head, then group-summed (GQA).
+    """
+    q, k, v = operands[:3]
+    B, Hq, Tq, D = q.shape
+    Hkv, Tk, Dv = k.shape[1], k.shape[2], v.shape[3]
+    g = Hq // Hkv
+    nq, nk = Tq // br, Tk // bc
+    pruned = prune and mask.prunable
+    prof = q_profile(nq=nq, nk=nk, br=br, bc=bc, mask=mask)
+    seq = prof.seq_grid if pruned else nq
+    if seq == 0:                          # statically fully masked chunk
+        return jnp.zeros(k.shape, k.dtype), jnp.zeros(v.shape, v.dtype)
+
     def q_block(j, i):
         return _q_index(j, i, br, bc, nq, mask, prune)
 
-    q_spec2 = pl.BlockSpec((1, 1, br, D),
-                           lambda b, h, j, i: (b, h, q_block(j, i), 0))
-    kv_spec2 = pl.BlockSpec((1, 1, bc, D), lambda b, h, j, i: (b, h // g, j, 0))
-    v_spec2 = pl.BlockSpec((1, 1, bc, Dv), lambda b, h, j, i: (b, h // g, j, 0))
-    do_spec2 = pl.BlockSpec((1, 1, br, Dv),
-                            lambda b, h, j, i: (b, h, q_block(j, i), 0))
-    k_out2 = pl.BlockSpec((1, 1, bc, D), lambda b, h, j, i: (b, h, j, 0))
-    v_out2 = pl.BlockSpec((1, 1, bc, Dv), lambda b, h, j, i: (b, h, j, 0))
-    stat_spec2 = pl.BlockSpec((1, 1, 1, br),
-                              lambda b, h, j, i: (b, h, 0, q_block(j, i)))
-    in_specs2 = [q_spec2, kv_spec2, v_spec2, do_spec2, stat_spec2,
-                 stat_spec2]
+    stat_spec = pl.BlockSpec((1, 1, 1, br),
+                             lambda b, h, j, i: (b, h, 0, q_block(j, i)))
+    in_specs = [
+        pl.BlockSpec((1, 1, br, D),
+                     lambda b, h, j, i: (b, h, q_block(j, i), 0)),
+        pl.BlockSpec((1, 1, bc, D), lambda b, h, j, i: (b, h // g, j, 0)),
+        pl.BlockSpec((1, 1, bc, Dv), lambda b, h, j, i: (b, h // g, j, 0)),
+        pl.BlockSpec((1, 1, br, Dv),
+                     lambda b, h, j, i: (b, h, q_block(j, i), 0)),
+        stat_spec, stat_spec]
     if has_segs:
-        in_specs2 += _seg_specs(br, bc, kv_block, dkv=True, q_block=q_block)
+        in_specs += _seg_specs(br, bc, None, dkv=True, q_block=q_block)
     dk_h, dv_h = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, mask=mask, nq=nq,
                           prune=prune, has_segs=has_segs),
-        grid=(B, Hq, nk, seq_q),
-        in_specs=in_specs2,
-        out_specs=[k_out2, v_out2],
+        grid=(B, Hq, nk, seq),
+        in_specs=in_specs,
+        out_specs=[
+            pl.BlockSpec((1, 1, bc, D), lambda b, h, j, i: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, bc, Dv), lambda b, h, j, i: (b, h, j, 0))],
         out_shape=[jax.ShapeDtypeStruct((B, Hq, Tk, D), k.dtype),
                    jax.ShapeDtypeStruct((B, Hq, Tk, Dv), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bc, D), jnp.float32),
@@ -489,9 +590,9 @@ def flash_bwd_bhtd(q, k, v, o, lse, do, *, scale, mask: MaskSpec,
                                  "arbitrary")),
         interpret=interpret,
         name="flash_bwd.dkv",
-        metadata=_grid_work(q_prof, pruned, B * Hq),
-    )(q, k, v, do, *stats, *seg_ops)
+        metadata=_grid_work(prof, pruned, B * Hq, br, bc),
+    )(*operands)
     if g > 1:
         dk_h = dk_h.reshape(B, Hkv, g, Tk, D).sum(axis=2)
         dv_h = dv_h.reshape(B, Hkv, g, Tk, Dv).sum(axis=2)
-    return dq, dk_h.astype(k.dtype), dv_h.astype(v.dtype)
+    return dk_h.astype(k.dtype), dv_h.astype(v.dtype)

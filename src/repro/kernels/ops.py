@@ -4,8 +4,10 @@ Public layout is (B, T, H, D) (matching the model code); the kernels use
 (B, H, T, D). Masking is a static :class:`repro.core.mask.MaskSpec`
 (hashable — it rides through jit as a static argument); document segment
 IDs are (B, T) int32 operands. The legacy ``causal``/``rel_offset``/
-``window`` kwargs still build the equivalent spec. Block sizes default to
-128 (MXU-aligned) and shrink to the chunk size for small test shapes.
+``window`` kwargs still build the equivalent spec. Tiles left unset
+(``block_q``/``block_kv`` None) come from ``flash_tiles``, per kernel, from
+the call's lengths, head dims and mask; a hint wins on its axis, and a
+hint that does not divide T shrinks to a divisor.
 ``prune`` (default on) enables the static block-sparse grid pruning;
 ``prune=False`` forces the dense ``nq × nk`` sweep (benchmark baseline /
 differential testing).
@@ -29,7 +31,7 @@ def _to_bhtd(x):
                                    "scale", "block_q", "block_kv",
                                    "interpret", "prune"))
 def flash_fwd(q, k, v, *, mask=None, causal=False, rel_offset=0, window=0,
-              scale=None, block_q=128, block_kv=128, interpret=False,
+              scale=None, block_q=None, block_kv=None, interpret=False,
               prune=True, q_segments=None, kv_segments=None):
     """(B,T,H,D) partial attention -> (o (B,T,H,D), lse (B,T,H))."""
     mask = as_spec(mask, causal=causal, window=window,
@@ -47,7 +49,7 @@ def flash_fwd(q, k, v, *, mask=None, causal=False, rel_offset=0, window=0,
                                    "scale", "block_q", "block_kv",
                                    "interpret", "prune"))
 def flash_bwd(q, k, v, o, lse, do, *, mask=None, causal=False, rel_offset=0,
-              window=0, scale=None, block_q=128, block_kv=128,
+              window=0, scale=None, block_q=None, block_kv=None,
               interpret=False, delta=None, prune=True, q_segments=None,
               kv_segments=None):
     """Backward from saved (o, lse). Returns (dq, dk, dv)."""

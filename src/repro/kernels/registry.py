@@ -266,7 +266,8 @@ def block_tuning_kw(block_q, block_kv, *, backend=None, platform=None,
     context (requires ``backend`` + shape context — the bare two-arg form
     used inside backend closures never re-consults the table).  Explicit
     kwargs always win wholesale; with no env, no table, and no kwargs the
-    kernels keep their built-in defaults."""
+    kernels choose their own tiles (the Pallas kernels' ``flash_tiles``,
+    chunked-lax's default block)."""
     if block_q is None and block_kv is None:
         from repro.tune import table as _tt
         block_q = _tt.env_int("REPRO_TUNE_BLOCK_Q")

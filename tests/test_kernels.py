@@ -308,3 +308,147 @@ def test_kernel_ref_grad_consistency():
     assert jnp.allclose(dq, dq_a, atol=1e-4)
     assert jnp.allclose(dk, dk_a, atol=1e-4)
     assert jnp.allclose(dv, dv_a, atol=1e-4)
+
+
+# ------------------------------------------------------------- tile chooser
+
+TILE_OPS = ("fwd", "dq", "dkv")
+
+
+@pytest.mark.parametrize("op", TILE_OPS)
+@pytest.mark.parametrize("D", [64, 128, 256])
+@pytest.mark.parametrize("T", [8192, 1536, 640, 384, 96])
+def test_flash_tiles_divide_t_align_and_fit_vmem(T, D, op):
+    """The chosen tiles divide T, are multiples of 128 or all of T, and
+    the step they make fits the VMEM budget."""
+    from repro.core import mask as mk
+    from repro.kernels import flash_attention as fa
+    bq, bk = fa.flash_tiles(T, T, D, D, mk.causal(), op)
+    for b in (bq, bk):
+        assert T % b == 0 and (b % 128 == 0 or b == T), (b, T)
+    assert fa._vmem_bytes(bq, bk, D, D, op) <= fa.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("op", TILE_OPS)
+@pytest.mark.parametrize("T,causal_edge", [(8192, 1024), (4096, 1024),
+                                           (2048, 512), (1024, 256),
+                                           (256, 128), (96, 96)])
+def test_flash_tiles_start_from_target_and_shrink_to_short_chunks(
+        T, causal_edge, op):
+    """At the head dims in use the tiles are the target or all of a
+    shorter chunk; where the causal diagonal crosses the call, at most a
+    quarter of it (at least 128). A causal chunk wholly after its keys has
+    no diagonal in it."""
+    from repro.core import mask as mk
+    from repro.kernels import flash_attention as fa
+    edge = min(fa.TILE_TARGET, T)
+    for D in (64, 128):
+        assert fa.flash_tiles(T, T, D, D, mk.full(), op) == (edge, edge)
+        assert fa.flash_tiles(T, T, D, D, mk.causal(rel_offset=T),
+                              op) == (edge, edge)
+        assert fa.flash_tiles(T, T, D, D, mk.causal(),
+                              op) == (causal_edge, causal_edge)
+
+
+@pytest.mark.parametrize("window,cap", [(100, 128), (300, 256), (640, 512),
+                                        (8192, 1024)])
+def test_flash_tiles_follow_a_narrow_window(window, cap):
+    """A sliding window narrower than the target cuts both edges to the
+    window rounded down to 128 (at least 128); a wide one cuts nothing."""
+    from repro.core import mask as mk
+    from repro.kernels import flash_attention as fa
+    for op in TILE_OPS:
+        got = fa.flash_tiles(8192, 8192, 64, 64,
+                             mk.sliding_window(window, causal=False), op)
+        assert got == (min(cap, fa.TILE_TARGET),) * 2, op
+
+
+@pytest.mark.parametrize("D", [256, 512, 1024])
+def test_flash_tiles_shrink_for_wide_heads(D):
+    """At head dims whose target tiles overflow the budget, the tiles
+    shrink until they fit."""
+    from repro.core import mask as mk
+    from repro.kernels import flash_attention as fa
+    for op in TILE_OPS:
+        bq, bk = fa.flash_tiles(8192, 8192, D, D, mk.causal(), op)
+        assert bq * bk < fa.TILE_TARGET ** 2, op
+        assert fa._vmem_bytes(bq, bk, D, D, op) <= fa.VMEM_BUDGET, op
+
+
+@pytest.mark.parametrize("source", ["chooser", "hints", "env", "table"])
+def test_flash_tile_precedence(monkeypatch, source):
+    """Through chunk_attn, explicit hints, then ``REPRO_TUNE_BLOCK_*``,
+    then a tuning-table hit win over the chooser, as
+    ``registry.block_tuning_kw`` orders them; each flash call records the
+    tiles it ran with in its metadata."""
+    from jax.experimental import pallas as pl
+    from repro.core import mask as mk
+    from repro.core.attention import chunk_attn, chunk_attn_bwd
+    from repro.kernels import flash_attention as fa
+    from repro.tune import table as tt
+    T, D = 1024, 64
+    mask = mk.causal()
+    for var in ("REPRO_TUNE", "REPRO_TUNE_TABLE", "REPRO_TUNE_BLOCK_Q",
+                "REPRO_TUNE_BLOCK_KV"):
+        monkeypatch.delenv(var, raising=False)
+    monkeypatch.setattr(tt, "_EXPLICIT", None)     # no table ...
+    monkeypatch.setattr(tt, "_ACTIVE", tt._UNSET)
+    hints = {}
+    want = {op: fa.flash_tiles(T, T, D, D, mask, op) for op in TILE_OPS}
+    if source == "hints":
+        hints = dict(block_q=256, block_kv=128)
+        want = dict.fromkeys(TILE_OPS, (256, 128))
+    elif source == "env":
+        monkeypatch.setenv("REPRO_TUNE_BLOCK_KV", "256")
+        want = {op: (b[0], 256) for op, b in want.items()}
+    elif source == "table":                        # ... but for this one
+        row = dict(backend="pallas-interpret", platform="cpu",
+                   mask_kind="causal", head_dim=D, seq=T, block_q=128,
+                   block_kv=256, wall_us=1.0)
+        monkeypatch.setattr(tt, "_EXPLICIT", tt.TuningTable(dict(
+            schema_version=tt.SCHEMA_VERSION, generated_by="tests",
+            host=dict(platform="cpu"), schedule=[], paged=[],
+            kernel=[dict(row, op="fwd"), dict(row, op="bwd")])))
+        want = dict.fromkeys(TILE_OPS, (128, 256))
+    seen = {}
+    real = pl.pallas_call
+
+    def spy(*a, name=None, metadata=None, **kw):
+        seen[name] = (metadata["block_q"], metadata["block_kv"])
+        return real(*a, name=name, metadata=metadata, **kw)
+
+    monkeypatch.setattr(pl, "pallas_call", spy)
+    q, k, v, do = _mk(1, T, T, 2, 1, D, jnp.float32)
+    kw = dict(mask=mask, impl="pallas-interpret", **hints)
+
+    def fwd_bwd(q, k, v, do):
+        o, lse = chunk_attn(q, k, v, **kw)
+        return chunk_attn_bwd(q, k, v, o, lse, do, **kw)
+
+    jax.eval_shape(fwd_bwd, q, k, v, do)
+    assert seen == {"flash_fwd": want["fwd"], "flash_bwd.dq": want["dq"],
+                    "flash_bwd.dkv": want["dkv"]}
+
+
+@pytest.mark.parametrize("kind", ["causal", "document-boundaries"])
+def test_default_tiles_multi_block_vs_ref(kind):
+    """The chooser's own tiles, several blocks a side (T = 3072: 4 × 4
+    tiles of 768), against the oracle, fwd and bwd, GQA 4/2: the diagonal
+    mask path at the tiles a TPU call runs with."""
+    from repro.core import mask as mk
+    from repro.kernels import flash_attention as fa
+    B, T, Hq, Hkv, D = 1, 3072, 4, 2, 32
+    mask = (mk.causal() if kind == "causal"
+            else mk.document(boundaries=mk.doc_boundaries(T, 3)))
+    for op in TILE_OPS:
+        bq, bk = fa.flash_tiles(T, T, D, D, mask, op)
+        assert T // bq > 1 and T // bk > 1, op
+    q, k, v, do = _mk(B, T, T, Hq, Hkv, D, jnp.float32, seed=5)
+    o_r, lse_r = chunk_attn_ref(q, k, v, mask=mask)
+    o_p, lse_p = ops.flash_fwd(q, k, v, mask=mask, interpret=True)
+    assert jnp.allclose(o_r, o_p, atol=1e-5, rtol=1e-5)
+    assert jnp.allclose(lse_r, lse_p, atol=1e-4, rtol=1e-4)
+    ref = chunk_attn_bwd_ref(q, k, v, o_r, lse_r, do, mask=mask)
+    pal = ops.flash_bwd(q, k, v, o_r, lse_r, do, mask=mask, interpret=True)
+    for r, p_ in zip(ref, pal):
+        assert jnp.allclose(r, p_, atol=2e-4, rtol=2e-4), kind

@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import mask as mk
 from repro.kernels import ops
 from repro.kernels.block_sparse import kv_profile, q_profile
+from repro.kernels.flash_attention import flash_tiles
 from repro.kernels.paged import paged_attn_pallas
 
 sys.path.append(str(Path(__file__).resolve().parents[1] / "bench"))
@@ -117,9 +118,11 @@ def test_paged_decode_compiles(one_chip, width, tq):
 @pytest.mark.parametrize("mask_name", sorted(MASKS))
 def test_flash_calls_carry_their_names_and_grid_work(one_chip, mask_name,
                                                      prune):
-    """Each flash call is named for the trace and carries its static grid
-    work, B·H times its ``GridProfile`` counts, as ``kernel_metadata``:
-    the steps it launches and those whose compute body runs."""
+    """Each flash call is named for the trace and carries, as
+    ``kernel_metadata``, the tiles it ran with (the chooser's, at default
+    tiles) and its static grid work at those tiles, B·H times its
+    ``GridProfile`` counts: the steps it launches and those whose compute
+    body runs."""
     mask, dynamic = MASKS[mask_name]
     q, k, v = _qkv(one_chip, "hd64")
     segs = _segments(one_chip, dynamic)
@@ -133,19 +136,40 @@ def test_flash_calls_carry_their_names_and_grid_work(one_chip, mask_name,
     got = {scopes.kernel(n): meta
            for n, (_, meta) in scopes.instructions(hlo).items()
            if scopes.kernel(n)}
-    n = (T + 127) // 128
-    prof = {"flash_fwd": kv_profile(nq=n, nk=n, br=128, bc=128, mask=mask)}
-    prof["flash_bwd.dq"] = prof["flash_fwd"]
-    prof["flash_bwd.dkv"] = q_profile(nq=n, nk=n, br=128, bc=128, mask=mask)
+    ops_of = {"flash_fwd": ("fwd", kv_profile),
+              "flash_bwd.dq": ("dq", kv_profile),
+              "flash_bwd.dkv": ("dkv", q_profile)}
+    d = WIDTHS["hd64"][2]
     bh = B * q.shape[2]
-    assert set(got) == set(prof)
-    for name, p in prof.items():
+    assert set(got) == set(ops_of)
+    for name, (op, profile) in ops_of.items():
+        br, bc = got[name]["block_q"], got[name]["block_kv"]
+        assert (br, bc) == flash_tiles(T, T, d, d, mask, op), name
+        p = profile(nq=T // br, nk=T // bc, br=br, bc=bc, mask=mask)
         if prune and mask.prunable:   # the body runs where the mask reaches
             want = {"launched": p.launched_steps,
                     "executed": p.executed_steps}
         else:                         # the body runs on every grid step
             want = {"launched": p.full_steps, "executed": p.full_steps}
-        assert got[name] == {k: bh * v for k, v in want.items()}, name
+        want = {k: bh * v for k, v in want.items()}
+        assert got[name] == {**want, "block_q": br, "block_kv": bc}, name
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_flash_default_tiles_compile_at_training_length(one_chip, width):
+    """fwd + bwd at the chooser's tiles, causal, batch 1 at seq 8192 (the
+    single-chip training cell's shape; qwen3-8b's widths at its
+    sequence-parallel chunk): VMEM overuse or a refused block shape at the
+    tiles a TPU call runs with would fail here."""
+    hq, hkv, d = WIDTHS[width]
+    q = _shape(one_chip, (1, 8192, hq, d))
+    kv = _shape(one_chip, (1, 8192, hkv, d))
+
+    def fwd_bwd(q, k, v, do):
+        o, lse = ops.flash_fwd(q, k, v, mask=mk.causal())
+        return ops.flash_bwd(q, k, v, o, lse, do, mask=mk.causal())
+
+    _assert_kernel(jax.jit(fwd_bwd).lower(q, kv, kv, q))
 
 
 def test_paged_kernel_carries_its_name(one_chip):
