@@ -405,7 +405,10 @@ def build_plan(schedule: str, mask: MaskSpec, P: int, Tl: int) \
 def _shift(x, axis, shift, size):
     """ppermute by ``shift`` hops: device p receives from (p − shift) mod
     P.  Multi-hop shifts (skipped steps folded together) are one
-    collective."""
+    collective.  Each call site names its exchange with a scope:
+    ``ring.fwd`` (forward prefetches, helpers shipping ``(o, lse)``),
+    ``ring.bwd`` (backward prefetches, travelling accumulators) or
+    ``ring.home`` (the accumulators' return)."""
     perm = [(i, (i + shift) % size) for i in range(size)]
     return jax.tree.map(lambda a: lax.ppermute(a, axis, perm), x)
 
@@ -592,7 +595,8 @@ def execute_fwd(plan: SchedulePlan, q, k, v, seg=None, *, axis, tune,
                 o_r, s_r = o_t, s_t
                 pred = r.pred
                 if r.ship:
-                    o_r, s_r = _shift((o_t, s_t), axis, r.ship, plan.P)
+                    with jax.named_scope("ring.fwd"):
+                        o_r, s_r = _shift((o_t, s_t), axis, r.ship, plan.P)
                     pred = r.recv_pred
                 pv = _pred_val(pred, ctx.p)
                 if pv is not None:
@@ -604,12 +608,14 @@ def execute_fwd(plan: SchedulePlan, q, k, v, seg=None, *, axis, tune,
     rest = plan.steps[1:]
     if rest:
         data = ctx.data_containers()
-        data = _shift(data, axis, rest[0].shift, plan.P)   # prefetch step 1
+        with jax.named_scope("ring.fwd"):
+            data = _shift(data, axis, rest[0].shift, plan.P)  # prefetch step 1
         ctx.d = rest[0].shift
         ctx.install(data)
         for i, step in enumerate(rest):
-            nxt = _shift(data, axis, rest[i + 1].shift, plan.P) \
-                if i + 1 < len(rest) else None               # prefetch (overlap)
+            with jax.named_scope("ring.fwd"):        # prefetch (overlap)
+                nxt = _shift(data, axis, rest[i + 1].shift, plan.P) \
+                    if i + 1 < len(rest) else None
             run(step)
             if nxt is not None:
                 data = nxt
@@ -682,27 +688,31 @@ def execute_bwd(plan: SchedulePlan, q, k, v, o, lse, do, seg=None, *,
     rest = plan.steps[1:]
     if rest:
         data = ctx.data_containers(bwd_bundle=(do, lse, delta))
-        data = _shift(data, axis, rest[0].shift, plan.P)
+        with jax.named_scope("ring.bwd"):
+            data = _shift(data, axis, rest[0].shift, plan.P)
         ctx.d = rest[0].shift
         ctx.install(data)
         for i, step in enumerate(rest):
-            nxt = _shift(data, axis, rest[i + 1].shift, plan.P) \
-                if i + 1 < len(rest) else None               # prefetch (overlap)
+            with jax.named_scope("ring.bwd"):        # prefetch (overlap)
+                nxt = _shift(data, axis, rest[i + 1].shift, plan.P) \
+                    if i + 1 < len(rest) else None
             run(step)
             if nxt is not None:
                 data = nxt
                 ctx.install(data)
                 s = rest[i + 1].shift
                 ctx.d += s
-                if dkv is not None:                # accumulators move late
-                    dkv = _shift(dkv, axis, s, plan.P)
-                if dqb is not None:
-                    dqb = _shift(dqb, axis, s, plan.P)
+                with jax.named_scope("ring.bwd"):  # accumulators move late
+                    if dkv is not None:
+                        dkv = _shift(dkv, axis, s, plan.P)
+                    if dqb is not None:
+                        dqb = _shift(dqb, axis, s, plan.P)
         D = ctx.d                                  # route accumulators home
-        if dkv is not None:
-            dkv = _shift(dkv, axis, -D, plan.P)
-        if dqb is not None:
-            dqb = _shift(dqb, axis, -D, plan.P)
+        with jax.named_scope("ring.home"):
+            if dkv is not None:
+                dkv = _shift(dkv, axis, -D, plan.P)
+            if dqb is not None:
+                dqb = _shift(dqb, axis, -D, plan.P)
     if dkv is not None:
         dk_home = dk_home + dkv[0]
         dv_home = dv_home + dkv[1]
