@@ -6,7 +6,7 @@ kv-block) pairs the mask can reach is computable at trace time. This module
 is the single source of truth for those ranges — the Pallas kernels
 (``flash_attention.py``), the ``chunked-lax`` backend (``chunked.py``) and
 the kernel microbench (``benchmarks/kernel_bench.py``) all derive their
-iteration spaces from the same three functions, so CPU CI exercises the
+iteration spaces from the same functions, so CPU CI exercises the
 identical block-range logic the TPU kernels run.
 
 Conventions. Q block ``i`` covers absolute query positions
@@ -113,33 +113,6 @@ def kv_block_bounds(i, *, br, bc, nk, mask: MaskSpec):
     if mask.document and mask.boundaries is not None:
         lo = _mx(lo, _mx(0, (mask.doc_start(qs) - ko) // bc))
         hi = _mn(hi, (mask.doc_end(qe) - ko) // bc)
-    return lo, hi
-
-
-def interior_kv_bounds(i, *, br, bc, nk, mask: MaskSpec):
-    """Inclusive (lo, hi) of KV blocks the mask cannot touch for q block
-    ``i`` — *every* (qp, kp) pair in the tile is unmasked, so the kernel may
-    skip the position mask entirely. Empty (``hi < lo``) when no interior
-    block exists (e.g. the diagonal row of a causal chunk). Conservative
-    (never larger than the true interior): a dynamic-segment document spec
-    has no static interior at all."""
-    qs = mask.q_offset + i * br
-    qe = qs + br - 1
-    ko = mask.kv_offset
-    # causal: fully below the diagonal iff the last key ko+(j+1)*bc-1 <= qs
-    hi = _mn(nk - 1, (qs + 1 - ko) // bc - 1) if mask.causal else nk - 1
-    # window: fully inside iff the first key ko + j*bc > qe - window
-    lo = (_mx(0, (qe - mask.window - ko) // bc + 1)
-          if mask.window and mask.window > 0 else 0)
-    if mask.document:
-        if mask.boundaries is None:
-            return 1, 0                      # dynamic segments: no interior
-        ds, de = mask.doc_start(qs), mask.doc_end(qs)
-        single_doc = mask.doc_start(qe) == ds
-        # kv block fully inside the q block's document
-        lo = _mx(lo, _mx(0, _cdiv(ds - ko, bc)))
-        hi = _mn(hi, (de + 1 - ko) // bc - 1)
-        hi = _where(single_doc, hi, -1)      # q spans a boundary: no interior
     return lo, hi
 
 

@@ -30,9 +30,8 @@ transpose for the dkv kernel — is computed at trace time by
     ``lo(i) + jj``, clamped to the row's last valid block so out-of-range
     steps revisit an already-resident block (no extra DMA) and skip compute
     under ``pl.when``;
-  * blocks the mask cannot touch (``interior_kv_bounds``) take a mask-free
-    fast path — only diagonal/window-edge/document-boundary tiles pay the
-    position mask + where.
+  * every executed tile of a masked call pays the position mask + where,
+    in line (:func:`_apply_mask`).
 
 The backward follows FA2: ``delta = rowsum(do ⊙ o)`` precomputed, then a
 dq-kernel (grid over q blocks, sequential kv) and a dkv-kernel (grid over kv
@@ -51,10 +50,23 @@ window and to :data:`VMEM_BUDGET`, then fitted to a divisor of T. Each grid step
 bookkeeping, DMA issue and wait, the index-map arithmetic) that a 128×128
 tile is too small to hide; the targets come from a sweep of tile shapes on
 a TPU v5e (``tools/flash_tile_sweep.py``, PERF.md).
+
+Bodies. Each grid step does as little beside its matmuls as the math
+allows: the dots take q, k, v and dO in their own dtype (bf16 inputs fill
+the MXU's 16-row bf16 vregs; f32 inputs stay f32) and accumulate in f32,
+with p and dS cast to that dtype just before the dot that consumes them;
+``exp``, the softmax statistics and the accumulators stay f32. A
+power-of-two scale rides the (block, d) q or k tile (exact), any other
+multiplies the logits once; the gradient's scale is applied once to the
+dq/dk accumulators. Fully masked rows are guarded on the (br,)
+statistics, not the (br, bc) tile. The dkv kernel works kv-major
+(sᵀ = k·qᵀ), so every dot contracts a tile's last dim and no (br, bc)
+tile is transposed.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -62,9 +74,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.mask import MaskSpec
-from repro.kernels.block_sparse import (interior_kv_bounds, kv_block_bounds,
-                                        kv_profile, pick_block,
-                                        q_block_bounds, q_profile)
+from repro.kernels.block_sparse import (kv_block_bounds, kv_profile,
+                                        pick_block, q_block_bounds, q_profile)
 
 NEG_INF = -1e30
 LANES = 128  # TPU lane width; stat scratch is lane-replicated
@@ -141,32 +152,31 @@ def _tiles(q, k, v, mask, op, block_q, block_kv):
             bk if block_kv is None else pick_block(Tk, block_kv))
 
 
-def _pos_mask(i, j, br, bc, mask: MaskSpec, q_seg=None, kv_seg=None):
+def _pos_mask(i, j, br, bc, mask: MaskSpec, q_seg=None, kv_seg=None,
+              transpose=False):
     """(br, bc) boolean attend-mask for q block i, kv block j (static args
-    except the traced program ids i, j and the segment vectors)."""
+    except the traced program ids i, j and the segment vectors); with
+    ``transpose``, the (bc, br) mask of the kv-major tile."""
+    shape, qa, ka = ((bc, br), 1, 0) if transpose else ((br, bc), 0, 1)
     qp = (mask.q_offset + i * br
-          + jax.lax.broadcasted_iota(jnp.int32, (br, bc), 0))
+          + jax.lax.broadcasted_iota(jnp.int32, shape, qa))
     kp = (mask.kv_offset + j * bc
-          + jax.lax.broadcasted_iota(jnp.int32, (br, bc), 1))
-    qs = None if q_seg is None else q_seg[:, None]
-    ks = None if kv_seg is None else kv_seg[None, :]
+          + jax.lax.broadcasted_iota(jnp.int32, shape, ka))
+    qs = None if q_seg is None else jnp.expand_dims(q_seg, ka)
+    ks = None if kv_seg is None else jnp.expand_dims(kv_seg, qa)
     return mask.allow(qp, kp, qs, ks)
 
 
-def _apply_mask(s, i, j, mask: MaskSpec, prune, q_seg=None, kv_seg=None):
-    """Mask score tile ``s`` for block (i, j). With pruning, interior blocks
-    (mask provably all-True) skip the iota/compare/where entirely via a
-    runtime branch — only edge tiles pay for ``_pos_mask``."""
-    br, bc = s.shape
-
-    def _m(x):
-        return jnp.where(_pos_mask(i, j, br, bc, mask, q_seg, kv_seg),
-                         x, NEG_INF)
-
-    if not prune:
-        return _m(s)
-    lo_f, hi_f = interior_kv_bounds(i, br=br, bc=bc, nk=2 ** 30, mask=mask)
-    return jax.lax.cond((j < lo_f) | (j > hi_f), _m, lambda x: x, s)
+def _apply_mask(s, i, j, mask: MaskSpec, q_seg=None, kv_seg=None,
+                transpose=False):
+    """Mask score tile ``s`` for block (i, j): (br, bc), or (bc, br) with
+    ``transpose``. Interior tiles, whose mask is all-True, pay the compare
+    and select too: a branch that skipped them would carry the tile through
+    it, and Mosaic stores and copies a branch's (br, bc) result on every
+    step, which costs more than the mask (PERF.md §6)."""
+    br, bc = s.shape[::-1] if transpose else s.shape
+    return jnp.where(_pos_mask(i, j, br, bc, mask, q_seg, kv_seg, transpose),
+                     s, NEG_INF)
 
 
 def _row_span(i, br, bc, nk, mask, prune):
@@ -241,20 +251,60 @@ def _stat_operand(x):
     return x.astype(jnp.float32)[:, :, None, :]
 
 
-def _grid_work(prof, pruned, n, br, bc):
+def _grid_work(prof, pruned, n, br, bc, dtype, scale):
     """``metadata=`` of a flash call over ``n`` (batch, head) pairs at
     tiles (br, bc): the grid steps it launches, the steps whose compute
-    body runs, and the tiles (``block_q``, ``block_kv``). Pruned, the grid
+    body runs, the tiles (``block_q``, ``block_kv``), the dtype its MXU
+    operands take (``operand_dtype``, the inputs') and whether the softmax
+    scale rode an operand tile (``scale_folded``). Pruned, the grid
     is the profile's shrunk one and the body runs where the static mask
     reaches the tile; unpruned, the body runs on every step of the full
     grid. Tiles that only dynamic segment IDs mask still run (their scores
     are masked), so they count as executed. The compiled call carries
     these as ``kernel_metadata``, so a trace event can be matched to its
-    own call's counts and tiling."""
+    own call's counts, tiling and body."""
     steps = ((prof.launched_steps, prof.executed_steps) if pruned
              else (prof.full_steps, prof.full_steps))
     return {"launched": n * steps[0], "executed": n * steps[1],
-            "block_q": br, "block_kv": bc}
+            "block_q": br, "block_kv": bc,
+            "operand_dtype": jnp.dtype(dtype).name,
+            "scale_folded": _scale_folds(scale)}
+
+
+def _scale_folds(scale) -> bool:
+    """True iff ``scale`` is a power of two, so multiplying a (block, d)
+    operand tile by it before the MXU is exact and the logits' (br, bc)
+    ``* scale`` can go (head_dim 64 and 256; not 128)."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _lse_safe(lse):
+    """Saved row logsumexp with a fully masked row's ``NEG_INF`` turned to
+    ``-NEG_INF``: ``exp(s - lse)`` is then 0 across that row whatever its
+    scores, with the guard on the (br,) vector and not the (br, bc) tile."""
+    return jnp.where(lse <= NEG_INF / 2, -NEG_INF, lse)
+
+
+def _dot(a, b):
+    """a·b on the MXU: ``a`` cast to ``b``'s dtype (a bf16 call pushes bf16
+    operands; an f32 call keeps f32), accumulated in f32."""
+    return jax.lax.dot(a.astype(b.dtype), b,
+                       preferred_element_type=jnp.float32)
+
+
+def _dot_nt(a, b):
+    """a·bᵀ, contracting both operands' last (head) dim, in f32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _logits(a, b, scale):
+    """a·bᵀ × ``scale`` in f32. Where the scale folds (:func:`_scale_folds`)
+    it multiplies ``a``'s (block, d) tile in f32 before the cast back,
+    exactly, and no (br, bc) pass is spent on it."""
+    if _scale_folds(scale):
+        return _dot_nt((a.astype(jnp.float32) * scale).astype(a.dtype), b)
+    return _dot_nt(a, b) * scale
 
 
 # ---------------------------------------------------------------- forward
@@ -280,25 +330,24 @@ def _fwd_kernel(*refs, scale, mask, nk, prune, has_segs):
 
     @pl.when(jj < count)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)              # (br, d)
-        k = k_ref[0, 0].astype(jnp.float32)              # (bc, d)
-        v = v_ref[0, 0].astype(jnp.float32)
+        q = q_ref[0, 0]                                  # (br, d)
+        k = k_ref[0, 0]                                  # (bc, d)
+        v = v_ref[0, 0]
 
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+        s = _logits(q, k, scale)
         if mask.needs_mask:
             q_seg = None if qs_ref is None else qs_ref[0, 0]
             kv_seg = None if ks_ref is None else ks_ref[0, 0]
-            s = _apply_mask(s, i, j, mask, prune, q_seg, kv_seg)
+            s = _apply_mask(s, i, j, mask, q_seg, kv_seg)
 
         m_prev = m_ref[:, 0]                             # (br,)
         m_cur = jnp.max(s, axis=1)
         m_new = jnp.maximum(m_prev, m_cur)
         m_safe = jnp.maximum(m_new, NEG_INF / 2)
-        p = jnp.exp(s - m_safe[:, None])
-        p = jnp.where(m_new[:, None] <= NEG_INF / 2, 0.0, p)
+        p = jnp.exp(s - m_safe[:, None])         # masked rows: exp(-5e29) = 0
         alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, jnp.exp(m_prev - m_safe))
         l_new = alpha * l_ref[:, 0] + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot(p, v)
+        acc_ref[...] = acc_ref[...] * alpha[:, None] + _dot(p, v)
         m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
 
@@ -377,7 +426,7 @@ def flash_fwd_bhtd(q, k, v, *, scale, mask: MaskSpec, block_q=None,
                                  "arbitrary")),
         interpret=interpret,
         name="flash_fwd",
-        metadata=_grid_work(prof, pruned, B * Hq, br, bc),
+        metadata=_grid_work(prof, pruned, B * Hq, br, bc, q.dtype, scale),
     )(*operands)
     return o, lse.reshape(B, Hq, Tq)
 
@@ -404,27 +453,23 @@ def _dq_kernel(*refs, scale, mask, nk, prune, has_segs):
 
     @pl.when(jj < count)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0, 0]                           # (br,)
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
+        lse = _lse_safe(lse_ref[0, 0, 0])                # (br,)
         delta = delta_ref[0, 0, 0]
 
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+        s = _logits(q, k, scale)
         if mask.needs_mask:
             q_seg = None if qs_ref is None else qs_ref[0, 0]
             kv_seg = None if ks_ref is None else ks_ref[0, 0]
-            s = _apply_mask(s, i, j, mask, prune, q_seg, kv_seg)
-        p = jnp.where(lse[:, None] <= NEG_INF / 2, 0.0,
-                      jnp.exp(s - lse[:, None]))
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-        ds = p * (dp - delta[:, None]) * scale
-        acc_ref[...] += jax.lax.dot(ds, k)
+            s = _apply_mask(s, i, j, mask, q_seg, kv_seg)
+        p = jnp.exp(s - lse[:, None])
+        dp = _dot_nt(do, v)
+        ds = p * (dp - delta[:, None])
+        acc_ref[...] += _dot(ds, k)
 
     @pl.when(jj == jnp.maximum(count - 1, 0))
     def _finalize():
-        dq_ref[0, 0] = acc_ref[...].astype(dq_ref.dtype)
+        dq_ref[0, 0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(*refs, scale, mask, nq, prune, has_segs):
@@ -447,28 +492,25 @@ def _dkv_kernel(*refs, scale, mask, nq, prune, has_segs):
 
     @pl.when(ii < count)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0, 0]
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
+        lse = _lse_safe(lse_ref[0, 0, 0])
         delta = delta_ref[0, 0, 0]
 
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+        # kv-major: every (bc, br) tile is used as computed, none transposed
+        st = _logits(k, q, scale)                        # sᵀ
         if mask.needs_mask:
             q_seg = None if qs_ref is None else qs_ref[0, 0]
             kv_seg = None if ks_ref is None else ks_ref[0, 0]
-            s = _apply_mask(s, i, j, mask, prune, q_seg, kv_seg)
-        p = jnp.where(lse[:, None] <= NEG_INF / 2, 0.0,
-                      jnp.exp(s - lse[:, None]))
-        dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-        ds = p * (dp - delta[:, None]) * scale
-        dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())))
+            st = _apply_mask(st, i, j, mask, q_seg, kv_seg, transpose=True)
+        pt = jnp.exp(st - lse[None, :])
+        dv_acc[...] += _dot(pt, do)
+        dpt = _dot_nt(v, do)
+        dst = pt * (dpt - delta[None, :])
+        dk_acc[...] += _dot(dst, q)
 
     @pl.when(ii == jnp.maximum(count - 1, 0))
     def _finalize():
-        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+        dk_ref[0, 0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
@@ -538,7 +580,7 @@ def _dq_call(operands, br, bc, *, scale, mask, interpret, prune, has_segs):
                                  "arbitrary")),
         interpret=interpret,
         name="flash_bwd.dq",
-        metadata=_grid_work(prof, pruned, B * Hq, br, bc),
+        metadata=_grid_work(prof, pruned, B * Hq, br, bc, q.dtype, scale),
     )(*operands)
 
 
@@ -590,7 +632,7 @@ def _dkv_call(operands, br, bc, *, scale, mask, interpret, prune,
                                  "arbitrary")),
         interpret=interpret,
         name="flash_bwd.dkv",
-        metadata=_grid_work(prof, pruned, B * Hq, br, bc),
+        metadata=_grid_work(prof, pruned, B * Hq, br, bc, q.dtype, scale),
     )(*operands)
     if g > 1:
         dk_h = dk_h.reshape(B, Hkv, g, Tk, D).sum(axis=2)

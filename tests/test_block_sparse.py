@@ -1,9 +1,8 @@
 """Brute-force verification of the static block-sparsity ranges every
 pruned kernel derives its iteration space from (kernels/block_sparse.py):
-for each block, the predicted valid/interior ranges must cover exactly
-(valid) or conservatively (interior, prefix hull) the ground truth computed
-from the dense position mask — across causal / window / prefix_lm /
-document MaskSpecs."""
+for each block, the predicted valid ranges must cover exactly (or, with a
+prefix, as a hull) the ground truth computed from the dense position mask
+— across causal / window / prefix_lm / document MaskSpecs."""
 import itertools
 
 import numpy as np
@@ -54,8 +53,7 @@ def _sweep():
 def test_block_bounds_match_dense_mask(br, bc):
     """kv/q bounds agree with any() of the dense mask for every block of
     every sweep config (hull: predicted range must contain every non-empty
-    block and, for contiguous kinds, nothing more); interior bounds must
-    only ever cover all-True blocks."""
+    block and, for contiguous kinds, nothing more)."""
     for (br_, bc_, nq, nk, m) in _sweep():
         if (br_, bc_) != (br, bc):
             continue
@@ -64,7 +62,6 @@ def test_block_bounds_match_dense_mask(br, bc):
         kw = dict(br=br, bc=bc, mask=m)
         for i in range(nq):
             lo, hi = bs.kv_block_bounds(i, nk=nk, **kw)
-            lo_f, hi_f = bs.interior_kv_bounds(i, nk=nk, **kw)
             assert 0 <= lo and hi <= nk - 1
             for j in range(nk):
                 tile = dm[i * br:(i + 1) * br, j * bc:(j + 1) * bc]
@@ -73,12 +70,6 @@ def test_block_bounds_match_dense_mask(br, bc):
                     assert (lo <= j <= hi) or not tile.any(), cfg
                 else:
                     assert (lo <= j <= hi) == bool(tile.any()), cfg
-                # interior is conservative: inside => all-True
-                if lo_f <= j <= hi_f:
-                    assert tile.all(), cfg
-                elif not (m.document or m.prefix_len):
-                    # causal/window interiors are exact
-                    assert not tile.all() or not tile.size, cfg
         for j in range(nk):
             lo_q, hi_q = bs.q_block_bounds(j, nq=nq, **kw)
             for i in range(nq):
@@ -146,12 +137,10 @@ def test_degenerate_ranges():
                       mask=MaskSpec(causal=True, window=10_000, q_offset=64))
     assert p.row_counts == (2, 2)
     # dynamic-segment documents: the causal half still prunes, but the
-    # segment half needs runtime arrays — so no mask-free interior exists
+    # segment half needs runtime arrays
     m = document()
     assert m.needs_segments
     assert m.prunable                  # via the causal component
-    lo_f, hi_f = bs.interior_kv_bounds(0, br=64, bc=64, nk=4, mask=m)
-    assert hi_f < lo_f                 # no mask-free interior
     # document-only (causal dropped, e.g. a ring step) with dynamic
     # segments: nothing static to prune at all
     assert not m.replace(causal=False).prunable
@@ -170,13 +159,10 @@ def test_traced_bounds_match_python_bounds():
 
         @jax.jit
         def traced(i, kw=kw):
-            lo, hi = bs.kv_block_bounds(i, **kw)
-            lo_f, hi_f = bs.interior_kv_bounds(i, **kw)
-            return jnp.stack([lo, hi, lo_f, hi_f])
+            return jnp.stack(bs.kv_block_bounds(i, **kw))
 
         for i in range(4):
-            want = (*bs.kv_block_bounds(i, **kw),
-                    *bs.interior_kv_bounds(i, **kw))
+            want = bs.kv_block_bounds(i, **kw)
             got = tuple(int(x) for x in traced(jnp.int32(i)))
             assert got == want, (m, i, got, want)
 

@@ -430,25 +430,120 @@ def test_flash_tile_precedence(monkeypatch, source):
                     "flash_bwd.dkv": want["dkv"]}
 
 
-@pytest.mark.parametrize("kind", ["causal", "document-boundaries"])
-def test_default_tiles_multi_block_vs_ref(kind):
+# (kind, dtype, head_dim): f32 at D 32 (scale 1/sqrt(32), applied to the
+# logits), bf16 at hd64 (scale 1/8, a power of two: folded into the operand
+# tile) and at hd128 (1/sqrt(128): on the logits)
+MULTI_BLOCK_CASES = {
+    "causal": ("causal", jnp.float32, 32),
+    "document-boundaries": ("document-boundaries", jnp.float32, 32),
+    "document-segments": ("document-segments", jnp.float32, 32),
+    "causal-bf16-hd64": ("causal", jnp.bfloat16, 64),
+    "document-boundaries-bf16-hd64": ("document-boundaries", jnp.bfloat16,
+                                      64),
+    "document-segments-bf16-hd64": ("document-segments", jnp.bfloat16, 64),
+    "causal-bf16-hd128": ("causal", jnp.bfloat16, 128),
+    "document-segments-bf16-hd128": ("document-segments", jnp.bfloat16,
+                                     128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MULTI_BLOCK_CASES))
+def test_default_tiles_multi_block_vs_ref(case):
     """The chooser's own tiles, several blocks a side (T = 3072: 4 × 4
     tiles of 768), against the oracle, fwd and bwd, GQA 4/2: the diagonal
-    mask path at the tiles a TPU call runs with."""
+    mask path at the tiles a TPU call runs with, in f32 and with bf16 MXU
+    operands. With dynamic segments, one query row's document appears in
+    no key of the chunk: its lse is NEG_INF, its output and dq are exactly
+    0, and its q and dO leave dk and dv exactly as they are."""
+    import numpy as np
     from repro.core import mask as mk
     from repro.kernels import flash_attention as fa
-    B, T, Hq, Hkv, D = 1, 3072, 4, 2, 32
-    mask = (mk.causal() if kind == "causal"
-            else mk.document(boundaries=mk.doc_boundaries(T, 3)))
+    kind, dtype, D = MULTI_BLOCK_CASES[case]
+    B, T, Hq, Hkv = 1, 3072, 4, 2
+    bnd = mk.doc_boundaries(T, 3)
+    segs, row = {}, 1000
+    if kind == "causal":
+        mask = mk.causal()
+    elif kind == "document-boundaries":
+        mask = mk.document(boundaries=bnd)
+    else:
+        mask = mk.document()
+        kv_seg = mk.segments_from_boundaries(T, bnd)[None]
+        q_seg = kv_seg.copy()
+        q_seg[0, row] = 7                  # a document no key belongs to
+        segs = dict(q_segments=jnp.asarray(q_seg),
+                    kv_segments=jnp.asarray(kv_seg))
     for op in TILE_OPS:
-        bq, bk = fa.flash_tiles(T, T, D, D, mask, op)
+        bq, bk = fa.flash_tiles(T, T, D, D, mask, op, jnp.dtype(dtype).itemsize)
         assert T // bq > 1 and T // bk > 1, op
-    q, k, v, do = _mk(B, T, T, Hq, Hkv, D, jnp.float32, seed=5)
-    o_r, lse_r = chunk_attn_ref(q, k, v, mask=mask)
-    o_p, lse_p = ops.flash_fwd(q, k, v, mask=mask, interpret=True)
-    assert jnp.allclose(o_r, o_p, atol=1e-5, rtol=1e-5)
-    assert jnp.allclose(lse_r, lse_p, atol=1e-4, rtol=1e-4)
-    ref = chunk_attn_bwd_ref(q, k, v, o_r, lse_r, do, mask=mask)
-    pal = ops.flash_bwd(q, k, v, o_r, lse_r, do, mask=mask, interpret=True)
+    q, k, v, do = _mk(B, T, T, Hq, Hkv, D, dtype, seed=5)
+    f32 = dtype == jnp.float32
+    tol_f, tol_b = (1e-5, 2e-4) if f32 else (2e-2, 5e-2)
+    o_r, lse_r = chunk_attn_ref(q, k, v, mask=mask, **segs)
+    o_p, lse_p = ops.flash_fwd(q, k, v, mask=mask, interpret=True, **segs)
+    assert jnp.allclose(o_r.astype(jnp.float32), o_p.astype(jnp.float32),
+                        atol=tol_f, rtol=tol_f), case
+    assert jnp.allclose(lse_r, lse_p, atol=1e-4, rtol=1e-4), case
+    ref = chunk_attn_bwd_ref(q, k, v, o_r, lse_r, do, mask=mask, **segs)
+    pal = ops.flash_bwd(q, k, v, o_r.astype(dtype), lse_r, do, mask=mask,
+                        interpret=True, **segs)
     for r, p_ in zip(ref, pal):
-        assert jnp.allclose(r, p_, atol=2e-4, rtol=2e-4), kind
+        assert jnp.allclose(r.astype(jnp.float32), p_.astype(jnp.float32),
+                            atol=tol_b, rtol=tol_b), case
+    if not segs:
+        return
+    assert bool(jnp.all(lse_p[:, row] <= fa.NEG_INF / 2))
+    assert not np.any(np.asarray(o_p[:, row], np.float32))
+    assert not np.any(np.asarray(pal[0][:, row], np.float32)), "dq row"
+    other = ops.flash_bwd(q.at[:, row].multiply(-3), k, v, o_r.astype(dtype),
+                          lse_r, do.at[:, row].multiply(5), mask=mask,
+                          interpret=True, **segs)
+    for a, b in zip(pal[1:], other[1:]):
+        assert np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32)), "dk/dv share"
+
+
+def _branch_results(jaxpr):
+    """The result shapes of every ``cond`` in ``jaxpr`` and the jaxprs
+    nested in its equations, a tuple per cond (a kernel body's ``pl.when``
+    is a cond with no results)."""
+    from jax._src import core
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            yield tuple(v.aval.shape for v in eqn.outvars)
+        for sub in core.jaxprs_in_params(eqn.params):
+            yield from _branch_results(sub)
+
+
+@pytest.mark.parametrize("kind", ["causal", "window", "document-boundaries",
+                                  "document-segments"])
+def test_flash_bodies_pass_no_tile_through_a_branch(kind):
+    """No flash kernel body carries a value through a branch: the mask is
+    applied in line on every executed tile. A branch whose result is the
+    (br, bc) score tile makes Mosaic store the tile and copy it through the
+    branch on every grid step (PERF.md §6)."""
+    from repro.core import mask as mk
+    B, T, Hq, Hkv, D = 1, 512, 2, 1, 64
+    q, k, v, do = _mk(B, T, T, Hq, Hkv, D, jnp.bfloat16)
+    bnd = mk.doc_boundaries(T, 3)
+    segs = {}
+    if kind == "causal":
+        mask = mk.causal()
+    elif kind == "window":
+        mask = mk.causal().replace(window=200)
+    elif kind == "document-boundaries":
+        mask = mk.document(boundaries=bnd)
+    else:
+        mask = mk.document()
+        seg = jnp.asarray(mk.segments_from_boundaries(T, bnd)[None])
+        segs = dict(q_segments=seg, kv_segments=seg)
+    lse = jnp.zeros((B, Hq, T), jnp.float32)
+    kw = dict(mask=mask, block_q=128, block_kv=128, interpret=True, **segs)
+
+    def fwd_bwd(q, k, v, do):
+        ops.flash_fwd(q, k, v, **kw)
+        return ops.flash_bwd(q, k, v, q, lse, do, **kw)
+
+    results = list(_branch_results(jax.make_jaxpr(fwd_bwd)(q, k, v,
+                                                          do).jaxpr))
+    assert results and all(r == () for r in results), results

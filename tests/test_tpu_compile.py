@@ -120,9 +120,10 @@ def test_flash_calls_carry_their_names_and_grid_work(one_chip, mask_name,
                                                      prune):
     """Each flash call is named for the trace and carries, as
     ``kernel_metadata``, the tiles it ran with (the chooser's, at default
-    tiles) and its static grid work at those tiles, B·H times its
+    tiles), its static grid work at those tiles, B·H times its
     ``GridProfile`` counts: the steps it launches and those whose compute
-    body runs."""
+    body runs, and its body: bf16 MXU operands, and hd64's 1/8 scale
+    folded into an operand tile."""
     mask, dynamic = MASKS[mask_name]
     q, k, v = _qkv(one_chip, "hd64")
     segs = _segments(one_chip, dynamic)
@@ -152,7 +153,9 @@ def test_flash_calls_carry_their_names_and_grid_work(one_chip, mask_name,
         else:                         # the body runs on every grid step
             want = {"launched": p.full_steps, "executed": p.full_steps}
         want = {k: bh * v for k, v in want.items()}
-        assert got[name] == {**want, "block_q": br, "block_kv": bc}, name
+        assert got[name] == {**want, "block_q": br, "block_kv": bc,
+                             "operand_dtype": "bfloat16",
+                             "scale_folded": True}, name
 
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))
@@ -160,7 +163,9 @@ def test_flash_default_tiles_compile_at_training_length(one_chip, width):
     """fwd + bwd at the chooser's tiles, causal, batch 1 at seq 8192 (the
     single-chip training cell's shape; qwen3-8b's widths at its
     sequence-parallel chunk): VMEM overuse or a refused block shape at the
-    tiles a TPU call runs with would fail here."""
+    tiles a TPU call runs with would fail here. Every kernel runs 1024 ×
+    1024 tiles with bf16 MXU operands; the scale folds into an operand
+    tile at hd64 (1/8) and not at hd128 (1/sqrt(128))."""
     hq, hkv, d = WIDTHS[width]
     q = _shape(one_chip, (1, 8192, hq, d))
     kv = _shape(one_chip, (1, 8192, hkv, d))
@@ -169,7 +174,15 @@ def test_flash_default_tiles_compile_at_training_length(one_chip, width):
         o, lse = ops.flash_fwd(q, k, v, mask=mk.causal())
         return ops.flash_bwd(q, k, v, o, lse, do, mask=mk.causal())
 
-    _assert_kernel(jax.jit(fwd_bwd).lower(q, kv, kv, q))
+    hlo = _assert_kernel(jax.jit(fwd_bwd).lower(q, kv, kv, q))
+    got = {scopes.kernel(n): meta
+           for n, (_, meta) in scopes.instructions(hlo).items()
+           if scopes.kernel(n)}
+    assert set(got) == {"flash_fwd", "flash_bwd.dq", "flash_bwd.dkv"}
+    for name, meta in got.items():
+        assert (meta["block_q"], meta["block_kv"]) == (1024, 1024), name
+        assert meta["operand_dtype"] == "bfloat16", name
+        assert meta["scale_folded"] is (width == "hd64"), name
 
 
 def test_paged_kernel_carries_its_name(one_chip):

@@ -1,16 +1,18 @@
 """Time the flash kernels on a TPU at candidate tile shapes.
 
     python tools/flash_tile_sweep.py [--seq 8192] [--iters 5]
-        [--tiles 512x512,1024x1024] [--out FILE]
+        [--mask causal|unmasked] [--tiles 512x512,1024x1024] [--out FILE]
 
 For each attention width (smollm-360m's 15/5 heads at head_dim 64, and
 qwen3-8b's 32/8 at 128) and each candidate ``(block_q, block_kv)``, one
-causal bf16 forward and backward at batch 1 is compiled, warmed up and
+bf16 forward and backward at batch 1 is compiled, warmed up and
 profiled over ``--iters`` calls. The device time of each kernel's events
 (``flash_fwd``, ``flash_bwd.dq``, ``flash_bwd.dkv``) is summed from the
-trace and printed per call in ms, one JSON line per candidate. The tile
-rule of ``flash_tiles`` was chosen from these readings (PERF.md). Fails
-without a TPU.
+trace and printed per call in ms, one JSON line per candidate. The mask
+is causal (a training chunk on its own diagonal) or none (``unmasked``:
+the sequence-parallel step's off-diagonal 8192² chunks). The tile rule of
+``flash_tiles`` was chosen from these readings (PERF.md). Fails without a
+TPU.
 """
 from __future__ import annotations
 
@@ -36,6 +38,7 @@ WIDTHS = {"hd64": (15, 5, 64), "hd128": (32, 8, 128)}
 TILES = [(128, 128), (256, 256), (256, 512), (512, 256), (512, 512),
          (512, 1024), (1024, 512), (1024, 1024)]
 KERNELS = ("flash_fwd", "flash_bwd.dq", "flash_bwd.dkv")
+MASKS = {"causal": mk.causal(), "unmasked": mk.MaskSpec()}
 
 
 def kernel_ms(fn, args, iters):
@@ -61,6 +64,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--seq", type=int, default=8192)
     ap.add_argument("--iters", type=int, default=5)
+    ap.add_argument("--mask", choices=sorted(MASKS), default="causal")
     ap.add_argument("--tiles", default=None,
                     help="comma-separated QxKV candidates (default: all)")
     ap.add_argument("--out", default=None)
@@ -69,7 +73,7 @@ def main():
         tuple(int(x) for x in t.split("x")) for t in a.tiles.split(",")]
     if jax.default_backend() != "tpu":
         sys.exit("flash_tile_sweep: no TPU")
-    mask, T, rows = mk.causal(), a.seq, []
+    mask, T, rows = MASKS[a.mask], a.seq, []
     for width, (hq, hkv, d) in WIDTHS.items():
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
         q, do = (jax.random.normal(k, (1, T, hq, d), jnp.bfloat16)
@@ -87,8 +91,8 @@ def main():
                 ms = kernel_ms(fn, (q, k, v, do), a.iters)
             except Exception as e:  # noqa: BLE001 - report and go on
                 ms = {"error": f"{type(e).__name__}: {str(e)[:200]}"}
-            row = {"width": width, "seq": T, "block_q": bq, "block_kv": bk,
-                   **ms}
+            row = {"width": width, "mask": a.mask, "seq": T,
+                   "block_q": bq, "block_kv": bk, **ms}
             rows.append(row)
             print(json.dumps(row), flush=True)
     if a.out:
