@@ -210,154 +210,6 @@ def _decode_attn_site(*, B, S, seq_shards, H, hd_qk, hd_v, Hkv, window,
     return flops, bytes_
 
 
-def paged_decode_terms(cfg, *, batch, mean_len, block_size, bpe=2):
-    """Roofline terms of ONE paged flash-decode step (all layers) at mean
-    in-flight context length ``mean_len``: kernel FLOPs, HBM bytes of the
-    block-table gather (KV streamed in whole blocks — the read-side cost of
-    paging is the partial last block, reported as ``block_waste``), plus
-    the table/q/o traffic.  Feeds the serving bench's predicted tok/s bound
-    next to its measured numbers."""
-    a = cfg.attn
-    if a is None:
-        return None
-    is_mla = a.is_mla
-    if is_mla:
-        hd_qk = a.kv_lora_rank + a.qk_rope_head_dim
-        hd_v = a.kv_lora_rank
-        Hkv = 1
-    else:
-        hd_qk = hd_v = a.head_dim
-        Hkv = a.n_kv_heads
-    H = a.n_heads
-    L_ = cfg.n_layers
-    w = min(a.window, mean_len) if a.window else mean_len
-    blocks = -(-w // block_size)
-    toks_read = blocks * block_size
-    flops = L_ * 2 * batch * w * H * (hd_qk + hd_v)
-    kv_bytes = L_ * batch * toks_read * Hkv * (hd_qk + hd_v) * bpe
-    table_bytes = L_ * batch * blocks * 4
-    qo_bytes = L_ * batch * H * (hd_qk + hd_v) * bpe
-    terms = roofline_terms(flops, kv_bytes + table_bytes + qo_bytes, 0.0)
-    terms["block_waste"] = toks_read / max(w, 1) - 1.0
-    terms["tok_s_bound"] = batch / max(terms["step_s_lower_bound"], 1e-12)
-    return terms
-
-
-def speculative_terms(cfg, *, batch, mean_len, depth, acceptance,
-                      block_size, bpe=2, draft_cfg=None):
-    """Expected-throughput model of speculative decoding at draft depth
-    ``depth`` (= K proposals verified per step) and per-token acceptance
-    rate ``acceptance`` (= a).
-
-    With position-independent acceptance the number of tokens committed
-    per verify step is ``1 + #accepted prefix`` — a truncated geometric —
-    so the expectation is the standard speculative-decoding series
-
-        E[tokens/step] = (1 - a^(K+1)) / (1 - a)      (K+1 at a = 1)
-
-    The verify step itself prices like a paged decode step with K+1 query
-    rows per request: attention FLOPs scale with the extra rows while the
-    streamed KV bytes barely move (the K+1 rows share one block-table
-    gather), which is exactly why verification is cheap in the
-    memory-bound decode regime.  When ``draft_cfg`` is given, the draft's
-    K single-token decode steps are added to the step lower bound.
-    Returns the vanilla terms, the verify terms, E[tokens/step], and the
-    speculative / vanilla tokens-per-second bound ratio."""
-    if not 0.0 <= acceptance <= 1.0:
-        raise ValueError("acceptance must be in [0, 1]")
-    if depth < 0:
-        raise ValueError("depth must be >= 0")
-    K = int(depth)
-    a = float(acceptance)
-    exp_tokens = (K + 1.0 if a >= 1.0
-                  else (1.0 - a ** (K + 1)) / (1.0 - a))
-    vanilla = paged_decode_terms(cfg, batch=batch, mean_len=mean_len,
-                                 block_size=block_size, bpe=bpe)
-    if vanilla is None:
-        return None
-    # verify = decode with K+1 query rows: q/o traffic and pair count scale
-    # by (K+1); the KV stream is the same blocks read once
-    at = cfg.attn
-    if at.is_mla:
-        hd_qk, hd_v, Hkv = (at.kv_lora_rank + at.qk_rope_head_dim,
-                            at.kv_lora_rank, 1)
-    else:
-        hd_qk = hd_v = at.head_dim
-        Hkv = at.n_kv_heads
-    w = min(at.window, mean_len) if at.window else mean_len
-    blocks = -(-w // block_size)
-    toks_read = blocks * block_size
-    L_ = cfg.n_layers
-    flops = L_ * 2 * batch * (K + 1) * w * at.n_heads * (hd_qk + hd_v)
-    kv_bytes = L_ * batch * toks_read * Hkv * (hd_qk + hd_v) * bpe
-    qo_bytes = L_ * batch * (K + 1) * at.n_heads * (hd_qk + hd_v) * bpe
-    table_bytes = L_ * batch * blocks * 4
-    verify = roofline_terms(flops, kv_bytes + qo_bytes + table_bytes, 0.0)
-    step_lb = verify["step_s_lower_bound"]
-    draft_lb = 0.0
-    if draft_cfg is not None and K > 0:
-        d = paged_decode_terms(draft_cfg, batch=batch, mean_len=mean_len,
-                               block_size=block_size, bpe=bpe)
-        if d is not None:
-            draft_lb = K * d["step_s_lower_bound"]
-            step_lb += draft_lb
-    tok_s_spec = batch * exp_tokens / max(step_lb, 1e-12)
-    return {
-        "depth": K,
-        "acceptance": a,
-        "expected_tokens_per_step": exp_tokens,
-        "vanilla": vanilla,
-        "verify": verify,
-        "draft_s_lower_bound": draft_lb,
-        "step_s_lower_bound": step_lb,
-        "tok_s_bound": tok_s_spec,
-        "speedup_bound": tok_s_spec / max(vanilla["tok_s_bound"], 1e-12),
-    }
-
-
-def prefix_cache_terms(cfg, *, prompt_len, hit_rate, chunk_tokens=0,
-                       bpe=2):
-    """Analytic prefill cost of ONE request under the content-addressed
-    prefix cache: a fraction ``hit_rate`` of the prompt's KV is shared
-    from the pool instead of recomputed, so the cold-vs-cached TTFT lower
-    bounds differ by the skipped prefill work (model forward FLOPs ∝
-    uncached tokens; attention FLOPs quadratic in context but only over
-    uncached *query* rows, which still attend the cached KV).  Chunked
-    prefill (``chunk_tokens``) spreads the same work over
-    ``ceil(uncached / chunk)`` engine steps — it bounds per-step latency
-    without changing the total.  Feeds the serving bench's shared-prefix
-    A/B next to its measured TTFTs."""
-    n_params = cfg.active_param_count()
-    a = cfg.attn
-    H = a.n_heads if a else 0
-    hd = ((a.kv_lora_rank + a.qk_rope_head_dim) if a and a.is_mla
-          else (a.head_dim if a else 0))
-
-    def prefill_cost(n_cached):
-        q = prompt_len - n_cached             # query rows actually run
-        flops = 2 * n_params * q              # matmul forward
-        if a:                                 # attention: q rows × full ctx
-            kv = prompt_len
-            flops += cfg.n_layers * 2 * q * kv * H * 2 * hd
-        bytes_ = n_params * bpe + q * cfg.d_model * bpe \
-            + 2 * kv * (a.n_kv_heads if a and not a.is_mla else 1) * hd * bpe
-        return roofline_terms(flops, bytes_, 0.0)
-
-    cold = prefill_cost(0)
-    cached = prefill_cost(int(hit_rate * prompt_len))
-    n_chunks = (max(1, -(-prompt_len // chunk_tokens)) if chunk_tokens
-                else 1)
-    saved = 1 - (cached["compute_s"] / cold["compute_s"]
-                 if cold["compute_s"] else 0.0)
-    return {
-        "ttft_s_lower_bound_cold": cold["step_s_lower_bound"],
-        "ttft_s_lower_bound_cached": cached["step_s_lower_bound"],
-        "prefill_flops_saved_frac": saved,
-        "n_chunks_cold": n_chunks,
-        "blocks_saved_frac": hit_rate,        # shared, not re-stored
-    }
-
-
 def attention_analytic(cfg, shape, *, seq_shards, batch_shards):
     """Total analytic kernel (flops, bytes) per chip for all attention
     sites of one (arch × shape)."""

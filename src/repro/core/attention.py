@@ -48,22 +48,6 @@ def _resolve_mask(mask, causal, rel_offset, window) -> MaskSpec:
     return mk.full() if mask is None else mask
 
 
-def _tuning_kw(be, block_q, block_kv, *, mask=None, q=None, op="fwd"):
-    """block_q/block_kv hints are forwarded only to backends that declare
-    ``tunable_blocks`` (Pallas tile shapes, chunked-lax scan chunk); other
-    backends silently ignore the hints rather than erroring.  When the
-    caller passes no hints, the call context (backend, mask kind, shape)
-    lets ``block_tuning_kw`` consult the env overrides and the active
-    tuning table (repro.tune) before the kernels' built-in defaults."""
-    if not be.tunable_blocks:
-        return {}
-    return registry.block_tuning_kw(
-        block_q, block_kv, backend=be.name,
-        mask_kind=mask.kind if mask is not None else None,
-        head_dim=int(q.shape[-1]) if q is not None else None,
-        seq=int(q.shape[1]) if q is not None else None, op=op)
-
-
 def _offset_kw(mask, q_offset, kv_offset):
     """Reconcile the dynamic position operands via ``mk.fold_offsets``:
     static ints fold into the (static) MaskSpec — pruning and the Pallas
@@ -78,12 +62,11 @@ def _offset_kw(mask, q_offset, kv_offset):
 
 def chunk_attn(q, k, v, *, mask: MaskSpec | None = None, causal=None,
                rel_offset=None, window=None, scale=None, impl=None,
-               block_q=None, block_kv=None, q_segments=None,
-               kv_segments=None, q_offset=None, kv_offset=None):
+               q_segments=None, kv_segments=None, q_offset=None,
+               kv_offset=None):
     """Partial attention under a static ``mask`` (MaskSpec).
     ``q_segments``/``kv_segments`` are (B, Tq)/(B, Tk) int32 document IDs
-    (document kind). ``block_q``/``block_kv`` are optional tile-shape hints
-    for tunable backends. ``q_offset``/``kv_offset`` are *dynamic position
+    (document kind). ``q_offset``/``kv_offset`` are *dynamic position
     operands* added to the mask's own offsets — python ints fold into the
     spec; traced scalars (schedule steps whose chunk distance depends on
     the device index) restrict resolution to ``dynamic_offsets`` backends.
@@ -93,15 +76,13 @@ def chunk_attn(q, k, v, *, mask: MaskSpec | None = None, causal=None,
     be = registry.resolve(impl, mask=mask, dtype=q.dtype,
                           dynamic_offsets=dyn)
     return be.fwd(q, k, v, mask=mask, scale=scale, q_segments=q_segments,
-                  kv_segments=kv_segments, **okw,
-                  **_tuning_kw(be, block_q, block_kv, mask=mask, q=q))
+                  kv_segments=kv_segments, **okw)
 
 
 def chunk_attn_bwd(q, k, v, o, lse, do, *, mask: MaskSpec | None = None,
                    causal=None, rel_offset=None, window=None, scale=None,
-                   impl=None, delta=None, block_q=None, block_kv=None,
-                   q_segments=None, kv_segments=None, q_offset=None,
-                   kv_offset=None):
+                   impl=None, delta=None, q_segments=None, kv_segments=None,
+                   q_offset=None, kv_offset=None):
     """FA2 backward for one chunk using the saved (o, lse) — no forward
     recompute. ``delta = rowsum(o⊙do)`` may be precomputed (the distributed
     helper path ships delta instead of o). ``q_offset``/``kv_offset`` as in
@@ -111,9 +92,7 @@ def chunk_attn_bwd(q, k, v, o, lse, do, *, mask: MaskSpec | None = None,
     be = registry.resolve(impl, mask=mask, dtype=q.dtype,
                           dynamic_offsets=dyn)
     return be.bwd(q, k, v, o, lse, do, mask=mask, scale=scale, delta=delta,
-                  q_segments=q_segments, kv_segments=kv_segments, **okw,
-                  **_tuning_kw(be, block_q, block_kv, mask=mask, q=q,
-                               op="bwd"))
+                  q_segments=q_segments, kv_segments=kv_segments, **okw)
 
 
 def paged_decode_attn(q, k_pool, v_pool, block_table, lengths, *,

@@ -161,10 +161,6 @@ class DistAttnSpec:
     # the platform's default); capability/platform fallback happens at
     # resolve time
     impl: Optional[str] = None
-    # per-call-site kernel tile hints, forwarded to tunable backends only
-    # (Pallas block shapes / chunked-lax scan chunk). None = backend default.
-    block_q: Optional[int] = None
-    block_kv: Optional[int] = None
     mesh2d: Optional[Mesh2DSpec] = None
 
     def __post_init__(self, causal, window):
@@ -230,9 +226,8 @@ class DistAttnSpec:
 
 
 def _tune(spec: DistAttnSpec) -> dict:
-    """chunk_attn tuning kwargs carried by the spec (scale + tile hints)."""
-    return dict(scale=spec.scale, impl=spec.impl, block_q=spec.block_q,
-                block_kv=spec.block_kv)
+    """chunk_attn kwargs carried by the spec (scale and backend)."""
+    return dict(scale=spec.scale, impl=spec.impl)
 
 
 def _seg_kw(mask: MaskSpec, q_seg, kv_seg) -> dict:

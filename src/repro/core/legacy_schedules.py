@@ -17,8 +17,7 @@ from repro.core.attention import chunk_attn, chunk_attn_bwd, mask_partial, merge
 
 
 def _tune(spec):
-    return dict(scale=spec.scale, impl=spec.impl, block_q=spec.block_q,
-                block_kv=spec.block_kv)
+    return dict(scale=spec.scale, impl=spec.impl)
 
 
 def _seg_kw(mask, q_seg, kv_seg):

@@ -1218,11 +1218,8 @@ def choose_schedule(mask: MaskSpec, P: int, *, Tl: int, B: int = 1,
     so it stays an explicit opt-in) plus the ulysses baseline when the
     head counts divide P.
 
-    Ranking consults the active tuning table (repro.tune) first: a
-    measured row at the nearest (mask kind, P, seq) bucket decides
-    outright; otherwise the table's calibrated cost-model coefficients
-    rank the candidates; only with no table at all does the uncalibrated
-    analytic roofline decide.  Deterministic: ties break toward
+    Ranking is the analytic cost model's step-time lower bound
+    (``plan_cost`` / ``ulysses_cost``).  Deterministic: ties break toward
     balanced > ring > ulysses.
 
     ``include_bwd`` is both the cost-ranking horizon *and* a capability
@@ -1233,8 +1230,7 @@ def choose_schedule(mask: MaskSpec, P: int, *, Tl: int, B: int = 1,
 
     ``factorize=True`` widens the search to the 2D (seq=r, head=u)
     factorization space and returns a ``(name, r, u)`` triple instead of
-    a name — ranked purely by the analytic cost model (the tuning table's
-    measured rows are 1D walls and would be incommensurable)."""
+    a name, ranked by the same analytic cost model."""
     Hkv = Hq if Hkv is None else Hkv
     if factorize:
         return _choose_factorized(mask, P, Tl=Tl, B=B, Hq=Hq, Hkv=Hkv,
@@ -1255,37 +1251,17 @@ def choose_schedule(mask: MaskSpec, P: int, *, Tl: int, B: int = 1,
     if len(names) == 1:
         return names[0]
 
-    from repro.tune.table import active_table
-    tab = active_table()
-    if tab is not None:
-        hit = tab.best_schedule(mask_kind=mask.kind, P=P, seq=P * Tl,
-                                candidates=names)
-        if hit is not None:
-            return hit
-    coeffs = tab.coeffs() if tab is not None else None
-
     scored = []
     order = {"balanced": 0, "ring": 1, "ulysses": 2}
     for name in names:
-        if coeffs is not None:
-            from repro.tune.calibrate import (predict_s,
-                                              schedule_features)
-            feats = schedule_features(
-                name, mask_kind=mask.kind, P=P, seq=P * Tl, B=B, Hq=Hq,
-                Hkv=Hkv, Dqk=Dqk, bpe=bpe, window=mask.window or None,
-                dynamic_seg=dynamic_seg, include_bwd=include_bwd)
-            if feats is None:
-                continue
-            t = predict_s(feats, coeffs)
-        elif name == "ulysses":
+        if name == "ulysses":
             cost = ulysses_cost(mask, P, Tl=Tl, B=B, Hq=Hq, Hkv=Hkv,
                                 Dqk=Dqk, Dv=Dv, bpe=bpe)
-            t = cost.time_estimate(include_bwd)["step_s_lower_bound"]
         else:
             cost = plan_cost(build_plan(name, mask, P, Tl), B=B, Hq=Hq,
                              Hkv=Hkv, Dqk=Dqk, Dv=Dv, bpe=bpe,
                              dynamic_seg=dynamic_seg)
-            t = cost.time_estimate(include_bwd)["step_s_lower_bound"]
+        t = cost.time_estimate(include_bwd)["step_s_lower_bound"]
         scored.append((t, order[name], name))
     return min(scored)[2]
 

@@ -4,10 +4,9 @@ The schedules guarantee the :class:`repro.core.mask.MaskSpec` of every step
 is static (DESIGN.md §2), so for a fixed block tiling the set of (q-block,
 kv-block) pairs the mask can reach is computable at trace time. This module
 is the single source of truth for those ranges — the Pallas kernels
-(``flash_attention.py``), the ``chunked-lax`` backend (``chunked.py``) and
-the kernel microbench (``benchmarks/kernel_bench.py``) all derive their
-iteration spaces from the same functions, so CPU CI exercises the
-identical block-range logic the TPU kernels run.
+(``flash_attention.py``) and the ``chunked-lax`` backend (``chunked.py``)
+derive their iteration spaces from the same functions, so CPU CI exercises
+the identical block-range logic the TPU kernels run.
 
 Conventions. Q block ``i`` covers absolute query positions
 ``[mask.q_offset + i*br, mask.q_offset + (i+1)*br - 1]``; KV block ``j``
@@ -69,7 +68,7 @@ def _cdiv(a, b):
 
 def pick_block(T: int, block: int) -> int:
     """Largest divisor of T that is ≤ ``block`` (grids and scans need equal
-    blocks, so a non-dividing tuning hint is shrunk, not crashed on). When T
+    blocks, so a non-dividing tile argument is shrunk, not crashed on). When T
     has no useful divisor near the target (prime-ish lengths), blocking
     would degenerate into a near-token-level sweep — return T itself so the
     caller takes the single-block path."""

@@ -57,15 +57,13 @@ def _seg_chunks(seg, sl, nv, bc):
 
 
 def chunked_fwd(q, k, v, *, mask=None, causal=False, rel_offset=0, window=0,
-                scale=None, block_kv=DEFAULT_BLOCK_KV, block_q=None,
-                prune=True, q_segments=None, kv_segments=None,
-                q_offset=0, kv_offset=0):
-    """Partial attention, chunk_attn semantics: returns (o, lse).
-    ``block_q`` is accepted for tuning-surface uniformity with the Pallas
-    backend (queries are not blocked here). ``q_offset``/``kv_offset`` are
-    extra position operands (ints fold into the mask; traced values ride
-    through to the reference kernel and disable static pruning)."""
-    del block_q
+                scale=None, block_kv=DEFAULT_BLOCK_KV, prune=True,
+                q_segments=None, kv_segments=None, q_offset=0, kv_offset=0):
+    """Partial attention, chunk_attn semantics: returns (o, lse). Only
+    keys are blocked (``block_kv``); queries are not. ``q_offset``/
+    ``kv_offset`` are extra position operands (ints fold into the mask;
+    traced values ride through to the reference kernel and disable static
+    pruning)."""
     mask = as_spec(mask, causal=causal, window=window,
                    rel_offset=rel_offset)
     mask, q_offset, kv_offset, dyn = fold_offsets(mask, q_offset, kv_offset)
@@ -111,11 +109,10 @@ def chunked_fwd(q, k, v, *, mask=None, causal=False, rel_offset=0, window=0,
 
 def chunked_bwd(q, k, v, o, lse, do, *, mask=None, causal=False,
                 rel_offset=0, window=0, scale=None, delta=None,
-                block_kv=DEFAULT_BLOCK_KV, block_q=None, prune=True,
+                block_kv=DEFAULT_BLOCK_KV, prune=True,
                 q_segments=None, kv_segments=None, q_offset=0, kv_offset=0):
     """FA2 backward from saved (o, lse), blocked over KV chunks.
     Returns (dq, dk, dv); dk/dv are zeros on statically-masked chunks."""
-    del block_q
     mask = as_spec(mask, causal=causal, window=window,
                    rel_offset=rel_offset)
     mask, q_offset, kv_offset, dyn = fold_offsets(mask, q_offset, kv_offset)

@@ -6,8 +6,8 @@ Public layout is (B, T, H, D) (matching the model code); the kernels use
 IDs are (B, T) int32 operands. The legacy ``causal``/``rel_offset``/
 ``window`` kwargs still build the equivalent spec. Tiles left unset
 (``block_q``/``block_kv`` None) come from ``flash_tiles``, per kernel, from
-the call's lengths, head dims and mask; a hint wins on its axis, and a
-hint that does not divide T shrinks to a divisor.
+the call's lengths, head dims and mask; a tile passed explicitly wins on
+its axis, and one that does not divide T shrinks to a divisor.
 ``prune`` (default on) enables the static block-sparse grid pruning;
 ``prune=False`` forces the dense ``nq × nk`` sweep (benchmark baseline /
 differential testing).

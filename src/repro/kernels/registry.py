@@ -62,8 +62,8 @@ class BackendSpec:
     ``bwd(q, k, v, o, lse, do, *, mask, scale, delta, q_segments,
         kv_segments) -> (dq, dk, dv)``
 
-    (``mask`` is a static MaskSpec; tunable backends additionally take
-    ``block_q``/``block_kv`` hints.)
+    (``mask`` is a static MaskSpec.) The Pallas backends' tiles come from
+    ``flash_attention.flash_tiles``; chunked-lax uses its own scan chunk.
     """
     name: str
     fwd: Callable
@@ -73,10 +73,6 @@ class BackendSpec:
     dtypes: Tuple[str, ...] = ALL_DTYPES
     platforms: Tuple[str, ...] = ALL_PLATFORMS
     exact: bool = True             # numerically exact (vs stub)
-    # accepts block_q/block_kv tuning hints (fwd/bwd take them as kwargs);
-    # chunk_attn only forwards the hints to backends with this flag set, so
-    # schedules can pick block shapes per step without knowing the backend
-    tunable_blocks: bool = False
     # accepts *traced* q_offset/kv_offset position operands (fwd/bwd take
     # them as kwargs). Needed by schedule steps whose chunk distance
     # depends on the device index (zigzag window bands); static int offsets
@@ -97,19 +93,6 @@ class BackendSpec:
             raise ValueError(f"unknown mask kinds {sorted(unknown)}; "
                              f"valid: {sorted(ALL_MASK_KINDS)}")
         object.__setattr__(self, "mask_kinds", frozenset(self.mask_kinds))
-
-    # legacy capability views (pre-MaskSpec flag names)
-    @property
-    def causal(self) -> bool:
-        return "causal" in self.mask_kinds
-
-    @property
-    def window(self) -> bool:
-        return "sliding_window" in self.mask_kinds
-
-    @property
-    def rel_offset(self) -> bool:
-        return True    # every backend handles static chunk offsets
 
     @property
     def paged(self) -> bool:
@@ -255,59 +238,22 @@ def _chunked_bwd(q, k, v, o, lse, do, **kw):
     return chunked_bwd(q, k, v, o, lse, do, **kw)
 
 
-def block_tuning_kw(block_q, block_kv, *, backend=None, platform=None,
-                    mask_kind=None, head_dim=None, seq=None, op="fwd"):
-    """None-filtered {block_q, block_kv} kwargs for tunable backends (shared
-    by chunk_attn's hint forwarding and the pallas closures below).
-
-    When the caller passes *neither* block, the tuning chain kicks in:
-    ``REPRO_TUNE_BLOCK_Q``/``REPRO_TUNE_BLOCK_KV`` env overrides first,
-    then the active tuning table's nearest-bucket winner for the call
-    context (requires ``backend`` + shape context — the bare two-arg form
-    used inside backend closures never re-consults the table).  Explicit
-    kwargs always win wholesale; with no env, no table, and no kwargs the
-    kernels choose their own tiles (the Pallas kernels' ``flash_tiles``,
-    chunked-lax's default block)."""
-    if block_q is None and block_kv is None:
-        from repro.tune import table as _tt
-        block_q = _tt.env_int("REPRO_TUNE_BLOCK_Q")
-        block_kv = _tt.env_int("REPRO_TUNE_BLOCK_KV")
-        if block_q is None and block_kv is None and backend is not None:
-            tab = _tt.active_table()
-            if tab is not None:
-                hit = tab.best_blocks(
-                    backend=backend, platform=platform or current_platform(),
-                    mask_kind=mask_kind or "causal",
-                    head_dim=head_dim or 64, seq=seq or 0, op=op)
-                if hit is not None:
-                    block_q, block_kv = hit
-    kw = {}
-    if block_q is not None:
-        kw["block_q"] = block_q
-    if block_kv is not None:
-        kw["block_kv"] = block_kv
-    return kw
-
-
 def _pallas_fwd(interpret):
-    def fwd(q, k, v, *, mask, scale=None, q_segments=None, kv_segments=None,
-            block_q=None, block_kv=None):
+    def fwd(q, k, v, *, mask, scale=None, q_segments=None, kv_segments=None):
         from repro.kernels import ops
         return ops.flash_fwd(q, k, v, mask=mask, scale=scale,
                              interpret=interpret, q_segments=q_segments,
-                             kv_segments=kv_segments,
-                             **block_tuning_kw(block_q, block_kv))
+                             kv_segments=kv_segments)
     return fwd
 
 
 def _pallas_bwd(interpret):
     def bwd(q, k, v, o, lse, do, *, mask, scale=None, delta=None,
-            q_segments=None, kv_segments=None, block_q=None, block_kv=None):
+            q_segments=None, kv_segments=None):
         from repro.kernels import ops
         return ops.flash_bwd(q, k, v, o, lse, do, mask=mask, scale=scale,
                              interpret=interpret, delta=delta,
-                             q_segments=q_segments, kv_segments=kv_segments,
-                             **block_tuning_kw(block_q, block_kv))
+                             q_segments=q_segments, kv_segments=kv_segments)
     return bwd
 
 
@@ -361,21 +307,21 @@ register(BackendSpec(
 
 register(BackendSpec(
     name="chunked-lax", fwd=_chunked_fwd, bwd=_chunked_bwd,
-    tunable_blocks=True, dynamic_offsets=True, paged_fwd=_paged_chunked,
+    dynamic_offsets=True, paged_fwd=_paged_chunked,
     fallback=("ref",),
     description="lax.scan-blocked online softmax; Pallas-free"))
 
 register(BackendSpec(
     name="pallas", fwd=_pallas_fwd(False), bwd=_pallas_bwd(False),
     platforms=("tpu",), dtypes=("float32", "bfloat16"),
-    tunable_blocks=True, paged_fwd=_paged_pallas(False),
+    paged_fwd=_paged_pallas(False),
     fallback=("chunked-lax", "ref"),
     description="compiled Pallas TPU FlashAttention-2 kernel"))
 
 register(BackendSpec(
     name="pallas-interpret", fwd=_pallas_fwd(True), bwd=_pallas_bwd(True),
     dtypes=("float32", "bfloat16"),
-    tunable_blocks=True, paged_fwd=_paged_pallas(True),
+    paged_fwd=_paged_pallas(True),
     fallback=("chunked-lax", "ref"),
     description="Pallas kernel body under the interpreter (validation)"))
 

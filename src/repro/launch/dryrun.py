@@ -7,7 +7,7 @@ on the production meshes and extract memory/cost/collective analyses.
 
 Success of ``.lower().compile()`` for a pair proves the sharding config is
 coherent (no mismatched collectives, divisibility holes, or unsupported
-layouts); the printed analyses feed EXPERIMENTS.md §Dry-run and §Roofline.
+layouts); it prints the memory, cost and roofline analyses per pair.
 The CLI runs on 512 placeholder host devices, which stand in for the
 production 2×16×16 multi-pod mesh; importing this module changes nothing.
 """

@@ -39,7 +39,7 @@ def main(argv=None):
     ap.add_argument("--fixed-slot", action="store_true",
                     help="legacy dense-cache engine instead of paged")
     ap.add_argument("--block-size", type=int, default=0,
-                    help="paged block size (0 = tuning-table default)")
+                    help="paged block size (0 = the cache default, 16)")
     ap.add_argument("--n-blocks", type=int, default=0,
                     help="paged pool size (0 = sized to the workload)")
     ap.add_argument("--spec-depth", type=int, default=0,

@@ -381,24 +381,8 @@ class PagedKVCache:
 
     @staticmethod
     def default_block_size(a, mesh=None, seq_axis: str = "model") -> int:
-        """Default pool granularity when the caller passes none:
-        ``REPRO_TUNE_BLOCK_SIZE`` env > the active tuning table's winner
-        for this (kv layout, pool sharding) > the historical 16."""
-        from repro.tune import table as _tt
-        bs = _tt.env_int("REPRO_TUNE_BLOCK_SIZE")
-        if bs is not None:
-            return bs
-        tab = _tt.active_table()
-        if tab is not None:
-            size = 1
-            if mesh is not None:
-                size = dict(zip(mesh.axis_names,
-                                mesh.devices.shape)).get(seq_axis, 1)
-            hit = tab.best_block_size(
-                layout="mla" if a.is_mla else "mha",
-                sharding="none" if size <= 1 else "pool")
-            if hit is not None:
-                return hit
+        """Pool granularity when the caller passes none: 16 tokens for
+        every layout and pool sharding."""
         return 16
 
     @staticmethod
@@ -434,7 +418,7 @@ class PagedKVCache:
         return write_pos // self.block_size >= int(self.n_assigned[slot])
 
     def device_table(self) -> jax.Array:
-        return jnp.asarray(self.table)
+        return jnp.asarray(self.table.copy())  # the live table mutates
 
     @property
     def n_cache_blocks(self) -> int:
@@ -648,7 +632,7 @@ class PagedKVCache:
         ``n_tokens`` (padded prefill); only the first ``n_tokens`` page in."""
         n = self.blocks_for(n_tokens)
         assert n <= int(self.n_assigned[slot])
-        ids = jnp.asarray(self.table[slot, :n], jnp.int32)
+        ids = jnp.asarray(self.table[slot, :n].copy())
         bs = self.block_size
         for dk, pk in (("k", "k_pool"), ("v", "v_pool"), ("ckv", "ckv_pool")):
             if dk not in dense_cache:
@@ -667,7 +651,7 @@ class PagedKVCache:
         """Contiguous (L, length, ...) view of a slot's cache — test /
         debugging aid (the decode path never materializes this)."""
         n = self.blocks_for(length)
-        ids = jnp.asarray(self.table[slot, :n], jnp.int32)
+        ids = jnp.asarray(self.table[slot, :n].copy())
         out = {}
         for pk in self.pools:
             p = self.pools[pk][:, ids]                # (L, n, bs, ...)

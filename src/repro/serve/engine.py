@@ -249,7 +249,9 @@ class Engine:
         toks = np.zeros((C,), np.int32)
         toks[:n] = req.context[start:end]
         nkv = self._nkv_for(end)
-        bt = jnp.asarray(self.cache.table[req.slot:req.slot + 1, :nkv])
+        # a private copy: the transfer may read host memory after this
+        # returns, and the scheduler edits the live table meanwhile
+        bt = jnp.asarray(self.cache.table[req.slot:req.slot + 1, :nkv].copy())
         self.cache.pools = self._chunk_jit(
             self.params, self.cache.pools, bt, jnp.int32(start),
             jnp.int32(n), jnp.asarray(toks)[None])

@@ -228,7 +228,6 @@ def test_capability_flags_reported():
     spec = registry.get("chunked-lax")
     assert spec.mask_kinds == frozenset(
         {"causal", "sliding_window", "prefix_lm", "document"})
-    assert spec.causal and spec.window and spec.rel_offset  # legacy views
     assert "cpu" in spec.platforms and "tpu" in spec.platforms
     assert registry.get("pallas").platforms == ("tpu",)
 

@@ -85,39 +85,6 @@ print("OK P=7 balanced")
     assert "OK" in out
 
 
-def test_block_tuning_hints_through_schedules(subproc):
-    """DistAttnSpec.block_q/block_kv thread through every schedule step's
-    chunk_attn call (tunable backends only) and stay exact — forward and
-    backward, with and without a sliding window."""
-    out = subproc("""
-import jax, jax.numpy as jnp
-from repro.launch.mesh import make_mesh
-from repro.core import mask as mk
-from repro.core.dist_attention import DistAttnSpec, dist_flash_attn
-from repro.kernels.ref import full_attn_ref
-mesh = make_mesh((1,4), ("data","model"))
-B,N,H,D = 1,256,2,16
-ks = jax.random.split(jax.random.PRNGKey(5),3)
-q,k,v = (jax.random.normal(kk,(B,N,H,D)) for kk in ks)
-for sched, window in [("balanced",0), ("ring",40)]:
-    spec = DistAttnSpec(axis="model", axis_size=4, schedule=sched,
-                        mask=mk.MaskSpec(causal=True, window=window),
-                        impl="chunked-lax", block_q=32, block_kv=32)
-    o_ref = full_attn_ref(q,k,v,causal=True,window=window)
-    def loss(q,k,v):
-        o,_ = dist_flash_attn(q,k,v,mesh,spec,("data",))
-        return jnp.sum(o.astype(jnp.float32)**2), o
-    (l,o), g = jax.jit(jax.value_and_grad(loss,(0,1,2),has_aux=True))(q,k,v)
-    assert float(jnp.abs(o-o_ref).max()) < 2e-5, sched
-    def loss_ref(q,k,v): return jnp.sum(full_attn_ref(q,k,v,causal=True,window=window).astype(jnp.float32)**2)
-    g_ref = jax.grad(loss_ref,(0,1,2))(q,k,v)
-    for a,b in zip(g,g_ref):
-        assert float(jnp.abs(a-b).max()) < 5e-5, sched
-    print("OK tuned", sched)
-""", devices=4)
-    assert out.count("OK") == 2
-
-
 def test_decode_attention(subproc):
     out = subproc("""
 import jax, jax.numpy as jnp

@@ -260,38 +260,37 @@ def test_mask_kinds_chunked_vs_ref(kind):
         assert jnp.allclose(r, c_, atol=2e-4, rtol=2e-4), kind
 
 
-# ------------------------------------------------------ block tuning surface
+# ------------------------------------------------------ explicit kernel tiles
 
 def test_chunk_attn_block_hints_reach_tunable_backends():
-    """block_q/block_kv flow through chunk_attn to tunable backends and
-    stay exact; non-tunable backends silently drop the hints."""
+    """Explicit tiles on the kernel entries (``ops.flash_fwd/flash_bwd``
+    and chunked-lax's ``block_kv``) stay exact; a tile that does not
+    divide T (96 ∤ 128) shrinks to a divisor instead of failing."""
+    from functools import partial
     from repro.core import mask as mkk
-    from repro.core.attention import chunk_attn, chunk_attn_bwd
+    from repro.kernels import ops
+    from repro.kernels.chunked import chunked_bwd, chunked_fwd
     q, k, v, do = _mk(1, 128, 256, 2, 2, 32, jnp.float32)
     m = mkk.causal(rel_offset=128)
     o_r, lse_r = chunk_attn_ref(q, k, v, mask=m)
-    for impl in ("chunked-lax", "pallas-interpret", "ref"):
-        # non-dividing hints (96 ∤ 128) must shrink to a divisor, not crash
-        o_nd, _ = chunk_attn(q, k, v, mask=m, impl=impl, block_q=96,
-                             block_kv=96)
+    g_r = chunk_attn_bwd_ref(q, k, v, o_r, lse_r, do, mask=m)
+    entries = {
+        "chunked-lax": (lambda bq, bk: partial(chunked_fwd, block_kv=bk),
+                        lambda bq, bk: partial(chunked_bwd, block_kv=bk)),
+        "pallas-interpret": (
+            lambda bq, bk: partial(ops.flash_fwd, block_q=bq, block_kv=bk,
+                                   interpret=True),
+            lambda bq, bk: partial(ops.flash_bwd, block_q=bq, block_kv=bk,
+                                   interpret=True)),
+    }
+    for impl, (fwd, bwd) in entries.items():
+        o_nd, _ = fwd(96, 96)(q, k, v, mask=m)
         assert jnp.allclose(o_r, o_nd, atol=1e-5), impl
-        o_b, lse_b = chunk_attn(q, k, v, mask=m, impl=impl, block_q=64,
-                                block_kv=32)
+        o_b, lse_b = fwd(64, 32)(q, k, v, mask=m)
         assert jnp.allclose(o_r, o_b, atol=1e-5), impl
-        g_r = chunk_attn_bwd_ref(q, k, v, o_r, lse_r, do, mask=m)
-        g_b = chunk_attn_bwd(q, k, v, o_b, lse_b, do, mask=m, impl=impl,
-                             block_q=64, block_kv=32)
+        g_b = bwd(64, 32)(q, k, v, o_b, lse_b, do, mask=m)
         for a, b in zip(g_r, g_b):
             assert jnp.allclose(a, b, atol=2e-4), impl
-
-
-def test_registry_tunable_flag():
-    from repro.kernels import registry
-    assert registry.get("pallas").tunable_blocks
-    assert registry.get("pallas-interpret").tunable_blocks
-    assert registry.get("chunked-lax").tunable_blocks
-    assert not registry.get("ref").tunable_blocks
-    assert not registry.get("null").tunable_blocks
 
 
 def test_kernel_ref_grad_consistency():
@@ -375,41 +374,36 @@ def test_flash_tiles_shrink_for_wide_heads(D):
         assert fa._vmem_bytes(bq, bk, D, D, op) <= fa.VMEM_BUDGET, op
 
 
-@pytest.mark.parametrize("source", ["chooser", "hints", "env", "table"])
+@pytest.mark.parametrize("op", TILE_OPS)
+@pytest.mark.parametrize("D,kind", [(64, "causal"), (128, "causal"),
+                                    (128, "unmasked")])
+def test_flash_tiles_at_cell_shapes(D, kind, op):
+    """The tiles of the benchmark cells' flash calls at T 8192 in bf16:
+    smollm-360m's causal hd64 step, and the qwen3-8b sequence-parallel
+    step's diagonal (causal) and off-diagonal (unmasked) hd128 chunks.
+    Each kernel runs 1024 a side."""
+    from repro.core import mask as mk
+    from repro.kernels import flash_attention as fa
+    mask = mk.causal() if kind == "causal" else mk.full()
+    assert fa.flash_tiles(8192, 8192, D, D, mask, op, 2) == (1024, 1024)
+
+
+@pytest.mark.parametrize("source", ["chooser", "hints"])
 def test_flash_tile_precedence(monkeypatch, source):
-    """Through chunk_attn, explicit hints, then ``REPRO_TUNE_BLOCK_*``,
-    then a tuning-table hit win over the chooser, as
-    ``registry.block_tuning_kw`` orders them; each flash call records the
-    tiles it ran with in its metadata."""
+    """Explicit tiles on ``ops.flash_fwd/flash_bwd`` win over the chooser;
+    without them, as through chunk_attn, each kernel takes
+    ``flash_tiles``'s. Each flash call records the tiles it ran with in
+    its metadata."""
     from jax.experimental import pallas as pl
     from repro.core import mask as mk
     from repro.core.attention import chunk_attn, chunk_attn_bwd
     from repro.kernels import flash_attention as fa
-    from repro.tune import table as tt
+    from repro.kernels import ops
     T, D = 1024, 64
     mask = mk.causal()
-    for var in ("REPRO_TUNE", "REPRO_TUNE_TABLE", "REPRO_TUNE_BLOCK_Q",
-                "REPRO_TUNE_BLOCK_KV"):
-        monkeypatch.delenv(var, raising=False)
-    monkeypatch.setattr(tt, "_EXPLICIT", None)     # no table ...
-    monkeypatch.setattr(tt, "_ACTIVE", tt._UNSET)
-    hints = {}
     want = {op: fa.flash_tiles(T, T, D, D, mask, op) for op in TILE_OPS}
     if source == "hints":
-        hints = dict(block_q=256, block_kv=128)
         want = dict.fromkeys(TILE_OPS, (256, 128))
-    elif source == "env":
-        monkeypatch.setenv("REPRO_TUNE_BLOCK_KV", "256")
-        want = {op: (b[0], 256) for op, b in want.items()}
-    elif source == "table":                        # ... but for this one
-        row = dict(backend="pallas-interpret", platform="cpu",
-                   mask_kind="causal", head_dim=D, seq=T, block_q=128,
-                   block_kv=256, wall_us=1.0)
-        monkeypatch.setattr(tt, "_EXPLICIT", tt.TuningTable(dict(
-            schema_version=tt.SCHEMA_VERSION, generated_by="tests",
-            host=dict(platform="cpu"), schedule=[], paged=[],
-            kernel=[dict(row, op="fwd"), dict(row, op="bwd")])))
-        want = dict.fromkeys(TILE_OPS, (128, 256))
     seen = {}
     real = pl.pallas_call
 
@@ -419,9 +413,13 @@ def test_flash_tile_precedence(monkeypatch, source):
 
     monkeypatch.setattr(pl, "pallas_call", spy)
     q, k, v, do = _mk(1, T, T, 2, 1, D, jnp.float32)
-    kw = dict(mask=mask, impl="pallas-interpret", **hints)
 
     def fwd_bwd(q, k, v, do):
+        if source == "hints":
+            kw = dict(mask=mask, interpret=True, block_q=256, block_kv=128)
+            o, lse = ops.flash_fwd(q, k, v, **kw)
+            return ops.flash_bwd(q, k, v, o, lse, do, **kw)
+        kw = dict(mask=mask, impl="pallas-interpret")
         o, lse = chunk_attn(q, k, v, **kw)
         return chunk_attn_bwd(q, k, v, o, lse, do, **kw)
 

@@ -276,6 +276,18 @@ def test_scalar_pos_shim_warns_once():
 # registry: the paged capability flag
 # ==========================================================================
 
+@pytest.mark.parametrize("arch", ["smollm-360m", "deepseek-v2-lite-16b"])
+def test_default_block_size_is_16(arch):
+    """Without a block_size the pool is cut into 16-token blocks, for the
+    mha layout (smollm-360m) and the mla latent layout (deepseek)."""
+    from repro.core.config import get_config, smoke_config
+    cfg = get_config(arch)
+    assert cfg.attn.is_mla == arch.startswith("deepseek")
+    assert PagedKVCache.default_block_size(cfg.attn) == 16
+    cache = PagedKVCache.create(smoke_config(cfg), n_blocks=4, max_reqs=1)
+    assert cache.block_size == 16
+
+
 def test_registry_paged_capability_and_fallback():
     for name in ("ref", "chunked-lax", "pallas", "pallas-interpret"):
         assert registry.get(name).paged, name

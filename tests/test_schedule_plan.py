@@ -174,6 +174,30 @@ def test_plan_cost_model_sanity():
         sp.choose_schedule(mk.prefix_lm(8), 8, Tl=64, Hq=6, Hkv=3)
 
 
+def test_choose_schedule_roofline_without_table():
+    assert sp.choose_schedule(mk.causal(), 1, Tl=64) == "ring"
+    assert sp.choose_schedule(mk.causal(), 8, Tl=256, Hq=8) in (
+        "balanced", "ring", "ulysses")
+
+
+# schedule="auto" at qwen3-8b's attention widths (Hq 32, Hkv 8, hd128) and
+# 8192 tokens per device: the names the analytic cost model ranks first
+AUTO_AT_QWEN3_8B = {"causal": "ulysses", "sliding_window": "balanced",
+                    "document": "ulysses", "full": "ring"}
+
+
+@pytest.mark.parametrize("P", [2, 4, 8])
+@pytest.mark.parametrize("kind", sorted(AUTO_AT_QWEN3_8B))
+def test_choose_schedule_at_qwen3_8b_widths(kind, P):
+    Tl = 8192
+    mask = {"causal": mk.causal(), "document": mk.document(),
+            "sliding_window": mk.sliding_window(Tl // 4),
+            "full": mk.full()}[kind]
+    assert sp.choose_schedule(mask, P, Tl=Tl, Hq=32, Hkv=8, Dqk=128,
+                              dynamic_seg=kind == "document") \
+        == AUTO_AT_QWEN3_8B[kind]
+
+
 # --------------------------------------------------------------------------
 # 2. Differential vs the frozen seed implementations (8 host devices)
 # --------------------------------------------------------------------------

@@ -12,7 +12,10 @@ Covers the acceptance criteria of the speculative subsystem:
     and draft allocators conserve through rollbacks, including under a
     seeded chaos storm;
   * unit tests for the tree-mask helpers (core/mask.tree_spec) and the
-    prompt-lookup matcher (NGramDraft).
+    prompt-lookup matcher (NGramDraft);
+  * adaptive depth — the controller is a pure function of a request's
+    own acceptance history, and an adaptive engine emits token-identical
+    streams to the vanilla engine.
 """
 import types
 
@@ -275,3 +278,92 @@ def test_stats_merges_spec_and_robustness_counters(served):
               "spec_rollbacks", "spec_acceptance", "shed", "retried",
               "quarantined", "expired", "failed", "watchdog_trips"):
         assert k in s, k
+
+
+# ==========================================================================
+# adaptive speculative depth
+# ==========================================================================
+
+def _adaptive_spec(**over):
+    from repro.serve.speculative import SpecConfig
+    kw = dict(depth=4, mode="ngram", adaptive=True, adapt_window=4,
+              adapt_floor=0.25, min_depth=1)
+    kw.update(over)
+    return SpecConfig(**kw)
+
+
+def test_spec_config_adaptive_validation():
+    from repro.serve.speculative import SpecConfig
+    with pytest.raises(ValueError, match="adapt_window"):
+        SpecConfig(depth=4, adaptive=True, adapt_window=0)
+    with pytest.raises(ValueError, match="adapt_floor"):
+        SpecConfig(depth=4, adaptive=True, adapt_floor=1.5)
+    with pytest.raises(ValueError, match="min_depth"):
+        SpecConfig(depth=4, adaptive=True, min_depth=9)
+
+
+def test_adaptive_depth_is_pure_function_of_own_history():
+    from repro.serve.speculative import AdaptiveDepth
+    ad = AdaptiveDepth(_adaptive_spec())
+    # optimistic start: no history -> full cap
+    assert ad.depth_for(1) == 4
+    # full acceptance keeps the cap
+    for _ in range(4):
+        ad.observe(1, 4, 4)
+    assert ad.depth_for(1) == 4
+    # zero acceptance floors at min_depth
+    for _ in range(4):
+        ad.observe(1, 0, 4)
+    assert ad.depth_for(1) == 1
+    # a == 0.5 -> d* = log(.25)/log(.5) = 2
+    ad2 = AdaptiveDepth(_adaptive_spec())
+    for _ in range(4):
+        ad2.observe(2, 2, 4)
+    assert ad2.depth_for(2) == 2
+    # other requests' history never leaks: rid 3 untouched -> cap
+    assert ad2.depth_for(3) == 4
+    # release forgets
+    ad2.release(2)
+    assert ad2.depth_for(2) == 4
+    # zero-proposal steps carry no signal
+    ad3 = AdaptiveDepth(_adaptive_spec())
+    ad3.observe(5, 0, 0)
+    assert ad3.depth_for(5) == 4
+
+
+def test_adaptive_depth_window_slides():
+    from repro.serve.speculative import AdaptiveDepth
+    ad = AdaptiveDepth(_adaptive_spec(adapt_window=2))
+    for _ in range(10):
+        ad.observe(1, 0, 4)
+    assert ad.depth_for(1) == 1
+    # two perfect steps push the zeros out of the window -> cap again
+    ad.observe(1, 4, 4)
+    ad.observe(1, 4, 4)
+    assert ad.depth_for(1) == 4
+
+
+def _streams(model, params, prompts, spec):
+    from repro.serve.engine import Engine
+    eng = Engine(model, params, max_batch=2, block_size=8, n_blocks=32,
+                 spec=spec)
+    rids = [eng.submit(prompts[i][:24 + 4 * i], max_new_tokens=8,
+                       temperature=0.0) for i in range(2)]
+    out = eng.run()
+    return [np.asarray(out[r]) for r in rids], eng
+
+
+def test_adaptive_engine_streams_token_identical(served):
+    _, model, params, prompts = served
+    base, _ = _streams(model, params, prompts, None)
+    adapt, eng = _streams(model, params, prompts, _adaptive_spec())
+    for b, a in zip(base, adapt):
+        np.testing.assert_array_equal(b, a)
+    hist = eng.stats()["spec_depth_hist"]
+    assert hist and sum(hist.values()) > 0
+    assert all(0 <= k <= 4 for k in hist)
+    # determinism of the whole adaptive engine: replay is identical
+    adapt2, eng2 = _streams(model, params, prompts, _adaptive_spec())
+    for a, a2 in zip(adapt, adapt2):
+        np.testing.assert_array_equal(a, a2)
+    assert eng2.stats()["spec_depth_hist"] == hist
