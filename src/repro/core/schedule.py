@@ -18,13 +18,19 @@ The IR
 * :class:`Ref` — one operand chunk: ``src`` ∈ ``local`` (this device's
   shard) | ``ring`` (the traveling KV container) | ``bundle`` (the
   traveling query bundle of the balanced schedule); ``chunk`` indexes the
-  shard's ``n_chunks`` sub-chunks (zigzag holds two).
+  shard's ``n_chunks`` sub-chunks (zigzag holds two).  A q-side Ref may
+  name a row ``part=(i, n)``: the i-th of n equal row slices of the chunk
+  (the balanced plan's split last step, where two devices each compute
+  half of one chunk pair).  Segment cuts, position offsets, result merges
+  and gradient adds follow the part.
 * :class:`Operand` — a Ref, optionally predicate-selected against an
   alternative (``jnp.where`` on the device index — the balanced schedule's
   worker/helper fusion runs one kernel per step).
 * :class:`Route` — where one kernel result goes: merge into a local output
   chunk gated by a device predicate, optionally after a ``ship`` ppermute
-  (the balanced helper sending ``(o, lse)`` home).
+  (the balanced helper sending ``(o, lse)`` home).  A result over a row
+  part lands in the same part of the output chunk; the forward joins a
+  step's parts of one chunk into one full-chunk partial before merging.
 * :class:`Work` — one chunk-attention kernel call: q/kv operands, the
   step's static :class:`~repro.core.mask.MaskSpec`, result routes, and
   whether the mask needs *dynamic position offsets* (zigzag window bands,
@@ -50,7 +56,9 @@ follow its operand sources (local q → local ``dq``; bundle q → traveling
 
 :func:`plan_coverage` is a pure-numpy simulator of the executor used by
 the property tests (every causal pair computed exactly once; skipped steps
-provably all-masked), and :func:`plan_cost` is the static comm/compute
+provably all-masked); :func:`plan_device_pairs` walks the same routing to
+count, per device, the kernel pairs whose result some route keeps and
+those every route drops.  :func:`plan_cost` is the static comm/compute
 model behind ``DistAttnSpec(schedule="auto")`` (see
 :func:`choose_schedule`), with time conversion wired into
 ``analysis/roofline.py``.
@@ -115,9 +123,26 @@ def _pred_int(pred, p: int) -> bool:
 
 @dataclasses.dataclass(frozen=True)
 class Ref:
-    """One operand chunk: which container, which sub-chunk."""
+    """One operand chunk: which container, which sub-chunk, and (q side
+    only) which row part: ``part=(i, n)`` is the i-th of n equal row
+    slices of the chunk; ``(0, 1)`` is the whole chunk."""
     src: str                        # "local" | "ring" | "bundle"
     chunk: int = 0                  # sub-chunk index (< plan.n_chunks)
+    part: Tuple[int, int] = (0, 1)
+
+
+def _part(ref: Ref, c: int) -> Tuple[int, int]:
+    """(first row within the chunk, row count) of a ref's row part, for
+    chunks of ``c`` tokens."""
+    i, n = ref.part
+    return i * (c // n), c // n
+
+
+def _rows(ref: Ref, c: int) -> slice:
+    """Token rows of a ref's chunk part within its container."""
+    off, rows = _part(ref, c)
+    lo = ref.chunk * c + off
+    return slice(lo, lo + rows)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -283,10 +308,17 @@ def _balanced_plan(m: MaskSpec, P: int, Tl: int) -> SchedulePlan:
     (q_{(p−t) mod P} × kv_p) for distance-(P−t) pairs and ship (o, lse)
     home.  Plain causal (± dynamic document) fuses both roles into one
     predicate-selected kernel per step, as the paper's implementation
-    does; windowed / boundary-pruned variants split into separately
-    skippable worker and helper items (worker distance t, helper distance
-    P−t — a small window truncates to a helper-free, balanced-by-
-    construction band)."""
+    does.  At even P the last step (t = P/2) has no helper pair of its
+    own: helper p already holds the operands of worker p + P/2's pair
+    (q_{p+P/2} in the bundle, kv_p local), so the pair is split by query
+    rows between them — the worker computes rows [0, Tl/2) of its local q
+    against the ring kv, the helper rows [Tl/2, Tl) of its bundle q
+    against its local kv and ships that half of (o, lse) home — and every
+    device computes the even share, P/2 chunk pairs (an odd Tl keeps the
+    whole pair on the worker).  Windowed / boundary-pruned variants split
+    into separately skippable worker and helper items (worker distance t,
+    helper distance P−t — a small window truncates to a helper-free,
+    balanced-by-construction band)."""
     me = _exec_mask(m)
     local = [Work(_L0, _L0, me, (Route(),))]
     T = P // 2
@@ -295,12 +327,15 @@ def _balanced_plan(m: MaskSpec, P: int, Tl: int) -> SchedulePlan:
     for t in range(1, T + 1):
         helpers = (t != T) or (P % 2 == 1)
         if fused:
+            half = not helpers and Tl % 2 == 0
+            qw, qh = (Ref("local", 0, (0, 2)), Ref("bundle", 0, (1, 2))) \
+                if half else (Ref("local", 0), Ref("bundle", 0))
             routes = [Route(pred=_ge(t))]
-            if helpers:
+            if helpers or half:
                 routes.append(Route(pred=_lt(t), ship=-t,
                                     recv_pred=_ge(P - t)))
             executed.append((t, [Work(
-                Operand(Ref("local", 0), Ref("bundle", 0), _ge(t)),
+                Operand(qw, qh, _ge(t)),
                 Operand(Ref("ring", 0), Ref("local", 0), _ge(t)),
                 mk.strict_causal_pair(me), tuple(routes))]))
             continue
@@ -444,32 +479,33 @@ class _Ctx:
         self.bundle = None                    # fwd: q; bwd: (q, do, lse, Δ)
 
     # ------------------------------------------------------------ chunks
-    def _cut(self, x, i):
-        return x[:, i * self.c:(i + 1) * self.c]
+    def _cut(self, x, ref):
+        return x[:, _rows(ref, self.c)]
 
     def owner(self, src):
         return self.p if src == "local" else (self.p - self.d) % self.P
 
     def offset(self, ref):
-        """Traced absolute token offset of a ref's chunk."""
+        """Traced absolute token offset of a ref's chunk (part)."""
         g = _gchunk(self.plan.layout, self.P, self.owner(ref.src), ref.chunk)
-        return (g * self.c).astype(jnp.int32) if hasattr(g, "astype") \
-            else jnp.int32(g * self.c)
+        off = _part(ref, self.c)[0]
+        return (g * self.c + off).astype(jnp.int32) if hasattr(g, "astype") \
+            else jnp.int32(g * self.c + off)
 
     def seg_for(self, ref):
-        """(B, c) int32 segment IDs for a ref's chunk, or None."""
+        """(B, rows) int32 segment IDs for a ref's chunk (part), or
+        None."""
         if not self.doc:
             return None
         if self.derive_seg:
-            g = _gchunk(self.plan.layout, self.P, self.owner(ref.src),
-                        ref.chunk)
-            pos = g * self.c + jnp.arange(self.c)
+            rows = _part(ref, self.c)[1]
+            pos = self.offset(ref) + jnp.arange(rows)
             row = self.plan.mask.segment_of(pos)
-            return jnp.broadcast_to(row[None, :], (self.B, self.c))
+            return jnp.broadcast_to(row[None, :], (self.B, rows))
         if self.seg is None:
             return None
         arr = self.seg if ref.src == "local" else self.ring_seg
-        return self._cut(arr, ref.chunk)
+        return self._cut(arr, ref)
 
     # ---------------------------------------------------------- containers
     def data_containers(self, bwd_bundle=None):
@@ -511,19 +547,17 @@ def _q_side(ctx: _Ctx, ref: Ref, extras):
     bundle-resident statistics the backward needs (do, lse, delta), pulled
     from the local arrays or the traveling bundle to match the ref."""
     if ref.src == "local":
-        vals = [ctx._cut(ctx.q, ref.chunk)]
-        vals += [ctx._cut(x, ref.chunk) for x in extras]
+        vals = [ctx._cut(x, ref) for x in (ctx.q,) + tuple(extras)]
     else:
         assert ref.src == "bundle"
-        vals = [ctx._cut(ctx.bundle[0], ref.chunk)]
-        vals += [ctx._cut(x, ref.chunk) for x in ctx.bundle[1:]]
+        vals = [ctx._cut(x, ref) for x in ctx.bundle]
     return tuple(vals) + (ctx.seg_for(ref), ctx.offset(ref))
 
 
 def _kv_side(ctx: _Ctx, ref: Ref):
     kk, vv = (ctx.k, ctx.v) if ref.src == "local" else ctx.ring_kv
-    return (ctx._cut(kk, ref.chunk), ctx._cut(vv, ref.chunk),
-            ctx.seg_for(ref), ctx.offset(ref))
+    return (ctx._cut(kk, ref), ctx._cut(vv, ref), ctx.seg_for(ref),
+            ctx.offset(ref))
 
 
 def _resolve(ctx, op: Operand, side_fn):
@@ -557,6 +591,15 @@ def _wval(ctx, preds):
     return w
 
 
+def _route_part(w: Work, r: Route):
+    """Row part of the output chunk a route's result lands in: the part of
+    the q ref its sending device computes (the branch ``r.pred``
+    selects)."""
+    parts = {ref.part for _, ref in _grad_branches(w.q, r.pred)}
+    assert len(parts) == 1, (w.q, r.pred)
+    return parts.pop()
+
+
 def _grad_branches(op: Operand, route_pred):
     """Resolve which operand branch(es) a route's gradient flows to,
     with the predicate weight(s): [(preds, ref), ...]."""
@@ -583,7 +626,11 @@ def execute_fwd(plan: SchedulePlan, q, k, v, seg=None, *, axis, tune,
     ctx = _Ctx(plan, axis, tune, q, k, v, seg, latent)
     acc = [None] * plan.n_chunks
 
+    def merge_into(i, o_r, s_r):
+        acc[i] = (o_r, s_r) if acc[i] is None else merge(*acc[i], o_r, s_r)
+
     def run(step):
+        parts = {}                     # (chunk, n) -> {i: row-part result}
         for w in step.work:
             qc, q_seg, q_off = _resolve(ctx, w.q, lambda r: _q_side(ctx, r, ()))
             kc, vc, kv_seg, kv_off = _resolve(ctx, w.kv,
@@ -601,8 +648,18 @@ def execute_fwd(plan: SchedulePlan, q, k, v, seg=None, *, axis, tune,
                 pv = _pred_val(pred, ctx.p)
                 if pv is not None:
                     o_r, s_r = mask_partial(pv, o_r, s_r)
-                acc[r.chunk] = (o_r, s_r) if acc[r.chunk] is None \
-                    else merge(*acc[r.chunk], o_r, s_r)
+                i, n = _route_part(w, r)
+                if n == 1:
+                    merge_into(r.chunk, o_r, s_r)
+                else:
+                    parts.setdefault((r.chunk, n), {})[i] = (o_r, s_r)
+        # a step routes every row part of a split chunk (the worker's own,
+        # the helper's shipped home): join them into one full-chunk
+        # partial and merge it once
+        for (chunk, n), got in parts.items():
+            rows = [got[i] for i in range(n)]
+            merge_into(chunk, jnp.concatenate([o for o, _ in rows], axis=1),
+                       jnp.concatenate([s for _, s in rows], axis=1))
 
     run(plan.steps[0])
     rest = plan.steps[1:]
@@ -622,7 +679,7 @@ def execute_fwd(plan: SchedulePlan, q, k, v, seg=None, *, axis, tune,
                 ctx.d += rest[i + 1].shift
                 ctx.install(data)
     outs = [a if a is not None
-            else empty_partial(ctx._cut(q, i))
+            else empty_partial(ctx._cut(q, Ref("local", i)))
             for i, a in enumerate(acc)]
     if plan.n_chunks == 1:
         return outs[0]
@@ -650,12 +707,9 @@ def execute_bwd(plan: SchedulePlan, q, k, v, o, lse, do, seg=None, *,
         if plan.uses_ring else None
     dqb = jnp.zeros(q.shape, f32) if plan.ship_q else None
 
-    def sl(i):
-        return slice(i * ctx.c, (i + 1) * ctx.c)
-
-    def add(base, i, val, wgt):
+    def add(base, ref, val, wgt):
         val = val.astype(f32) if wgt is None else val.astype(f32) * wgt
-        return base.at[:, sl(i)].add(val)
+        return base.at[:, _rows(ref, ctx.c)].add(val)
 
     def run(step):
         nonlocal dq, dk_home, dv_home, dkv, dqb
@@ -672,17 +726,17 @@ def execute_bwd(plan: SchedulePlan, q, k, v, o, lse, do, seg=None, *,
                 for preds, ref in _grad_branches(w.q, r.pred):
                     wgt = _wval(ctx, preds)
                     if ref.src == "local":
-                        dq = add(dq, ref.chunk, dq_t, wgt)
+                        dq = add(dq, ref, dq_t, wgt)
                     else:
-                        dqb = add(dqb, ref.chunk, dq_t, wgt)
+                        dqb = add(dqb, ref, dq_t, wgt)
                 for preds, ref in _grad_branches(w.kv, r.pred):
                     wgt = _wval(ctx, preds)
                     if ref.src == "local":
-                        dk_home = add(dk_home, ref.chunk, dk_t, wgt)
-                        dv_home = add(dv_home, ref.chunk, dv_t, wgt)
+                        dk_home = add(dk_home, ref, dk_t, wgt)
+                        dv_home = add(dv_home, ref, dv_t, wgt)
                     else:
-                        dkv = (add(dkv[0], ref.chunk, dk_t, wgt),
-                               add(dkv[1], ref.chunk, dv_t, wgt))
+                        dkv = (add(dkv[0], ref, dk_t, wgt),
+                               add(dkv[1], ref, dv_t, wgt))
 
     run(plan.steps[0])
     rest = plan.steps[1:]
@@ -726,43 +780,41 @@ def execute_bwd(plan: SchedulePlan, q, k, v, o, lse, do, seg=None, *,
 # Pure-python plan simulator (property tests: exactly-once coverage)
 # ---------------------------------------------------------------------------
 
-def _sim_allow(w: Work, plan: SchedulePlan, qg, kg, c, segments):
-    """Boolean (c, c) attend matrix exactly as the kernel would compute it
-    for this work item: static mask offsets, plus true global offsets when
-    ``dyn_offsets``, plus segment IDs (given or boundary-derived)."""
+def _sim_allow(w: Work, plan: SchedulePlan, qref: Ref, qg, kg, c,
+               segments):
+    """Boolean (rows, c) attend matrix exactly as the kernel would compute
+    it for this work item on the q ref's rows: static mask offsets, plus
+    true global offsets when ``dyn_offsets``, plus segment IDs (given or
+    boundary-derived)."""
     m = w.mask
-    q_pos = m.q_offset + (qg * c if w.dyn_offsets else 0) + np.arange(c)
+    off, rows = _part(qref, c)
+    q0 = qg * c + off                          # global row of the part
+    q_pos = m.q_offset + (q0 if w.dyn_offsets else 0) + np.arange(rows)
     k_pos = m.kv_offset + (kg * c if w.dyn_offsets else 0) + np.arange(c)
     qs = ks = None
     if m.document:
         if segments is not None:
-            qs = np.asarray(segments)[qg * c:(qg + 1) * c][:, None]
+            qs = np.asarray(segments)[q0:q0 + rows][:, None]
             ks = np.asarray(segments)[kg * c:(kg + 1) * c][None, :]
         elif plan.mask.boundaries is not None:
             gb = plan.mask
-            qs = np.array([gb.segment_index(qg * c + i)
-                           for i in range(c)])[:, None]
+            qs = np.array([gb.segment_index(q0 + i)
+                           for i in range(rows)])[:, None]
             ks = np.array([gb.segment_index(kg * c + j)
                            for j in range(c)])[None, :]
     allow = m.allow(q_pos[:, None], k_pos[None, :], qs, ks)
     if allow is None:
-        return np.ones((c, c), bool)
+        return np.ones((rows, c), bool)
     return np.asarray(allow)
 
 
-def plan_coverage(plan: SchedulePlan, c: Optional[int] = None,
-                  segments=None) -> np.ndarray:
-    """(T, T) count of how many times each *global* (q, kv) token pair is
-    computed-and-merged by the plan — a pure-python walk of the executor's
-    routing.  ``c`` overrides tokens per sub-chunk (default: the plan's);
-    ``segments`` is an optional (T,) global segment-ID array for dynamic
-    document masks.  The exactly-once property: counts equal 1 on the
-    global mask's allowed pairs and 0 elsewhere (see
-    :func:`global_allow`)."""
-    P, nc = plan.P, plan.n_chunks
-    c = plan.chunk_len if c is None else c
-    T = P * nc * c
-    counts = np.zeros((T, T), np.int64)
+def _walk(plan: SchedulePlan):
+    """The executor's routing in python: for every device p and every
+    kernel call it runs, yield ``(p, w, qref, qg, kg, kept)`` — the q ref
+    the call computes on p, the global q/kv chunk indices, and the routes
+    that keep its result (a ship route keeps it when the sender's and the
+    receiver's predicates both hold)."""
+    P = plan.P
     for p in range(P):
         d = 0
         for step in plan.steps:
@@ -774,19 +826,55 @@ def plan_coverage(plan: SchedulePlan, c: Optional[int] = None,
                 k_owner = p if kref.src == "local" else (p - d) % P
                 qg = _gchunk(plan.layout, P, q_owner, qref.chunk)
                 kg = _gchunk(plan.layout, P, k_owner, kref.chunk)
-                for r in w.routes:
-                    if r.ship:
-                        recv = (p + r.ship) % P
-                        active = (_pred_int(r.pred, p)
-                                  and _pred_int(r.recv_pred, recv))
-                    else:
-                        active = _pred_int(r.pred, p)
-                    if not active:
-                        continue
-                    allow = _sim_allow(w, plan, qg, kg, c, segments)
-                    counts[qg * c:(qg + 1) * c,
-                           kg * c:(kg + 1) * c] += allow
+                kept = [r for r in w.routes
+                        if _pred_int(r.pred, p)
+                        and (not r.ship
+                             or _pred_int(r.recv_pred, (p + r.ship) % P))]
+                yield p, w, qref, qg, kg, kept
+
+
+def plan_coverage(plan: SchedulePlan, c: Optional[int] = None,
+                  segments=None) -> np.ndarray:
+    """(T, T) count of how many times each *global* (q, kv) token pair is
+    computed-and-merged by the plan — a pure-python walk of the executor's
+    routing.  ``c`` overrides tokens per sub-chunk (default: the plan's);
+    ``segments`` is an optional (T,) global segment-ID array for dynamic
+    document masks.  The exactly-once property: counts equal 1 on the
+    global mask's allowed pairs and 0 elsewhere (see
+    :func:`global_allow`)."""
+    c = plan.chunk_len if c is None else c
+    T = plan.P * plan.n_chunks * c
+    counts = np.zeros((T, T), np.int64)
+    for _, w, qref, qg, kg, kept in _walk(plan):
+        if not kept:
+            continue
+        allow = _sim_allow(w, plan, qref, qg, kg, c, segments)
+        off, rows = _part(qref, c)
+        q0 = qg * c + off
+        counts[q0:q0 + rows, kg * c:(kg + 1) * c] += len(kept) * allow
     return counts
+
+
+def _work_pairs(w: Work, c: int) -> float:
+    """Kernel (q, kv) pairs of one Work item over chunks of ``c`` tokens:
+    its q rows × c after static mask pruning (dynamic-offset items count
+    dense — their kernels can't prune)."""
+    rows = _part(w.q.ref, c)[1]
+    return float(rows * c) if w.dyn_offsets \
+        else _band_pairs(w.mask, rows, c)
+
+
+def plan_device_pairs(plan: SchedulePlan):
+    """Per-device kernel pairs of a plan, by the walk of
+    :func:`plan_coverage`: ``(kept, dropped)`` float arrays of shape (P,)
+    — the pairs of the calls whose result some route keeps on that
+    device, and of those it computes only for every route to drop (a
+    causal ring's idle devices, say)."""
+    kept = np.zeros(plan.P)
+    dropped = np.zeros(plan.P)
+    for p, w, _, _, _, routes in _walk(plan):
+        (kept if routes else dropped)[p] += _work_pairs(w, plan.chunk_len)
+    return kept, dropped
 
 
 def global_allow(mask: MaskSpec, T: int, segments=None) -> np.ndarray:
@@ -818,7 +906,9 @@ class PlanCost:
     """Per-device static cost summary of one plan (or the ulysses
     baseline).  ``comm_bytes_*`` are hop-weighted ring-link bytes;
     ``flops_*`` count kernel matmul FLOPs after static mask pruning
-    (dynamic-offset items count dense — their kernels can't prune)."""
+    (dynamic-offset items count dense — their kernels can't prune);
+    ``flops_dropped_*`` is the part of them whose result no route keeps,
+    on the device that drops the most (:func:`plan_device_pairs`)."""
     schedule: str
     exec_steps: int
     total_steps: int
@@ -827,6 +917,8 @@ class PlanCost:
     flops_bwd: float
     comm_bytes_fwd: float
     comm_bytes_bwd: float
+    flops_dropped_fwd: float = 0.0
+    flops_dropped_bwd: float = 0.0
 
     def time_estimate(self, include_bwd: bool = True) -> dict:
         """Two-term (compute, collective) roofline seconds via
@@ -857,18 +949,15 @@ def plan_cost(plan: SchedulePlan, *, B: int = 1, Hq: int = 8,
               Dv: Optional[int] = None, bpe: int = 2,
               dynamic_seg: bool = False) -> PlanCost:
     """Static per-device cost of a plan: kernel FLOPs per Work item
-    (after static mask pruning) and hop-weighted ring traffic per
-    executed shift, fwd and bwd."""
+    (its q rows, after static mask pruning) and hop-weighted ring traffic
+    per executed shift and result ship, fwd and bwd."""
     Hkv = Hq if Hkv is None else Hkv
     Dv = Dqk if Dv is None else Dv
     c = plan.chunk_len
-    f_fwd = f_bwd = 0.0
-    for s in plan.steps:
-        for w in s.work:
-            pairs = float(c * c) if w.dyn_offsets \
-                else _band_pairs(w.mask, c, c)
-            f_fwd += 2.0 * B * Hq * pairs * (Dqk + Dv)
-            f_bwd += 2.0 * B * Hq * pairs * (3 * Dqk + 2 * Dv)
+    fl_fwd = 2.0 * B * Hq * (Dqk + Dv)            # FLOPs per kernel pair
+    fl_bwd = 2.0 * B * Hq * (3 * Dqk + 2 * Dv)
+    pairs = sum(_work_pairs(w, c) for s in plan.steps for w in s.work)
+    dropped = plan_device_pairs(plan)[1].max()
     kv_bytes = B * plan.Tl * Hkv * (Dqk + Dv) * bpe if plan.uses_ring \
         else 0.0
     seg_bytes = B * plan.Tl * 4 if (plan.mask.document and dynamic_seg
@@ -887,8 +976,9 @@ def plan_cost(plan: SchedulePlan, *, B: int = 1, Hq: int = 8,
         for w in s.work:
             for r in w.routes:
                 if r.ship:
-                    c_fwd += (B * c * Hq * Dv * bpe
-                              + B * c * Hq * 4) * abs(r.ship)
+                    rows = c // _route_part(w, r)[1]
+                    c_fwd += (B * rows * Hq * Dv * bpe
+                              + B * rows * Hq * 4) * abs(r.ship)
     # bwd: data containers travel D hops; traveling accumulators move on
     # every transition after the first executed step (D − s1 hops) and
     # return home with one D-hop shift
@@ -898,8 +988,10 @@ def plan_cost(plan: SchedulePlan, *, B: int = 1, Hq: int = 8,
     return PlanCost(schedule=plan.name, exec_steps=plan.exec_steps,
                     total_steps=plan.total_steps,
                     kernel_calls=plan.kernel_calls,
-                    flops_fwd=f_fwd, flops_bwd=f_bwd,
-                    comm_bytes_fwd=c_fwd, comm_bytes_bwd=c_bwd)
+                    flops_fwd=fl_fwd * pairs, flops_bwd=fl_bwd * pairs,
+                    comm_bytes_fwd=c_fwd, comm_bytes_bwd=c_bwd,
+                    flops_dropped_fwd=fl_fwd * dropped,
+                    flops_dropped_bwd=fl_bwd * dropped)
 
 
 def ulysses_cost(mask: MaskSpec, P: int, *, Tl: int, B: int = 1,
@@ -1167,7 +1259,9 @@ def plan2d_cost(p2: Plan2D, *, B: int = 1, Dqk: int = 64,
                     total_steps=inner.total_steps,
                     kernel_calls=inner.kernel_calls,
                     flops_fwd=inner.flops_fwd, flops_bwd=inner.flops_bwd,
-                    comm_bytes_fwd=c_fwd, comm_bytes_bwd=c_bwd)
+                    comm_bytes_fwd=c_fwd, comm_bytes_bwd=c_bwd,
+                    flops_dropped_fwd=inner.flops_dropped_fwd,
+                    flops_dropped_bwd=inner.flops_dropped_bwd)
 
 
 def factorizations(P: int):

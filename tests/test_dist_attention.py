@@ -85,6 +85,40 @@ print("OK P=7 balanced")
     assert "OK" in out
 
 
+def test_even_p2_balanced_schedule(subproc):
+    """P = 2: the balanced plan's one ring step is the split last step
+    (each device computes half of the off-diagonal pair) — forward and
+    grads exact against the oracle, causal and dynamic document."""
+    out = subproc("""
+import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
+from repro.core import mask as mk
+from repro.core.dist_attention import DistAttnSpec, dist_flash_attn
+from repro.kernels.ref import full_attn_ref
+mesh = make_mesh((1,2), ("data","model"))
+B,N,H,Hkv,D = 2,128,4,2,16
+ks = jax.random.split(jax.random.PRNGKey(5),3)
+q = jax.random.normal(ks[0],(B,N,H,D)); k = jax.random.normal(ks[1],(B,N,Hkv,D))
+v = jax.random.normal(ks[2],(B,N,Hkv,D))
+seg = jnp.asarray(np.tile(mk.segments_from_boundaries(N, mk.doc_boundaries(N, 3)), (B,1)))
+for m, s in ((mk.causal(), None), (mk.document(), seg)):
+    def loss_ref(q,k,v):
+        o = full_attn_ref(q,k,v,mask=m,segments=s)
+        return jnp.sum(o.astype(jnp.float32)**2), o
+    (_, o_ref), g_ref = jax.value_and_grad(loss_ref,(0,1,2),has_aux=True)(q,k,v)
+    spec = DistAttnSpec(axis="model", axis_size=2, schedule="balanced", mask=m)
+    def loss_d(q,k,v):
+        o,_ = dist_flash_attn(q,k,v,mesh,spec,None,segments=s)
+        return jnp.sum(o.astype(jnp.float32)**2), o
+    (_, o), g = jax.jit(jax.value_and_grad(loss_d,(0,1,2),has_aux=True))(q,k,v)
+    eo = float(jnp.abs(o-o_ref).max())
+    eg = max(float(jnp.abs(a-b).max()) for a,b in zip(g,g_ref))
+    assert eo < 2e-5 and eg < 5e-5, (m.kind, eo, eg)
+    print("OK P=2 balanced", m.kind, eo, eg)
+""", devices=2)
+    assert out.count("OK") == 2
+
+
 def test_decode_attention(subproc):
     out = subproc("""
 import jax, jax.numpy as jnp

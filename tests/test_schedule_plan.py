@@ -132,6 +132,63 @@ def test_plan_static_shape_properties():
     assert p_zw.exec_steps < p_zw.total_steps
 
 
+@pytest.mark.parametrize("P", [2, 4, 6, 8])
+@pytest.mark.parametrize("kind", ["causal", "document"])
+def test_balanced_even_p_drops_no_work(kind, P):
+    """The balanced plan at even P splits its last step's chunk pair by
+    query rows between the worker and the helper that both hold its
+    operands: no device computes a pair whose result every route drops,
+    and every device's kernel pairs are the even share of the causal
+    triangle (the diagonal chunk counts its band)."""
+    Tl = 8
+    m = {"causal": mk.causal(), "document": mk.document()}[kind]
+    plan = sp.build_plan("balanced", m, P, Tl)
+    kept, dropped = sp.plan_device_pairs(plan)
+    T = P * Tl
+    assert not dropped.any(), dropped
+    assert np.array_equal(kept, np.full(P, T * (T + 1) / 2 / P)), kept
+    cost = plan.cost()
+    assert cost.flops_dropped_fwd == cost.flops_dropped_bwd == 0.0
+    last = plan.steps[-1].work[0]
+    assert (last.q.ref.part, last.q.alt.part) == ((0, 2), (1, 2))
+    assert len(last.routes) == 2 and last.routes[1].ship == -(P // 2)
+    # the half-(o, lse) ship: Tl/2 rows over P/2 hops, in the fwd bytes
+    kw = dict(B=1, Hq=4, Hkv=2, Dqk=16, bpe=2)
+    c = plan.cost(**kw)
+    ship = (Tl // 2) * 4 * (16 * 2 + 4) * (P // 2)
+    helper_ships = sum((Tl * 4 * (16 * 2 + 4)) * t for t in range(1, P // 2))
+    kv_q = Tl * (2 * 32 * 2 + 4 * 16 * 2) * (P // 2)
+    assert c.comm_bytes_fwd == kv_q + helper_ships + ship
+
+
+@pytest.mark.parametrize("P", [3, 5, 7])
+def test_balanced_odd_p_plan_unchanged(P):
+    """Odd P keeps its last-step helpers: every step's fused kernel is a
+    whole chunk pair, routed to the worker and shipped home from the
+    helper, and no work is dropped."""
+    plan = sp.build_plan("balanced", mk.causal(), P, 8)
+    for t, step in enumerate(plan.steps[1:], start=1):
+        (w,) = step.work
+        assert (w.q.ref, w.q.alt) == (sp.Ref("local", 0), sp.Ref("bundle", 0))
+        assert w.routes == (sp.Route(pred=("ge", t)),
+                            sp.Route(pred=("lt", t), ship=-t,
+                                     recv_pred=("ge", P - t)))
+    assert not sp.plan_device_pairs(plan)[1].any()
+    assert plan.cost().flops_dropped_fwd == 0.0
+
+
+def test_dropped_work_counts_idle_ring_devices():
+    """The counter reads what the executor computes for nothing: on the
+    causal ring, devices p < t run step t's kernel and drop its result."""
+    P, Tl = 4, 8
+    kept, dropped = sp.plan_device_pairs(sp.build_plan("ring", mk.causal(),
+                                                       P, Tl))
+    assert dropped.tolist() == [3 * Tl * Tl, 2 * Tl * Tl, Tl * Tl, 0]
+    assert (kept + dropped == kept[0] + dropped[0]).all()
+    c = sp.build_plan("ring", mk.causal(), P, Tl).cost(Hq=1, Dqk=1)
+    assert c.flops_dropped_fwd == 2.0 * 2 * 3 * Tl * Tl
+
+
 def test_plan_cost_model_sanity():
     """Cost model: windowed plans are strictly cheaper than causal on the
     same schedule; balanced ships more bytes but runs fewer steps than
@@ -181,9 +238,12 @@ def test_choose_schedule_roofline_without_table():
 
 
 # schedule="auto" at qwen3-8b's attention widths (Hq 32, Hkv 8, hd128) and
-# 8192 tokens per device: the names the analytic cost model ranks first
-AUTO_AT_QWEN3_8B = {"causal": "ulysses", "sliding_window": "balanced",
-                    "document": "ulysses", "full": "ring"}
+# 8192 tokens per device: the names the analytic cost model ranks first.
+# Causal and dynamic-document balanced plans compute the even share at
+# every P (the split last step at even P), so they tie ulysses on compute;
+# both are compute-bound there and the tie breaks toward balanced.
+AUTO_AT_QWEN3_8B = {"causal": "balanced", "sliding_window": "balanced",
+                    "document": "balanced", "full": "ring"}
 
 
 @pytest.mark.parametrize("P", [2, 4, 8])

@@ -1,6 +1,6 @@
 """Time the flash kernels on a TPU at candidate tile shapes.
 
-    python tools/flash_tile_sweep.py [--seq 8192] [--iters 5]
+    python tools/flash_tile_sweep.py [--seq 8192] [--q-seq N] [--iters 5]
         [--mask causal|unmasked] [--tiles 512x512,1024x1024] [--out FILE]
 
 For each attention width (smollm-360m's 15/5 heads at head_dim 64, and
@@ -10,9 +10,11 @@ profiled over ``--iters`` calls. The device time of each kernel's events
 (``flash_fwd``, ``flash_bwd.dq``, ``flash_bwd.dkv``) is summed from the
 trace and printed per call in ms, one JSON line per candidate. The mask
 is causal (a training chunk on its own diagonal) or none (``unmasked``:
-the sequence-parallel step's off-diagonal 8192² chunks). The tile rule of
-``flash_tiles`` was chosen from these readings (PERF.md). Fails without a
-TPU.
+the sequence-parallel step's off-diagonal 8192² chunks). ``--q-seq``
+gives the queries a length of their own (default ``--seq``): 4096 against
+8192 keys is the half pair each of two devices computes in the balanced
+plan's split last step. The tile rule of ``flash_tiles`` was chosen from
+these readings (PERF.md). Fails without a TPU.
 """
 from __future__ import annotations
 
@@ -63,6 +65,7 @@ def kernel_ms(fn, args, iters):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--seq", type=int, default=8192)
+    ap.add_argument("--q-seq", type=int, default=None)
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--mask", choices=sorted(MASKS), default="causal")
     ap.add_argument("--tiles", default=None,
@@ -74,9 +77,10 @@ def main():
     if jax.default_backend() != "tpu":
         sys.exit("flash_tile_sweep: no TPU")
     mask, T, rows = MASKS[a.mask], a.seq, []
+    Tq = T if a.q_seq is None else a.q_seq
     for width, (hq, hkv, d) in WIDTHS.items():
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
-        q, do = (jax.random.normal(k, (1, T, hq, d), jnp.bfloat16)
+        q, do = (jax.random.normal(k, (1, Tq, hq, d), jnp.bfloat16)
                  for k in ks[:2])
         k, v = (jax.random.normal(kk, (1, T, hkv, d), jnp.bfloat16)
                 for kk in ks[2:])
@@ -91,7 +95,7 @@ def main():
                 ms = kernel_ms(fn, (q, k, v, do), a.iters)
             except Exception as e:  # noqa: BLE001 - report and go on
                 ms = {"error": f"{type(e).__name__}: {str(e)[:200]}"}
-            row = {"width": width, "mask": a.mask, "seq": T,
+            row = {"width": width, "mask": a.mask, "seq": T, "q_seq": Tq,
                    "block_q": bq, "block_kv": bk, **ms}
             rows.append(row)
             print(json.dumps(row), flush=True)
